@@ -4,8 +4,13 @@
 # etcd sidecar: L0 durability is the in-process WAL store, mounted as a
 # volume (docker-compose.yml).
 #
-# The image runs the CPU backend by default; on a TPU VM, base off a
-# TPU-enabled JAX image and set MINISCHED_DEVICE_MODE=1.
+# The image runs the scalar engine on the CPU backend.  For the wave engine
+# on a TPU VM: base off a TPU-enabled JAX image, set MINISCHED_DEVICE_MODE=1
+# and JAX_PLATFORMS=tpu,cpu (keep cpu in the list), run ONE container per
+# chip (a chip belongs to one process), and mount a volume at
+# JAX_COMPILATION_CACHE_DIR so restarts load their executables instead of
+# compiling them.  The process logs the platform/device_kind it got;
+# `python chip_smoke.py` is the check that the path starts on the chip.
 FROM python:3.12-slim
 
 RUN apt-get update \
@@ -20,7 +25,8 @@ COPY Makefile ./
 COPY native ./native
 COPY minisched_tpu ./minisched_tpu
 
-# build the native host-table kernels into the package (Makefile `native`)
+# build the native host-table kernels into the package (Makefile `native`:
+# the package's own digest-checked build, failing if it fell back to NumPy)
 RUN make native
 
 ENV PORT=10251 \

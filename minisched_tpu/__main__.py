@@ -33,7 +33,13 @@ Optional env:
 
     MINISCHED_TPU_STORE_URL=file:///tmp/cluster.wal   durable WAL store
                                                       (reference: etcd URL)
-    MINISCHED_DEVICE_MODE=1                           TPU wave engine
+    MINISCHED_DEVICE_MODE=1                           TPU wave engine; logs
+                                                      the platform/device it
+                                                      got (a CPU-only JAX runs
+                                                      it on XLA:CPU) and keeps
+                                                      its compile cache under
+                                                      JAX_COMPILATION_CACHE_DIR
+                                                      (utils/compilecache)
     MINISCHED_MESH_DEVICES=8                          pin an N-device mesh
                                                       (overrides the policy)
     MINISCHED_MESH=0|1                                mesh policy when no pin
@@ -64,8 +70,41 @@ from minisched_tpu.service.config import (
 from minisched_tpu.service.service import SchedulerService
 
 
+def _announce_device(sched, cache_dir) -> None:
+    """Say which device the wave engine runs on — one stderr line plus
+    ``engine.*`` gauges on /metrics.  JAX picks the backend, so without
+    this a "TPU wave engine" on XLA:CPU looks exactly like one on a chip."""
+    import jax
+
+    from minisched_tpu.observability import counters
+
+    dev = jax.devices()[0]
+    mesh = sched.mesh
+    layout = (
+        "single-device"
+        if mesh is None
+        else "mesh " + "x".join(map(str, mesh.devices.shape))
+    )
+    counters.set_gauge(
+        f"engine.device.{dev.platform}.{dev.device_kind.replace(' ', '_')}",
+        jax.device_count(),
+    )
+    counters.set_gauge(
+        "engine.mesh_devices", 0 if mesh is None else int(mesh.devices.size)
+    )
+    print(
+        f"minisched_tpu: device engine on platform={dev.platform} "
+        f"device_kind={dev.device_kind!r} devices={jax.device_count()} "
+        f"layout={layout} compile_cache={cache_dir}",
+        file=sys.stderr,
+        flush=True,
+    )
+
+
 def start(cfg: ProcessConfig, device_mode: bool = False, mesh_devices: int = 0):
-    """Boot the stack; returns (client, api_base_url, stop_fn)."""
+    """Boot the stack; returns (client, api_base_url, stop_fn).  The live
+    SchedulerService rides on ``stop_fn.service`` for embedders that need
+    the engine itself (chip_smoke.py inspects its compiled programs)."""
     # validate the flag combination BEFORE booting any component — failing
     # after the store/API server/PV controller are live would leak their
     # threads and the open WAL with no stop path
@@ -87,13 +126,22 @@ def start(cfg: ProcessConfig, device_mode: bool = False, mesh_devices: int = 0):
         default_full_roster_config() if device_mode else default_scheduler_config()
     )
     mesh = None
-    if device_mode and mesh_devices:
-        from minisched_tpu.parallel.sharding import make_mesh
+    cache_dir = None
+    if device_mode:
+        # every way of booting the device engine shares one compile cache
+        # (main(), chip_smoke.py, embedders) — not main() alone
+        from minisched_tpu.utils.compilecache import enable_persistent_cache
 
-        mesh = make_mesh(mesh_devices)
-    service.start_scheduler(
+        cache_dir = enable_persistent_cache()
+        if mesh_devices:
+            from minisched_tpu.parallel.sharding import make_mesh
+
+            mesh = make_mesh(mesh_devices)
+    sched = service.start_scheduler(
         scheduler_cfg, device_mode=device_mode, device_mesh=mesh
     )
+    if device_mode:
+        _announce_device(sched, cache_dir)
 
     def stop() -> None:
         service.close()
@@ -102,6 +150,7 @@ def start(cfg: ProcessConfig, device_mode: bool = False, mesh_devices: int = 0):
         if hasattr(raw, "close"):
             raw.close()
 
+    stop.service = service
     return client, base, stop
 
 
@@ -121,10 +170,6 @@ def main() -> int:
     cfg = ProcessConfig.from_env()
     device_mode = os.environ.get("MINISCHED_DEVICE_MODE", "0") == "1"
     mesh_devices = int(os.environ.get("MINISCHED_MESH_DEVICES", "0"))
-    if device_mode:
-        from minisched_tpu.utils.compilecache import enable_persistent_cache
-
-        enable_persistent_cache()
     _, base, stop = start(
         cfg, device_mode=device_mode, mesh_devices=mesh_devices
     )

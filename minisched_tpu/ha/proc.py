@@ -9,11 +9,18 @@ the expiry through the watch path, bump their epochs, and adopt the
 orphaned shard within the lease TTL.
 
 Same process hygiene as the server supervisor: a fresh interpreter (the
-parent's JAX runtime and threads never leak in), ``JAX_PLATFORMS=cpu``
-by default (N scalar engines must not fight over one accelerator), a
-parent-death watchdog so an aborted soak strands no children, and
-readiness gated on OBSERVABLE state — the child's member lease appearing
-live in the store, the engine-side analog of polling /healthz.
+parent's JAX runtime and threads never leak in), a parent-death watchdog
+so an aborted soak strands no children, and readiness gated on
+OBSERVABLE state — the child's member lease appearing live in the store,
+the engine-side analog of polling /healthz.
+
+Children are pinned to ``JAX_PLATFORMS=cpu`` by default, device-mode ones
+included: a chip belongs to ONE process, so N engine children cannot
+share it (the second fails or hangs at backend start).  One engine per
+chip is the only device-mode layout; a supervisor that wants it passes
+``jax_platforms=""`` (inherit) for exactly one child per chip and stays
+off JAX itself.  The child's stderr is inherited, not discarded — it is
+where the engine says which device it got and why a wave parked.
 """
 
 from __future__ import annotations
@@ -178,7 +185,6 @@ class EngineSupervisor:
             [sys.executable, "-c", _CHILD_CMD, json.dumps(cfg)],
             env=env,
             stdout=subprocess.DEVNULL,
-            stderr=subprocess.DEVNULL,
         )
         deadline = time.monotonic() + self._boot_timeout_s
         while time.monotonic() < deadline:

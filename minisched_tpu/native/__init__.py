@@ -1,17 +1,22 @@
 """ctypes bindings for the native host-table kernels (native/tablebuilder.cc).
 
-Loads ``libminisched_native.so`` from this package directory; if absent,
-compiles it on first import with g++ (cached thereafter).  Every entry
-point has a NumPy fallback (``HAVE_NATIVE`` False) so the package works
-without a toolchain — the fallbacks are the same code the slow path always
-used, just batched.
+``libminisched_native.so`` lives in this package directory and is git-
+ignored, so every checkout builds its own: on import the library is loaded
+only if ``libminisched_native.so.sha256`` beside it records the digest of
+the CURRENT ``native/tablebuilder.cc``; otherwise it is (re)built with g++
+first.  A stale or foreign ``.so`` is never loaded.  Every entry point has
+a NumPy fallback (``HAVE_NATIVE`` False) so the package works without a
+toolchain — but taking it is one loud stderr line, never silence: the
+fallback is the slow host build.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -26,28 +31,71 @@ HAVE_NATIVE = False
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _try_build() -> bool:
-    if not os.path.exists(_SRC):
-        return False
+def _digest_path(so: str) -> str:
+    return so + ".sha256"
+
+
+def _fallback(why: str) -> None:
+    print(
+        f"minisched_tpu.native: {why}; host table builds take the slow "
+        "NumPy path",
+        file=sys.stderr,
+        flush=True,
+    )
+
+
+def _build(so: str, src: str, digest: str) -> bool:
+    """Compile ``src`` into ``so`` and record ``digest`` beside it.  Both
+    land by atomic rename (concurrent importers — test workers, HA engine
+    children — may all build at once), the digest last: a crash in between
+    leaves a mismatch, i.e. a rebuild, never a stale load."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", _SO, _SRC],
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, src],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(tmp, so)
+        with open(tmp, "w") as f:
+            f.write(digest + "\n")
+        os.replace(tmp, _digest_path(so))
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as err:
+        detail = getattr(err, "stderr", b"") or b""
+        _fallback(
+            f"g++ build of {src} failed ({type(err).__name__}: "
+            f"{detail.decode(errors='replace').strip()[-200:] or err})"
+        )
+        if os.path.exists(tmp):
+            os.unlink(tmp)
         return False
 
 
-def _load() -> None:
+def _current(so: str, digest: str) -> bool:
+    try:
+        with open(_digest_path(so)) as f:
+            return os.path.exists(so) and f.read().strip() == digest
+    except OSError:
+        return False
+
+
+def _load(so: str = _SO, src: str = _SRC) -> None:
     global _lib, HAVE_NATIVE
-    if not os.path.exists(_SO) and not _try_build():
+    _lib, HAVE_NATIVE = None, False
+    try:
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()
+    except OSError as err:
+        _fallback(f"source {src} unreadable ({err})")
+        return
+    if not _current(so, digest) and not _build(so, src, digest):
         return
     try:
-        lib = ctypes.CDLL(_SO)
-    except OSError:
+        lib = ctypes.CDLL(so)
+    except OSError as err:
+        _fallback(f"cannot load {so} ({err})")
         return
     c_char_p = ctypes.c_char_p
     i64_p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")

@@ -130,6 +130,31 @@ bench records (``scheduler_over_http`` + ``wire_fanout``) alongside the
           double-encode races) and N−1 hits; every snapshot swap
           invalidates wholesale by replacing the cache's owner
 
+The device engine says what it runs on and when a device call fails
+(ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
+
+    engine.device.<platform>.<device_kind>  (gauge)
+        — JAX devices visible to a device-mode engine booted through
+          ``__main__.start``, named after the first one
+          (``engine.device.tpu.TPU_v5_lite 1`` on one v5e chip;
+          ``engine.device.cpu.cpu N`` means the "TPU wave engine" is
+          running on XLA:CPU)
+    engine.mesh_devices  (gauge)
+        — devices the engine's wave mesh spans; 0 = single-device
+    wave.parked / wave.parked.<cause>
+        — batches (repair waves and scan-lane chunks/flushes) whose
+          device call RAISED and whose pods went back through
+          error_func, in total and per exception type name.  A
+          transient fault adds a few; a program the compiler refuses
+          adds one per retry for ever — the requeue keeps the process
+          up and /healthz green, so this counter (plus one stderr line
+          per park and the ``wave_park`` trace span) is the only sign
+    wave.dispatch_healed
+        — packed-program dispatches that hit jax's wrong-arity
+          executable fault ("supplied N buffers but compiled program
+          expected M") and were recompiled once (models/tables.
+          PackedCaller); one stderr line each.  Repeats are a bug
+
 The multi-chip live wave engine (ISSUE 7: DeviceScheduler over a
 jax.sharding.Mesh, parallel/sharding.MeshPackedCaller) records under
 ``wave_mesh.`` — surfaced in the bench ``mesh`` child and the c5

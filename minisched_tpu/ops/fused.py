@@ -28,11 +28,14 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from minisched_tpu.framework.plugin import implements_batch
 
-UINT32_MAX = jnp.uint32(0xFFFFFFFF)
-NEG_INF_SCORE = jnp.iinfo(jnp.int32).min
+# host constants: a module-level jnp scalar is a device array, and creating
+# one initialises the JAX backend (takes the chip) at import
+UINT32_MAX = np.uint32(0xFFFFFFFF)
+NEG_INF_SCORE = int(np.iinfo(np.int32).min)
 
 
 @dataclass(frozen=True)

@@ -576,6 +576,9 @@ class MeshPackedCaller:
             pod_packed, node_static, node_agg_packed, extra_packed
         )
 
+    def lowered_texts(self):
+        return self._inner.lowered_texts()
+
     def _build_sharded_fn(self, pod_packed, node_static, node_agg_packed,
                           extra_packed):
         from minisched_tpu.models.constraints import ConstraintTables
@@ -647,6 +650,12 @@ class MeshPackedCaller:
             with _fused.mesh_trace_guard():
                 return jitted(pod_flat, agg_flat, ex_flat, static_cols)
 
-        # expose clear_cache for the heal path
-        traced.clear_cache = getattr(jitted, "clear_cache", lambda: None)
+        def lower(*avals):
+            with _fused.mesh_trace_guard():
+                return jitted.lower(*avals)
+
+        # the jit surface PackedCaller uses: clear_cache for the heal path,
+        # lower for lowered_texts
+        traced.clear_cache = jitted.clear_cache
+        traced.lower = lower
         return traced

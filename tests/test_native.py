@@ -101,3 +101,34 @@ def test_non_simple_pods_take_slow_path():
     assert not _pod_is_simple(pod)
     table, _ = build_pod_table([pod])
     assert int(table.num_tols[0]) == 1
+
+
+def test_stale_so_is_rebuilt_not_loaded(tmp_path, monkeypatch, capfd):
+    """A library whose recorded source digest is not the current
+    native/tablebuilder.cc's (or that has none) is rebuilt before loading;
+    a failed build is one loud stderr line and the NumPy path."""
+    import hashlib
+
+    so = str(tmp_path / "libminisched_native.so")
+    for attr in ("_lib", "HAVE_NATIVE"):  # restored after the test
+        monkeypatch.setattr(native, attr, getattr(native, attr))
+    with open(so, "wb") as f:
+        f.write(b"not an ELF: a stale build from another checkout")
+    with open(so + ".sha256", "w") as f:
+        f.write("0" * 64 + "\n")
+    native._load(so, native._SRC)
+    assert native.HAVE_NATIVE
+    with open(native._SRC, "rb") as f:
+        want = hashlib.sha256(f.read()).hexdigest()
+    with open(so + ".sha256") as f:
+        assert f.read().strip() == want
+    with open(so, "rb") as f:
+        assert f.read(4) == b"\x7fELF"
+    assert native.fnv1a32_batch(["pod7"]).tolist() == [fnv1a32("pod7")]
+
+    bad_src = tmp_path / "broken.cc"
+    bad_src.write_text("this is not C++")
+    native._load(str(tmp_path / "other.so"), str(bad_src))
+    assert not native.HAVE_NATIVE
+    assert "g++ build of" in capfd.readouterr().err
+    assert native.fnv1a32_batch(["pod7"]).tolist() == [fnv1a32("pod7")]
