@@ -1,0 +1,18 @@
+"""The benchmark's own tests (not part of the repo's tier-1 suite).
+
+    JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
+
+They run on the CPU: the rehearsal is reached only as a Python argument of
+``run.main``, never from the command line.
+"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (os.path.dirname(BENCH), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)

@@ -1,0 +1,179 @@
+"""The tiny-size run of every cell on the CPU, the TPU gate, and the faults
+that ``correct`` has to catch.
+
+The rehearsal is reached only as a Python argument of ``run.main``: the
+same argv through the command line ends at the TPU gate.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import audit
+import faults
+import manifest
+import reference
+import run
+
+CELLS = [w["name"] for w in manifest.build()["workloads"]]
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def rehearse(capfd, cell, trace, fault=None, seconds="2"):
+    argv = ["--workload", cell, "--seed", "3000000019", "--seconds", seconds, "--trace", str(trace)]
+    assert run.main(argv, rehearsal=True, fault=fault) == 0
+    out, err = capfd.readouterr()
+    return json.loads(out.strip().splitlines()[-1]), err
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_prints_the_contract_line(capfd, cell, trace):
+    result, err = rehearse(capfd, cell, trace)
+    assert RESULT_KEYS <= set(result)
+    assert list(result)[-1] == "compared"
+    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
+    assert result["device"]["platform"] == "cpu"
+    assert "platform=cpu" in err
+    if trace:
+        assert {"busy_s", "window_s"} <= set(result["device"])
+        assert set(result["metrics"]) <= {m["name"] for m in run.cell_metrics(run.load_cell(cell))}
+    else:
+        wanted = set(run.load_cell(cell)["traffic_data"]["end_to_end"]) | {"setup_s"}
+        assert set(result["metrics"]) == wanted
+        assert all(m["value"] > 0 for m in result["metrics"].values())
+    # every number compared is printed beside its limit, last on stderr
+    assert err.strip().splitlines()[-1] == "correct: True"
+    assert f"compared nodes_over_allocatable: 0 (limit 0)" in err
+
+
+def test_the_command_line_ends_at_the_tpu_gate():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(manifest.HERE, "run.py"), "--workload", CELLS[0],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=manifest.ROOT, capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "BENCH_RUN": "7"},
+    )
+    assert proc.returncode != 0
+    assert "No fallback" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_alone_with_the_manifest_it_prints_no_result(tmp_path):
+    import shutil
+
+    shutil.copy(os.path.join(manifest.ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(manifest.HERE, tmp_path / "benchmarks", ignore=shutil.ignore_patterns("__pycache__", ".trace"))
+    proc = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+
+
+FAULTS = [
+    ("basic-5000n.drain", faults.overcommit, "nodes_over_allocatable"),
+    ("basic-5000n.drain", faults.drop_half, "unbound_after_grace"),
+    ("basic-5000n.drain", faults.drop_all, "unbound_after_grace"),
+    ("spread-5000n.drain", faults.one_zone, "skew_over_max"),
+    ("spread-5000n.drain", faults.drop_half, "unbound_after_grace"),
+    ("basic-5000n.trickle", faults.overcommit, "nodes_over_allocatable"),
+]
+
+
+@pytest.mark.parametrize("cell,make_fault,number", [f for f in FAULTS if f[0] in CELLS])
+def test_a_broken_timed_path_is_not_correct(capfd, monkeypatch, cell, make_fault, number):
+    """The rest of a run, with the bind path broken underneath."""
+    result, err = rehearse(capfd, cell, 0, fault=make_fault(monkeypatch.setattr))
+    assert result["correct"] is False
+    assert result["failed"] > 0
+    assert result["compared"][number]["number"] > result["compared"][number]["limit"]
+    assert err.strip().splitlines()[-1] == "correct: False"
+
+
+# -- the reference and the audit, on placements written by hand ------------
+
+
+def node(name, zone=None, unschedulable=False, cpu=4000, mem=32 << 30, pods=110):
+    labels = {"topology.kubernetes.io/zone": zone} if zone else {}
+    return {
+        "metadata": {"name": name, "labels": labels},
+        "spec": {"unschedulable": unschedulable},
+        "status": {"allocatable": {"milli_cpu": cpu, "memory": mem, "pods": pods}},
+    }
+
+
+def pod(name, on, cpu=100, mem=500 << 20, selector=None, spread=False):
+    spec = {
+        "node_name": on,
+        "containers": [{"requests": {"milli_cpu": cpu, "memory": mem}}],
+        "node_selector": selector or {},
+        "topology_spread_constraints": [],
+    }
+    labels = {}
+    if spread:
+        labels = {"color": "blue"}
+        spec["topology_spread_constraints"] = [{
+            "max_skew": 1, "topology_key": "topology.kubernetes.io/zone",
+            "when_unsatisfiable": "DoNotSchedule",
+            "label_selector": {"match_labels": {"color": "blue"}, "match_expressions": []},
+        }]
+    return {"metadata": {"name": name, "namespace": "default", "labels": labels}, "spec": spec}
+
+
+NODES = [node("a", "z1"), node("b", "z2"), node("c", "z3"), node("d", "z1", unschedulable=True)]
+SOUND = [pod("p0", "a"), pod("p1", "b", spread=True), pod("p2", "c", spread=True), pod("p3", "a", spread=True)]
+
+
+@pytest.mark.parametrize(
+    "pods,number",
+    [
+        (SOUND, None),
+        (SOUND + [pod(f"x{i}", "a") for i in range(40)], "nodes_over_allocatable"),  # 4.4 cpu on 4
+        (SOUND + [pod("x", "a", mem=33 << 30)], "nodes_over_allocatable"),
+        (SOUND + [pod("x", "d")], "on_unschedulable"),
+        (SOUND + [pod("x", "nowhere")], "on_unknown_node"),
+        (SOUND + [pod("x", "")], "unbound"),
+        (SOUND + [pod("x", "b", selector={"topology.kubernetes.io/zone": "z1"})], "selector_broken"),
+        (SOUND + [pod("x", "a", spread=True), pod("y", "a", spread=True)], "skew_over_max"),
+    ],
+)
+def test_reference_counts_what_the_guarantees_forbid(pods, number):
+    got = reference.violations(NODES, pods)
+    for key in ("unbound", "on_unknown_node", "on_unschedulable", "selector_broken", "nodes_over_allocatable"):
+        assert (got[key] > 0) == (key == number), (key, got)
+    assert (got["skew_over_max"] > 0) == (number == "skew_over_max"), got
+
+
+def acks_for(pods, **change):
+    acks = {"acks": {p["metadata"]["name"]: p["spec"]["node_name"] for p in pods},
+            "sent": [p["metadata"]["name"] for p in pods], "rebinds": []}
+    acks.update(change)
+    return acks
+
+
+def test_audit_accepts_a_sound_placement():
+    compared = audit.checks(NODES, SOUND, acks_for(SOUND), len(NODES), {"wave_parked": 0})
+    assert audit.verdict(compared), compared
+
+
+@pytest.mark.parametrize(
+    "pods,acks,counters,number",
+    [
+        (SOUND + [pod(f"x{i}", "a") for i in range(40)], None, {}, "nodes_over_allocatable"),
+        (SOUND, acks_for(SOUND, acks={"p0": "b", "p1": "b", "p2": "c", "p3": "a"}), {}, "ack_not_on_readback"),
+        (SOUND, acks_for(SOUND, rebinds=[["p0", "a", "b"]]), {}, "acked_twice"),
+        (SOUND, acks_for(SOUND, sent=["p0", "p1", "p2", "p3", "lost"]), {}, "pods_missing"),
+        (SOUND, acks_for(SOUND[:3], sent=["p0", "p1", "p2", "p3"]), {}, "bound_never_acked"),
+        (SOUND + [SOUND[0]], acks_for(SOUND), {}, "pods_twice"),
+        (SOUND, None, {"wave_parked": 1}, "wave_parked"),
+        (SOUND, None, {"compiles_in_window": 2}, "compiles_in_window"),
+    ],
+)
+def test_audit_refuses(pods, acks, counters, number):
+    compared = audit.checks(NODES, pods, acks or acks_for(pods), len(NODES), counters)
+    assert not audit.verdict(compared)
+    assert compared[number][0] > compared[number][1], compared
