@@ -52,6 +52,12 @@ from minisched_tpu.controlplane.store import (
     StorageDegraded,
     WrongShard,
 )
+from minisched_tpu.observability import profiling
+
+profiling.register_spans(
+    "http.create", "http.create_read", "http.create_decode",
+    "http.create_store", "http.create_respond",
+)
 
 
 def _kind_for(collection: str) -> str:
@@ -793,8 +799,17 @@ class _Handler(BaseHTTPRequestHandler):
             except KeyError as e:
                 self._error(404, str(e))
             return
+        # a pod create is the first layer a pod crosses: one span a call
+        # (1 pod or 1,024) with its four stages as children; other kinds
+        # (a cluster's set-up) open none
+        span = profiling.span if kind == "Pod" else profiling.no_span
+        with span("http.create") as whole:
+            self._create(kind, ns, span, whole)
+
+    def _create(self, kind: str, ns: str, span: Any, whole: Any) -> None:
         try:
-            body = self._body()
+            with span("http.create_read"):
+                body = self._body()
         except Exception as e:
             self._error(400, f"malformed body: {e}")
             return
@@ -803,13 +818,16 @@ class _Handler(BaseHTTPRequestHandler):
         # encode with a top-level "items" key).  Per-item errors are
         # returned per entry, like the batch bindings endpoint.
         if isinstance(body, dict) and isinstance(body.get("items"), list):
+            whole.set(n=len(body["items"]))
             self._create_many(
-                kind, ns, body["items"],
+                kind, ns, body["items"], span,
                 return_objects=body.get("return_objects", True),
             )
             return
+        whole.set(n=1)
         try:
-            obj = _decode(REST_KINDS[kind], body)
+            with span("http.create_decode", n=1):
+                obj = _decode(REST_KINDS[kind], body)
         except Exception as e:
             self._error(400, f"malformed body: {e}")
             return
@@ -817,7 +835,10 @@ class _Handler(BaseHTTPRequestHandler):
         if not self._shard_guard(kind, obj.metadata.namespace):
             return
         try:
-            self._send(201, _encode(self.store.create(kind, obj)))
+            with span("http.create_store", n=1):
+                created = self.store.create(kind, obj)
+            with span("http.create_respond", n=1):
+                self._send(201, _encode(created))
         except NotLeader as e:
             self._error(503, str(e))
         except StorageDegraded as e:
@@ -897,7 +918,8 @@ class _Handler(BaseHTTPRequestHandler):
         return entry
 
     def _create_many(
-        self, kind: str, ns: str, items: list, return_objects: bool = True
+        self, kind: str, ns: str, items: list, span: Any,
+        return_objects: bool = True,
     ) -> None:
         """Batch create: decode each item (same namespace fixup as the
         single-object POST), then ONE store transaction
@@ -908,28 +930,37 @@ class _Handler(BaseHTTPRequestHandler):
         {"error", "type"} on conflict/bad input)."""
         out: list = [None] * len(items)
         decoded = []
-        for i, raw in enumerate(items):
-            try:
-                obj = _decode(REST_KINDS[kind], raw)
-            except Exception as e:
-                out[i] = {"error": f"malformed item: {e}", "type": "BadRequest"}
-                continue
-            _fixup_namespace(kind, ns, obj)
-            decoded.append((i, obj))
+        with span("http.create_decode", n=len(items)):
+            for i, raw in enumerate(items):
+                try:
+                    obj = _decode(REST_KINDS[kind], raw)
+                except Exception as e:
+                    out[i] = {
+                        "error": f"malformed item: {e}", "type": "BadRequest"
+                    }
+                    continue
+                _fixup_namespace(kind, ns, obj)
+                decoded.append((i, obj))
         if not self._shard_guard(
             kind, *[o.metadata.namespace for _, o in decoded]
         ):
             return
         try:
-            results = self.store.create_many(
-                kind, [o for _, o in decoded], return_objects=return_objects
-            )
+            with span("http.create_store", n=len(decoded)):
+                results = self.store.create_many(
+                    kind, [o for _, o in decoded],
+                    return_objects=return_objects,
+                )
         except NotLeader as e:
             self._error(503, str(e))
             return
         except StorageDegraded as e:
             self._error(507, str(e))
             return
+        with span("http.create_respond", n=len(items)):
+            self._respond_many(decoded, results, out)
+
+    def _respond_many(self, decoded: list, results: list, out: list) -> None:
         for (i, _), res in zip(decoded, results):
             if isinstance(res, KeyError):
                 out[i] = {"error": str(res), "type": "Conflict"}

@@ -29,7 +29,9 @@ from minisched_tpu.controlplane.store import (
     ObjectStore,
     WatchEvent,
 )
-from minisched_tpu.observability import counters
+from minisched_tpu.observability import counters, profiling
+
+profiling.register_spans("informer.dispatch")
 
 Handler = Callable[[Any], None]
 UpdateHandler = Callable[[Any, Any], None]
@@ -506,16 +508,25 @@ class Informer:
         """One handler over a batch: a registered ``on_batch`` takes the
         whole list in one call; otherwise events dispatch one at a time.
         Every handler sees events in cache order either way."""
-        if h.on_batch is not None:
-            try:
-                h.on_batch(events)
-            except Exception:  # handler errors must not kill the stream
-                import traceback
+        # one span a batch handed to a handler: this is where pods enter
+        # the scheduling queue and bind events reach the cache
+        with (
+            profiling.span(
+                "informer.dispatch", kind=self._kind, n=len(events)
+            )
+            if events
+            else profiling.NO_SPAN
+        ):
+            if h.on_batch is not None:
+                try:
+                    h.on_batch(events)
+                except Exception:  # handler errors must not kill the stream
+                    import traceback
 
-                traceback.print_exc()
-            return
-        for ev in events:
-            self._invoke_one(h, ev)
+                    traceback.print_exc()
+                return
+            for ev in events:
+                self._invoke_one(h, ev)
 
     def _invoke_one(self, h: ResourceEventHandlers, ev: WatchEvent) -> None:
         try:

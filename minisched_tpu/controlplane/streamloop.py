@@ -49,7 +49,9 @@ import time
 import traceback
 from typing import Any, List, Optional
 
-from minisched_tpu.observability import counters
+from minisched_tpu.observability import counters, profiling
+
+profiling.register_spans("watch.deliver")
 
 # safe non-cycle: httpserver imports THIS module only lazily (inside
 # start_api_server), so the wire-framing definitions resolve at module
@@ -343,6 +345,17 @@ class StreamLoop:
                 return
         watch = stream.watch
         events = watch.next_batch(timeout=0)
+        # one span a drained batch: encode into the out-buffer and the
+        # socket write (an idle wake-up opens none)
+        with (
+            profiling.span("watch.deliver", n=len(events))
+            if events
+            else profiling.NO_SPAN
+        ):
+            self._deliver(stream, events)
+
+    def _deliver(self, stream: _Stream, events: list) -> None:
+        watch = stream.watch
         if events:
             from minisched_tpu.observability import hist
 

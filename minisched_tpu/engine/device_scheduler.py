@@ -19,11 +19,13 @@ draws (host control plane / device batch evaluator).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Any, List, Optional
 
 from minisched_tpu.api.objects import Pod
+from minisched_tpu.engine.pipeline import WavePipeline
 from minisched_tpu.engine.scheduler import Scheduler
 from minisched_tpu.framework.types import (
     CycleState,
@@ -42,7 +44,21 @@ from minisched_tpu.models.tables import (
     build_pod_table,
     pad_to,
 )
+from minisched_tpu.observability import profiling
 from minisched_tpu.ops.repair import RepairingEvaluator
+
+# the spans this module opens (the build worker's are pipeline.py's, the
+# scalar tail's — permit, bind — scheduler.py's): on /metrics from boot
+profiling.register_phases(
+    "constraints_lock_wait", "constraints_store_list",
+    "scan_flush", "scan_grouping", "scan_build", "scan_build_nodes",
+    "scan_build_pods", "scan_build_constraints", "scan_evaluate",
+    "scan_dispatch", "scan_fetch",
+    "loop_pop", "loop_gc",
+    "wave", "wave_evaluate", "wave_device", "wave_dispatch", "wave_fetch",
+    "wave_postfetch", "wave_winners", "losers_handle", "commit",
+)
+profiling.register_phases("wave_pipeline_stall", cpu=False)
 
 
 import os as _os
@@ -118,10 +134,17 @@ class DeviceScheduler(Scheduler):
         #: failure re-runs THAT wave on one device, later waves retry
         #: the mesh (see _eval_packed_wave)
         self._mesh_fallback_evaluator: Any = None
-        #: monotonic wave id stamped on trace spans (observability/trace)
-        #: so a pod's enqueue→bind chain joins its wave's build/evaluate
-        #: spans; (pod_shards, node_shards) rides along in mesh mode
+        #: id of the wave the loop thread is on, stamped on trace spans
+        #: (observability/trace) so a pod's enqueue→bind chain joins its
+        #: wave's build/evaluate spans; (pod_shards, node_shards) rides
+        #: along in mesh mode
         self._wave_seq = 0
+        #: where wave ids come from: the build worker draws one at pop,
+        #: the loop thread one for a wave it pops itself (``next`` on a
+        #: count is atomic; ``+= 1`` from two threads is not)
+        self._wave_ids = itertools.count(1)
+        #: the flush's id on its spans (loop thread only)
+        self._scan_call = 0
         self._mesh_shards: Any = None
         if mesh is not None:
             from minisched_tpu.observability import counters
@@ -1225,13 +1248,19 @@ class DeviceScheduler(Scheduler):
                 # gate opens for the device call: held event batches
                 # drain against GIL-free device compute
                 self.informer_factory.resume_dispatch()
-                with self.metrics.timed("scan_evaluate"):
-                    _, choice, _, accepted = (
-                        self._get_blocked_scheduler().call_packed(
-                            pod_table, node_static, node_agg, extra
+                with self.metrics.timed(
+                    "scan_evaluate", call=self._scan_call, n=len(part_live)
+                ):
+                    with self.metrics.timed("scan_dispatch"):
+                        _, choice, _, accepted = (
+                            self._get_blocked_scheduler().call_packed(
+                                pod_table, node_static, node_agg, extra
+                            )
                         )
-                    )
-                    choice, accepted = jax.device_get((choice, accepted))
+                    with self.metrics.timed("scan_fetch"):
+                        choice, accepted = jax.device_get(
+                            (choice, accepted)
+                        )
             else:
                 with self.metrics.timed("scan_build"):
                     node_table, node_names = self._table_builder.build(
@@ -1248,11 +1277,19 @@ class DeviceScheduler(Scheduler):
                         scan_planes=True,
                     )
                 self.informer_factory.resume_dispatch()
-                with self.metrics.timed("scan_evaluate"):
-                    _, choice, _, accepted = self._get_blocked_scheduler()(
-                        pod_table, node_table, extra
-                    )
-                    choice, accepted = jax.device_get((choice, accepted))
+                with self.metrics.timed(
+                    "scan_evaluate", call=self._scan_call, n=len(part_live)
+                ):
+                    with self.metrics.timed("scan_dispatch"):
+                        _, choice, _, accepted = (
+                            self._get_blocked_scheduler()(
+                                pod_table, node_table, extra
+                            )
+                        )
+                    with self.metrics.timed("scan_fetch"):
+                        choice, accepted = jax.device_get(
+                            (choice, accepted)
+                        )
             return node_names, choice.tolist(), accepted.tolist()
 
         live = [m for m in part if m is not None]
@@ -1340,11 +1377,17 @@ class DeviceScheduler(Scheduler):
                             device=False,
                             elide_zeros=False,  # one packed schema per cap
                         )
-                    with self.metrics.timed("scan_evaluate"):
-                        _, choice, _ = self._get_scan_scheduler().call_packed(
-                            pod_table, node_static, node_agg, extra
-                        )
-                        choice = jax.device_get(choice)
+                    with self.metrics.timed(
+                        "scan_evaluate", call=self._scan_call, n=len(pods_)
+                    ):
+                        with self.metrics.timed("scan_dispatch"):
+                            _, choice, _ = (
+                                self._get_scan_scheduler().call_packed(
+                                    pod_table, node_static, node_agg, extra
+                                )
+                            )
+                        with self.metrics.timed("scan_fetch"):
+                            choice = jax.device_get(choice)
                     return node_names, choice.tolist()[: len(pods_)]
                 with self.metrics.timed("scan_build"):
                     node_table, node_names = self._table_builder.build(
@@ -1365,11 +1408,15 @@ class DeviceScheduler(Scheduler):
                     self._record_wave(
                         pods_, pod_table, node_table, node_names, extra
                     )
-                with self.metrics.timed("scan_evaluate"):
-                    _, choice, _ = self._get_scan_scheduler()(
-                        pod_table, node_table, extra
-                    )
-                    choice = jax.device_get(choice)
+                with self.metrics.timed(
+                    "scan_evaluate", call=self._scan_call, n=len(pods_)
+                ):
+                    with self.metrics.timed("scan_dispatch"):
+                        _, choice, _ = self._get_scan_scheduler()(
+                            pod_table, node_table, extra
+                        )
+                    with self.metrics.timed("scan_fetch"):
+                        choice = jax.device_get(choice)
                 return node_names, choice.tolist()[: len(pods_)]
 
             part, result = self._evaluate_or_park(part, build_and_scan)
@@ -1514,17 +1561,14 @@ class DeviceScheduler(Scheduler):
 
         pipe = self._pipeline
         if pipe is None:
-            from minisched_tpu.engine.pipeline import WavePipeline
-
             pipe = self._pipeline = WavePipeline(self)
             pipe.start()
-        t0 = time.monotonic()
         # the worker emits an item at least once per pop window, so this
         # wait is bounded by (pop timeout + one build) — block past the
         # caller's timeout rather than spuriously reporting idle mid-build
-        item = pipe.get(timeout=max(timeout or 0.5, 1.0) + 1.0)
-        wait = time.monotonic() - t0
-        self.metrics.observe("loop_pop", wait)
+        with self.metrics.timed("loop_pop") as handoff:
+            item = pipe.get(timeout=max(timeout or 0.5, 1.0) + 1.0)
+        wait = handoff.wall_s
         prev_was_wave = getattr(self, "_pipe_prev_wave", False)
         self._pipe_prev_wave = item is not None and item[0] == "wave"
         if item is None or item[0] == "empty":
@@ -1532,8 +1576,7 @@ class DeviceScheduler(Scheduler):
                 # queue drained with constrained pods still deferred:
                 # flush the lane now (same as the serial idle path)
                 try:
-                    with self.metrics.timed("scan_flush"):
-                        self._flush_scan_backlog()
+                    self._flush_scan_backlog_timed()
                 finally:
                     with self.metrics.timed("loop_gc"):
                         self._wave_gc()
@@ -1549,8 +1592,8 @@ class DeviceScheduler(Scheduler):
                 # build-stage fallback (encode overflow, empty roster,
                 # priority bypass, injected build fault): the serial wave
                 # path owns every one of those cases already
-                _tag, qpis, partial = item
-                self.schedule_wave(qpis)
+                _tag, qpis, partial, wave_id = item
+                self.schedule_wave(qpis, wave_id)
             else:
                 prepared = item[1]
                 partial = prepared.partial
@@ -1578,8 +1621,7 @@ class DeviceScheduler(Scheduler):
                     if hi > min(
                         q.pod.spec.priority for q in prepared.qpis
                     ):
-                        with self.metrics.timed("scan_flush"):
-                            self._flush_scan_backlog()
+                        self._flush_scan_backlog_timed()
                 self._run_prepared_wave(prepared)
             if self._scan_backlog:
                 self._scan_backlog_waves += 1
@@ -1588,8 +1630,7 @@ class DeviceScheduler(Scheduler):
                     or len(self._scan_backlog) >= self.BLOCKED_MAX_CHUNK
                     or self._scan_backlog_waves >= self.SCAN_DEFER_MAX_WAVES
                 ):
-                    with self.metrics.timed("scan_flush"):
-                        self._flush_scan_backlog()
+                    self._flush_scan_backlog_timed()
         finally:
             with self.metrics.timed("loop_gc"):
                 self._wave_gc()
@@ -1597,12 +1638,10 @@ class DeviceScheduler(Scheduler):
 
     def _run_prepared_wave(self, prepared: Any) -> None:
         # same metric contract as schedule_wave: every exit observes
-        t_wave = time.monotonic()
-        self.metrics.observe("wave_size", float(len(prepared.qpis)))
-        try:
+        with self.metrics.timed(
+            "wave", wave=prepared.wave_id, n=len(prepared.qpis)
+        ):
             self._run_prepared_wave_inner(prepared)
-        finally:
-            self.metrics.observe("wave", time.monotonic() - t_wave)
 
     def _run_prepared_wave_inner(self, prepared: Any) -> None:
         """Device-evaluate a wave the worker built, then re-arbitrate its
@@ -1623,8 +1662,9 @@ class DeviceScheduler(Scheduler):
             # idle-wave gate fired: this wave reused the previous tables
             # wholesale (zero node-table build work; ISSUE 8)
             counters.inc("wave_pipeline.zero_build_waves")
-        self._wave_seq += 1
-        wave_id = self._wave_seq
+        # the id the build worker drew at pop: its sched.wave_build span,
+        # this thread's spans and the trace ring's carry the same number
+        wave_id = self._wave_seq = prepared.wave_id
         trace.span(
             "wave_build", wave=wave_id, size=len(qpis),
             build_s=round(prepared.build_s, 6),
@@ -1638,21 +1678,28 @@ class DeviceScheduler(Scheduler):
         self.informer_factory.resume_dispatch()
         try:
             with self.metrics.timed("wave_evaluate"):
-                with self.metrics.timed("wave_device"):
-                    _, choice, _, unsched = self._eval_packed_wave(
-                        prepared.pod_table,
-                        prepared.node_static,
-                        prepared.node_agg,
-                        prepared.extra,
-                        len(qpis),
-                        len(prepared.node_infos),
-                    )
+                with self.metrics.timed(
+                    "wave_device", wave=wave_id, n=len(qpis)
+                ):
+                    # dispatch: enqueue + H2D, returns before the device
+                    # has run; fetch: the device's run + D2H + getting
+                    # the interpreter lock back from whoever took it
+                    with self.metrics.timed("wave_dispatch"):
+                        _, choice, _, unsched = self._eval_packed_wave(
+                            prepared.pod_table,
+                            prepared.node_static,
+                            prepared.node_agg,
+                            prepared.extra,
+                            len(qpis),
+                            len(prepared.node_infos),
+                        )
                     # where the wave's outputs live, BEFORE the fetch
                     # turns them into host arrays (wave_evaluate span)
                     out_devices = sorted(
                         f"{d.platform}:{d.id}" for d in choice.devices()
                     )
-                    choice, unsched = jax.device_get((choice, unsched))
+                    with self.metrics.timed("wave_fetch"):
+                        choice, unsched = jax.device_get((choice, unsched))
                 with self.metrics.timed("wave_postfetch"):
                     unsched = unsched.tolist()
                     plugin_names = [p.name() for p in self.filter_plugins]
@@ -1789,8 +1836,7 @@ class DeviceScheduler(Scheduler):
                 # flush the lane now (the backlog, not the queue, holds
                 # the remaining work)
                 try:
-                    with self.metrics.timed("scan_flush"):
-                        self._flush_scan_backlog()
+                    self._flush_scan_backlog_timed()
                 finally:
                     with self.metrics.timed("loop_gc"):
                         self._wave_gc()
@@ -1820,14 +1866,22 @@ class DeviceScheduler(Scheduler):
                     or len(self._scan_backlog) >= self.BLOCKED_MAX_CHUNK
                     or self._scan_backlog_waves >= self.SCAN_DEFER_MAX_WAVES
                 ):
-                    with self.metrics.timed("scan_flush"):
-                        self._flush_scan_backlog()
+                    self._flush_scan_backlog_timed()
         finally:
             # every exit path (incl. scan-only waves and early returns)
             # collects; schedule_wave's own call was only on the main path
             with self.metrics.timed("loop_gc"):
                 self._wave_gc()
         return True
+
+    def _flush_scan_backlog_timed(self) -> None:
+        """The flush as the loop's own phase (``scan_flush``); the wave's
+        priority bypass calls the flush bare, inside its ``wave`` phase."""
+        self._scan_call += 1
+        with self.metrics.timed(
+            "scan_flush", call=self._scan_call, n=len(self._scan_backlog)
+        ):
+            self._flush_scan_backlog()
 
     def _flush_scan_backlog(self) -> None:
         """Run the deferred cross-pod lane over everything accumulated.
@@ -1935,24 +1989,28 @@ class DeviceScheduler(Scheduler):
                 qpi.pod_info.pod = cur_cache
             self.error_func(qpi, err)
 
-    def schedule_wave(self, qpis: List[QueuedPodInfo]) -> None:
+    def _next_wave_id(self) -> int:
+        return next(self._wave_ids)
+
+    def schedule_wave(
+        self, qpis: List[QueuedPodInfo], wave_id: Optional[int] = None
+    ) -> None:
+        """``wave_id``: the id the build worker drew when it popped a
+        batch it then handed back raw; a wave popped here draws its own."""
         # the 'wave' metric must observe EVERY exit path (empty-node
         # return, parked batch, scan-only wave, a raise) — the bench's
         # e2e accounting asserts pop+wave+scan_flush+gc sums to the loop
         # wall, and an invisible exit breaks the invariant (advisor r5)
         t_wave = time.monotonic()
-        self._wave_seq += 1
+        self._wave_seq = wave_id or self._next_wave_id()
         from minisched_tpu.observability import trace
 
         trace.span(
             "wave_build", wave=self._wave_seq, size=len(qpis),
             serial=True, mesh=self._mesh_shards,
         )
-        self.metrics.observe("wave_size", float(len(qpis)))
-        try:
+        with self.metrics.timed("wave", wave=self._wave_seq, n=len(qpis)):
             self._schedule_wave_inner(qpis, t_wave)
-        finally:
-            self.metrics.observe("wave", time.monotonic() - t_wave)
 
     def _schedule_wave_inner(
         self, qpis: List[QueuedPodInfo], t_wave: float
@@ -2122,20 +2180,24 @@ class DeviceScheduler(Scheduler):
         # the device call releases the GIL for the whole evaluation —
         # let the event handlers for the previous wave's binds run there
         self.informer_factory.resume_dispatch()
-        with self.metrics.timed("wave_device"):
-            if packed_mode:
-                _, choice, _, unsched = self._eval_packed_wave(
-                    pod_table, node_static, node_agg, extra,
-                    len(pods_), len(node_infos),
-                )
-            else:
-                _, choice, _, unsched = self._get_evaluator()(
-                    pod_table, node_table, extra
-                )
+        with self.metrics.timed(
+            "wave_device", wave=self._wave_seq, n=len(pods_)
+        ):
+            with self.metrics.timed("wave_dispatch"):
+                if packed_mode:
+                    _, choice, _, unsched = self._eval_packed_wave(
+                        pod_table, node_static, node_agg, extra,
+                        len(pods_), len(node_infos),
+                    )
+                else:
+                    _, choice, _, unsched = self._get_evaluator()(
+                        pod_table, node_table, extra
+                    )
             # ONE host fetch for both results (each device_get is a
             # blocking device→host copy); bool[K, P] → per-pod
             # failing-plugin sets
-            choice, unsched = jax.device_get((choice, unsched))
+            with self.metrics.timed("wave_fetch"):
+                choice, unsched = jax.device_get((choice, unsched))
         with self.metrics.timed("wave_postfetch"):
             unsched = unsched.tolist()
             plugin_names = [p.name() for p in self.filter_plugins]
@@ -2163,7 +2225,6 @@ class DeviceScheduler(Scheduler):
         will consume the capacity they freed) — otherwise several losers
         select the same victims and over-evict.
         """
-        self.metrics.observe("wave_losers", float(len(losers)))
         with self.metrics.timed("losers_handle"):
             self._handle_wave_losers_inner(losers, node_infos, n_nodes)
 
@@ -2224,7 +2285,6 @@ class DeviceScheduler(Scheduler):
         # ONE full merged snapshot (informer state + this wave's assumed
         # winners); per-loser deltas (evictions, phantoms) are applied
         # incrementally to just the touched NodeInfos
-        self.metrics.observe("wave_preempt_eligible", float(len(eligible)))
         base = self._merged_infos(node_infos)
         by_name = {ni.name: ni for ni in base}
         # a wave processes at most MAX_PREEMPT_PER_WAVE losers through the
@@ -2356,7 +2416,7 @@ class DeviceScheduler(Scheduler):
 
         ``winners``: (qpi, pod, node_name) triples, already assumed.
         """
-        with self.metrics.timed("commit"):
+        with self.metrics.timed("commit", wave=self._wave_seq, n=len(winners)):
             self._commit_winners_inner(winners)
 
     def _commit_winners_inner(self, winners: List[Any]) -> None:
@@ -2371,6 +2431,26 @@ class DeviceScheduler(Scheduler):
             state = CycleState()
             ready = [(qpi, pod, node_name, state) for qpi, pod, node_name in winners]
             winners = []
+        if winners:
+            # one span over the wave's reserve and permit chains, never
+            # one a pod (the default full roster has neither chain)
+            with self.metrics.timed("permit", n=len(winners)):
+                ready += self._reserve_and_permit(winners)
+        if not ready:
+            return
+        # the batch bind runs ON the engine thread: a worker-thread
+        # pipeline was tried and regressed ~40% — the bind is pure-Python
+        # host work, so overlapping it with the next wave's (also
+        # Python) snapshot/build just thrashes the GIL.  The informer
+        # dispatch of its events naturally overlaps the next wave's
+        # GIL-free device call instead.
+        self._bind_batch(ready)
+
+    def _reserve_and_permit(self, winners: List[Any]) -> List[Any]:
+        """Reserve → permit for each placed pod; returns the ones ready to
+        bind now.  A pod a permit plugin parked in Wait gets its detached
+        binding cycle here; a refused one goes through error_func."""
+        ready: List[Any] = []
         for qpi, pod, node_name in winners:
             state = CycleState()
             status = self.run_reserve_plugins(state, pod, node_name)
@@ -2379,8 +2459,7 @@ class DeviceScheduler(Scheduler):
                 if self.on_decision:
                     self.on_decision(pod, None, status)
                 continue
-            with self.metrics.timed("permit"):
-                status = self.run_permit_plugins(state, pod, node_name)
+            status = self.run_permit_plugins(state, pod, node_name)
             if not status.is_success() and not status.is_wait():
                 self.run_unreserve_plugins(state, pod, node_name)
                 self.error_func(qpi, status.as_error(), plugin=status.plugin)
@@ -2405,15 +2484,7 @@ class DeviceScheduler(Scheduler):
                 t.start()
                 continue
             ready.append((qpi, pod, node_name, state))
-        if not ready:
-            return
-        # the batch bind runs ON the engine thread: a worker-thread
-        # pipeline was tried and regressed ~40% — the bind is pure-Python
-        # host work, so overlapping it with the next wave's (also
-        # Python) snapshot/build just thrashes the GIL.  The informer
-        # dispatch of its events naturally overlaps the next wave's
-        # GIL-free device call instead.
-        self._bind_batch(ready)
+        return ready
 
     def _bind_batch(self, ready: List[Any]) -> None:
         from minisched_tpu.api.objects import Binding
@@ -2441,7 +2512,7 @@ class DeviceScheduler(Scheduler):
         # carries placements until the events land); only WHEN it contends
         # for the GIL changes.
         self.informer_factory.pause_dispatch()
-        with self.metrics.timed("bind"):
+        with self.metrics.timed("bind", n=len(ready)):
             try:
                 if self.faults is not None:
                     self.faults.check("engine.bind", str(len(ready)))

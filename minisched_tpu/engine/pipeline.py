@@ -37,8 +37,18 @@ from __future__ import annotations
 
 import queue as _queue
 import threading
-import time
 from typing import Any, List, Optional
+
+from minisched_tpu.observability import profiling
+
+profiling.register_phases(
+    "pipeline_pop",
+    "wave_pipeline_build",
+    "wave_snapshot",
+    "wave_assigned_list",
+    "wave_build_tables",
+    "wave_build_constraints",
+)
 
 
 class PreparedWave:
@@ -57,6 +67,7 @@ class PreparedWave:
         "build_s",
         "dirty_rows",
         "build_skipped",
+        "wave_id",
     )
 
     def __init__(self) -> None:
@@ -75,6 +86,9 @@ class PreparedWave:
         #: nothing dirty, roster epoch unchanged, same assume-delta —
         #: ISSUE 8); the loop thread counts these per wave
         self.build_skipped = False
+        #: one id for this wave on every thread, assigned at pop: the
+        #: build worker's spans, the loop's, and the trace ring's carry it
+        self.wave_id = 0
 
 
 class _BuildFallback(Exception):
@@ -87,8 +101,8 @@ class WavePipeline:
     Items on the handoff queue:
 
     * ``("wave", PreparedWave)`` — tables built, ready for the device.
-    * ``("raw", qpis, partial)`` — build-stage fallback; the loop thread
-      runs the serial ``schedule_wave`` over the original batch.
+    * ``("raw", qpis, partial, wave_id)`` — build-stage fallback; the loop
+      thread runs the serial ``schedule_wave`` over the original batch.
     * ``("empty",)`` — a pop window elapsed with nothing to do; the loop
       thread runs its idle path (lease expiry, backlog flush, gc).
 
@@ -185,29 +199,33 @@ class WavePipeline:
             if not qpis:
                 self._put(("empty",))
                 continue
-            item = self._build_item(qpis, len(qpis) < sched.max_wave)
+            item = self._build_item(
+                qpis, len(qpis) < sched.max_wave, sched._next_wave_id()
+            )
             if not self._put(item):
                 self._strand(item)
                 return
 
-    def _build_item(self, qpis: List[Any], partial: bool):
+    def _build_item(self, qpis: List[Any], partial: bool, wave_id: int):
         from minisched_tpu.observability import counters
 
         try:
-            t0 = time.monotonic()
-            with self._sched.metrics.timed("wave_pipeline_build"):
+            with self._sched.metrics.timed(
+                "wave_pipeline_build", wave=wave_id, n=len(qpis)
+            ) as sp:
                 prepared = self._build(qpis)
             prepared.partial = partial
-            prepared.build_s = time.monotonic() - t0
+            prepared.wave_id = wave_id
+            prepared.build_s = sp.wall_s
             return ("wave", prepared)
         except _BuildFallback:
-            return ("raw", qpis, partial)
+            return ("raw", qpis, partial, wave_id)
         except Exception:
             # encode overflow (ValueError), an injected store fault in
             # the constraint build, anything unforeseen: the serial path
             # owns the retry/park machinery for all of them
             counters.inc("wave_pipeline.build_fallback")
-            return ("raw", qpis, partial)
+            return ("raw", qpis, partial, wave_id)
 
     def _build(self, qpis: List[Any]) -> PreparedWave:
         from minisched_tpu.engine.device_scheduler import _is_cross_pod

@@ -48,7 +48,15 @@ from minisched_tpu.framework.types import (
     is_success,
 )
 from minisched_tpu.models.tables import pod_seed
+from minisched_tpu.observability import profiling
 from minisched_tpu.queue.queue import SchedulingQueue
+
+# the scalar cycle's spans (one pod is its whole wave); permit and bind
+# are also the wave engine's children of sched.wave_commit
+profiling.register_phases(
+    "snapshot", "schedule", "permit", "wait_on_permit", "bind"
+)
+profiling.register_phases("cycle", "cycle_failed", cpu=False)
 
 
 # ---------------------------------------------------------------------------
@@ -315,13 +323,11 @@ class Scheduler:
         self._bind_threads: set = set()
         # observability hooks: fn(pod, node_name_or_None, status), and
         # per-phase timing — a REAL CycleMetrics by default (ISSUE 11):
-        # the wave phases it forwards into observability/hist are what
+        # every phase is a span (observability/profiling) whose histograms
         # /metrics serves, and live telemetry must not depend on a bench
         # attaching a collector.  Assign NULL_METRICS to opt out.
         self.on_decision: Optional[Callable[[Any, Optional[str], Status], None]] = None
-        from minisched_tpu.observability.profiling import CycleMetrics
-
-        self.metrics: Any = CycleMetrics()
+        self.metrics: Any = profiling.CycleMetrics()
 
         # incremental NodeInfo cache (upstream cache.Cache analog) — wired
         # BEFORE the queue handlers so a requeued pod's next snapshot

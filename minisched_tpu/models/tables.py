@@ -267,21 +267,28 @@ class PackedCaller:
     evaluator and ship every column as its own buffer.
 
     Schemas are static jit-cache keys, so capacities must follow the same
-    quantization discipline as device-table consumers."""
+    quantization discipline as device-table consumers.
 
-    def __init__(self, consumer):
+    ``name`` is the owning lane's: the jitted function carries it, so a
+    profiler trace's ``XLA Modules`` read ``jit_<name>`` (``jit_wave``,
+    ``jit_scan_blocked``, ``jit_scan_exact``) and tell the lanes apart."""
+
+    def __init__(self, consumer, name: str = "packed"):
         self._consumer = consumer
+        self._name = name
         self._fns: Dict[Tuple, Any] = {}
         #: key → argument shapes of the call that built it (lowered_texts)
         self._avals: Dict[Tuple, Any] = {}
 
-    def lowered_texts(self) -> List[str]:
+    def lowered_texts(self, debug_info: bool = False) -> List[str]:
         """StableHLO text of every program this caller has dispatched,
         re-lowered from the recorded argument shapes (nothing compiles or
         runs).  chip_smoke.py reads it to prove the Mosaic kernel is IN the
-        live wave and scan programs, not merely importable."""
+        live wave and scan programs, not merely importable.  With
+        ``debug_info`` the text carries the locations, and with them the
+        ``jax.named_scope`` names (``select_hosts_xla``)."""
         return [
-            fn.lower(*self._avals[key]).as_text()
+            fn.lower(*self._avals[key]).as_text(debug_info=debug_info)
             for key, fn in list(self._fns.items())
         ]
 
@@ -311,6 +318,7 @@ class PackedCaller:
             )
             return consumer(pods, nodes, extra)
 
+        run.__name__ = run.__qualname__ = self._name
         return jax.jit(run)
 
     def _key(self, pod_packed, node_static, node_agg_packed, ex_schema):

@@ -18,7 +18,7 @@ against their offline sampled percentiles.  Factor-2 resolution is
 coarse for a single sample and plenty for an SLO percentile.
 
 The histogram registry documented here (the lint test in
-tests/test_observability.py greps call sites against THIS docstring,
+tests/test_telemetry.py greps call sites against THIS docstring,
 same contract as counters.py):
 
     sched.time_to_bind_s
@@ -27,11 +27,11 @@ same contract as counters.py):
           QueuedPodInfo), observed at bind ack, labeled
           ``priority=<pod priority>`` — the per-priority-class latency
           breakdown of "Priority Matters"
-    sched.wave_build_s / sched.wave_device_s / sched.wave_commit_s /
-    sched.wave_stall_s
-        — the wave pipeline's phase timers (CycleMetrics forwards these
-          phases here, so any engine with metrics attached feeds the
-          live plane; the engine now defaults to a real CycleMetrics)
+    sched.queue_wait_s
+        — admission→pop per pod: the same queue-owned stamp, read (not
+          consumed) when ``pop_batch`` seals a wave — how long the pod
+          sat in the queue before a wave took it, the part of
+          ``sched.time_to_bind_s`` that is waiting and not service
     http.request_s
         — REST façade request latency, labeled ``verb=``/``route=``
           (route is the low-cardinality shape of the path — kind +
@@ -97,6 +97,81 @@ same contract as counters.py):
           over the shared ladder): the saturation signal the hot
           threshold is judged against, recoverable after a split where
           the cumulative histogram is not
+
+**Spans** (``observability/profiling.span``).  Every span name below owns
+TWO histograms, registered at import of the module that opens it, so a
+scrape shows them with count 0 from boot: ``<span>_s``, its wall time,
+and ``<span>_cpu_s``, the CPU seconds of the thread that ran it.  Wall
+less CPU is time the thread did not run (the interpreter's one lock, the
+device, a socket, a queue).  The same name is the event's name in a
+``jax.profiler`` trace.  One span a batch, a wave or a call — never one
+a pod.  The lint in tests/test_telemetry.py holds every ``span(<name>)`` and
+``metrics.timed(<phase>)`` call site to this list:
+
+    http.create
+        — façade handler thread, one pod-create call (1 pod or 1,024;
+          other kinds open none), id ``n``; children, in order:
+          ``http.create_read`` (body off the socket + JSON parse),
+          ``http.create_decode`` (dicts → objects),
+          ``http.create_store`` (the store transaction and its fanout),
+          ``http.create_respond`` (encode + socket write)
+    watch.deliver
+        — stream-loop thread, one drained batch of watch events for one
+          stream: encode into the out-buffer + socket write; id ``n``
+    informer.dispatch
+        — informer thread, one batch handed to one handler (where pods
+          enter the scheduling queue and bind events reach the cache);
+          ids ``kind``, ``n``
+    sched.queue_pop_wait
+        — build worker blocked in ``pop_batch``: no work was offered
+          (engine phase ``pipeline_pop``)
+    sched.wave_build
+        — build worker, one wave's host build (phase
+          ``wave_pipeline_build``), ids ``wave``, ``n``; children
+          ``sched.wave_snapshot``, ``sched.wave_assigned_list``,
+          ``sched.wave_build_tables``, ``sched.wave_build_constraints``
+          (the serial wave path opens the same children on the loop
+          thread), and under the last ``sched.constraints_lock_wait``,
+          ``sched.constraints_store_list``
+    sched.loop_handoff_wait
+        — loop thread waiting for its next item (phase ``loop_pop``: the
+          pipeline's handoff queue; the scheduling queue itself on the
+          serial path)
+    sched.wave_stall
+        — not a span, one histogram (``_s``): the handoff wait of a wave
+          that followed a wave — the device idle because the next build
+          was not ready (phase ``wave_pipeline_stall``)
+    sched.wave
+        — loop thread, one wave from handoff to committed, ids ``wave``,
+          ``n``; children ``sched.wave_evaluate`` (which holds
+          ``sched.wave_device`` and ``sched.wave_postfetch``),
+          ``sched.wave_winners``, ``sched.wave_commit``,
+          ``sched.losers_handle``
+    sched.wave_device
+        — dispatch + H2D + the device's run + D2H on the host's clock,
+          ids ``wave``, ``n``; children ``sched.wave_dispatch`` (the
+          evaluator call returns: enqueue + H2D) and ``sched.wave_fetch``
+          (``device_get``: the run, D2H, and getting the lock back)
+    sched.wave_commit
+        — ``_commit_winners`` (phase ``commit``), ids ``wave``, ``n``;
+          children ``sched.permit`` (one span over a wave's reserve and
+          permit chains; the default roster has neither) and
+          ``sched.bind`` (the batched bind transaction, id ``n``)
+    sched.scan_flush
+        — loop thread, one flush of the deferred cross-pod lane, ids
+          ``call``, ``n``; children ``sched.scan_grouping``,
+          ``sched.scan_build`` (with ``sched.scan_build_nodes``,
+          ``sched.scan_build_pods``, ``sched.scan_build_constraints``),
+          ``sched.scan_evaluate`` (ids ``call``, ``n``; children
+          ``sched.scan_dispatch`` / ``sched.scan_fetch`` as in the wave)
+    sched.loop_gc
+        — loop thread, the explicit collection at a wave boundary
+    sched.snapshot / sched.schedule / sched.wait_on_permit
+        — the scalar engine's cycle (one pod is its whole wave) and the
+          detached binding cycle of a pod a permit plugin parked
+    sched.cycle / sched.cycle_failed
+        — not spans, one histogram each (``_s``): the scalar engine's
+          whole cycle, named by its outcome after the fact
 
 **Exemplars**: ``observe(..., exemplar="default/pod-1")`` stamps the
 bucket the sample lands in with that string (last writer wins, one per
@@ -197,6 +272,21 @@ class Histograms:
     def __init__(self) -> None:
         self._mu = threading.Lock()
         self._hists: Dict[Tuple[str, LabelsKey], Histogram] = {}
+        #: names that exist from registration on, with count 0 until the
+        #: first observation (and again after ``reset``)
+        self._registered: Dict[str, None] = {}
+        #: bumped by ``reset``: whoever keeps a child (``child``) instead
+        #: of going through ``observe`` drops it when this has moved
+        self.generation = 0
+
+    def register(self, *names: str) -> None:
+        """Create the unlabeled child of each name now, so a scrape shows
+        the series from boot: a reader that differences two scrapes then
+        never meets a histogram that appeared in between."""
+        with self._mu:
+            for name in names:
+                self._registered[name] = None
+                self._hists.setdefault((name, ()), Histogram())
 
     def _child(self, name: str, labels: Dict[str, str]) -> Histogram:
         key = (name, tuple(sorted(labels.items())))
@@ -205,6 +295,11 @@ class Histograms:
             if h is None:
                 h = self._hists[key] = Histogram()
         return h
+
+    def child(self, name: str) -> Histogram:
+        """The unlabeled child of ``name``, for a hot call site to keep
+        and observe directly; valid while ``generation`` stands."""
+        return self._child(name, {})
 
     def observe(
         self,
@@ -280,7 +375,8 @@ class Histograms:
 
     def reset(self) -> None:
         with self._mu:
-            self._hists.clear()
+            self._hists = {(n, ()): Histogram() for n in self._registered}
+            self.generation += 1
 
 
 GLOBAL = Histograms()
@@ -290,6 +386,10 @@ def observe(
     name: str, v: float, exemplar: Optional[str] = None, **labels: str
 ) -> None:
     GLOBAL.observe(name, v, exemplar=exemplar, **labels)
+
+
+def register(*names: str) -> None:
+    GLOBAL.register(*names)
 
 
 def quantile_bounds(name: str, q: float) -> Optional[Tuple[float, float]]:

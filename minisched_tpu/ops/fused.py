@@ -179,23 +179,27 @@ def select_hosts(scores, mask, seeds):
             return select_hosts_pallas(
                 scores, mask, seeds, interpret=_FORCE_PALLAS_ROUTE
             )
-    P, N = scores.shape
-    masked = jnp.where(mask, scores, NEG_INF_SCORE)
-    best = masked.max(axis=1)  # i32[P]
-    cand = mask & (masked == best[:, None])
-    h = mix32(seeds[:, None], jnp.arange(N, dtype=jnp.uint32)[None, :])
-    hkey = jnp.where(cand, h, UINT32_MAX)
-    minh = hkey.min(axis=1)
-    # among positions achieving the min hash, prefer real candidates (guards
-    # the pathological h == UINT32_MAX collision), then the lowest index
-    is_min = hkey == minh[:, None]
-    pref = is_min & cand
-    has_pref = pref.any(axis=1)
-    pick_from = jnp.where(has_pref[:, None], pref, is_min)
-    choice = jnp.argmax(pick_from, axis=1).astype(jnp.int32)
-    feasible_any = mask.any(axis=1)
-    choice = jnp.where(feasible_any, choice, jnp.int32(-1))
-    best = jnp.where(feasible_any, best, jnp.int32(0))
+    # the XLA tail under its own name: in a device trace its fusions read
+    # select_hosts_xla/..., beside the Mosaic kernel's select_hosts
+    with jax.named_scope("select_hosts_xla"):
+        P, N = scores.shape
+        masked = jnp.where(mask, scores, NEG_INF_SCORE)
+        best = masked.max(axis=1)  # i32[P]
+        cand = mask & (masked == best[:, None])
+        h = mix32(seeds[:, None], jnp.arange(N, dtype=jnp.uint32)[None, :])
+        hkey = jnp.where(cand, h, UINT32_MAX)
+        minh = hkey.min(axis=1)
+        # among positions achieving the min hash, prefer real candidates
+        # (guards the pathological h == UINT32_MAX collision), then the
+        # lowest index
+        is_min = hkey == minh[:, None]
+        pref = is_min & cand
+        has_pref = pref.any(axis=1)
+        pick_from = jnp.where(has_pref[:, None], pref, is_min)
+        choice = jnp.argmax(pick_from, axis=1).astype(jnp.int32)
+        feasible_any = mask.any(axis=1)
+        choice = jnp.where(feasible_any, choice, jnp.int32(-1))
+        best = jnp.where(feasible_any, best, jnp.int32(0))
     return choice, best
 
 

@@ -205,6 +205,7 @@ def nodenumber_select_hosts(
             pltpu.VMEM((pod_tile, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="nodenumber_select_hosts",
     )(
         nodes.unschedulable[None, :],
         nodes.suffix[None, :],
@@ -255,5 +256,6 @@ def select_hosts_pallas(scores, mask, seeds, interpret: bool = False):
             pltpu.VMEM((pod_tile, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="select_hosts",  # the kernel's name in a device trace
     )(scores, mask, seeds[:, None])
     return choice[:, 0], best[:, 0]

@@ -528,11 +528,13 @@ class RepairingEvaluator:
             if self._mesh is not None:
                 from minisched_tpu.parallel.sharding import MeshPackedCaller
 
-                self._packed_caller = MeshPackedCaller(consume, self._mesh)
+                self._packed_caller = MeshPackedCaller(
+                    consume, self._mesh, "wave"
+                )
             else:
                 from minisched_tpu.models.tables import PackedCaller
 
-                self._packed_caller = PackedCaller(consume)
+                self._packed_caller = PackedCaller(consume, "wave")
         return self._packed_caller(
             pod_packed, node_static, node_agg_packed, extra_packed
         )

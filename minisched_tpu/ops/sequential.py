@@ -620,18 +620,18 @@ def blocked_scan_schedule(
     )
 
 
-def _make_packed_caller(consume, mesh: Any):
+def _make_packed_caller(consume, mesh: Any, name: str):
     """PackedCaller for the scan lanes: single-device by default; under
     a mesh the scan layout (node axis sharded, pods replicated — the
     scan is sequential over pods by construction, so only the node-side
-    reductions parallelize)."""
+    reductions parallelize).  ``name`` names the lane's programs."""
     if mesh is not None:
         from minisched_tpu.parallel.sharding import MeshPackedCaller
 
-        return MeshPackedCaller(consume, mesh, scan_layout=True)
+        return MeshPackedCaller(consume, mesh, name, scan_layout=True)
     from minisched_tpu.models.tables import PackedCaller
 
-    return PackedCaller(consume)
+    return PackedCaller(consume, name)
 
 
 class BlockedSequentialScheduler:
@@ -697,7 +697,9 @@ class BlockedSequentialScheduler:
                     block_size=block_size,
                 )
 
-            self._packed_caller = _make_packed_caller(consume, self._mesh)
+            self._packed_caller = _make_packed_caller(
+                consume, self._mesh, "scan_blocked"
+            )
         return self._packed_caller(
             pod_packed, node_static, node_agg_packed, extra_packed
         )
@@ -767,7 +769,9 @@ class SequentialScheduler:
                     extra=extra,
                 )
 
-            self._packed_caller = _make_packed_caller(consume, self._mesh)
+            self._packed_caller = _make_packed_caller(
+                consume, self._mesh, "scan_exact"
+            )
         return self._packed_caller(
             pod_packed, node_static, node_agg_packed, extra_packed
         )

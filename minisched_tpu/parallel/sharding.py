@@ -542,7 +542,9 @@ class MeshPackedCaller:
     additionally carries the mesh factoring (and the layout flag), so an
     executable compiled for one mesh never serves another."""
 
-    def __init__(self, consumer, mesh: Mesh, scan_layout: bool = False):
+    def __init__(
+        self, consumer, mesh: Mesh, name: str, scan_layout: bool = False
+    ):
         from minisched_tpu.models.tables import PackedCaller
 
         self._mesh = mesh
@@ -568,7 +570,7 @@ class MeshPackedCaller:
                     pod_packed, node_static, node_agg_packed, extra_packed
                 )
 
-        self._inner = _Caller(consumer)
+        self._inner = _Caller(consumer, name)
 
     def __call__(self, pod_packed, node_static, node_agg_packed,
                  extra_packed=None):
@@ -576,8 +578,8 @@ class MeshPackedCaller:
             pod_packed, node_static, node_agg_packed, extra_packed
         )
 
-    def lowered_texts(self):
-        return self._inner.lowered_texts()
+    def lowered_texts(self, debug_info: bool = False):
+        return self._inner.lowered_texts(debug_info)
 
     def _build_sharded_fn(self, pod_packed, node_static, node_agg_packed,
                           extra_packed):
@@ -637,6 +639,7 @@ class MeshPackedCaller:
                     )
             return consumer(pods, nodes, extra)
 
+        run.__name__ = run.__qualname__ = self._inner._name
         jitted = jax.jit(
             run,
             # flat wire buffers replicate; statics arrive pre-sharded.

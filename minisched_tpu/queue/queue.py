@@ -57,6 +57,8 @@ from minisched_tpu.framework.events import (
 )
 from minisched_tpu.framework.types import PodInfo, QueuedPodInfo
 
+hist.register("sched.queue_wait_s")  # on /metrics from boot
+
 DEFAULT_INITIAL_BACKOFF_S = 1.0  # queue.go:219
 DEFAULT_MAX_BACKOFF_S = 10.0  # queue.go:220
 DEFAULT_UNSCHEDULABLE_TIMEOUT_S = 60.0  # upstream unschedulableQTimeInterval
@@ -735,6 +737,15 @@ class SchedulingQueue:
                 for ns in dict.fromkeys(pending + released):
                     self._promote_held_locked(ns)
         if batch:
+            # how long each pod sat here before a wave took it: the part
+            # of sched.time_to_bind_s that is waiting and not service (a
+            # requeued pod's stamp is its first admission's, as there)
+            now = self._clock()
+            stamps = self._arrival_ts
+            for qpi in batch:
+                t0 = stamps.get(self._uid(qpi.pod))
+                if t0 is not None:
+                    hist.observe("sched.queue_wait_s", max(now - t0, 0.0))
             _sort_gangs_adjacent(batch)
         return batch
 

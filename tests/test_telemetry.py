@@ -193,6 +193,22 @@ _COUNTER_CALL = re.compile(
     r"""counters\.(?:inc|set_gauge)\(\s*["']([^"']+)["']"""
 )
 _HIST_CALL = re.compile(r"""hist\.observe\(\s*\n?\s*["']([^"']+)["']""")
+# profiling.span("http.create", ...) and a local alias span("...") — a span
+# name has a dot, which the trace ring's trace.span("wave_build") has not
+_SPAN_CALL = re.compile(r"""\bspan\(\s*\n?\s*["']([a-z_]+\.[a-z_.]+)["']""")
+# metrics.timed("phase", ...) / metrics.observe("phase", dt): the span is
+# profiling.span_name(phase)
+_PHASE_CALL = re.compile(
+    r"""metrics\.(?:timed|observe)\(\s*\n?\s*["']([a-z_]+)["']"""
+)
+
+
+def _span_names(src: str):
+    from minisched_tpu.observability import profiling
+
+    return _SPAN_CALL.findall(src) + [
+        profiling.span_name(phase) for phase in _PHASE_CALL.findall(src)
+    ]
 
 
 def _py_sources():
@@ -210,8 +226,10 @@ def _py_sources():
 def test_every_metric_name_is_documented():
     """Registry lint: any ``counters.inc("x")`` / ``set_gauge`` name must
     appear in counters.py's module docstring, any ``hist.observe("x")``
-    name in hist.py's — the docstrings ARE the metric registry, and an
-    undocumented metric is a scrape nobody can interpret."""
+    name in hist.py's, and so must the name of any span opened with
+    ``profiling.span("x")`` or ``metrics.timed("phase")`` — the docstrings
+    ARE the metric registry, and an undocumented metric is a scrape nobody
+    can interpret."""
     counter_doc = counters.__doc__ or ""
     hist_doc = hist.__doc__ or ""
     missing = []
@@ -227,18 +245,41 @@ def test_every_metric_name_is_documented():
         for name in _HIST_CALL.findall(src):
             if name not in hist_doc:
                 missing.append(f"{rel}: histogram {name!r} not in hist.py doc")
+        for name in _span_names(src):
+            if name not in hist_doc:
+                missing.append(f"{rel}: span {name!r} not in hist.py doc")
     assert not missing, "\n".join(missing)
+
+
+def test_an_undocumented_span_name_fails_the_lint():
+    """The span half of the lint on a source written here: a dotted span
+    literal and an engine phase are both seen, and neither is in the
+    registry docstring."""
+    src = (
+        'with profiling.span("bogus.layer", n=1):\n    pass\n'
+        'with self.metrics.timed(\n    "bogus_phase", wave=1\n):\n    pass\n'
+        'trace.span("wave_build", wave=1)\n'
+    )
+    assert _span_names(src) == ["bogus.layer", "sched.bogus_phase"]
+    assert not any(name in (hist.__doc__ or "") for name in _span_names(src))
 
 
 def test_lint_scanner_actually_sees_call_sites():
     """Guard the guard: the regexes must match the tree's real call
     idioms, or the lint above passes vacuously."""
-    seen_counters, seen_hists = set(), set()
+    seen_counters, seen_hists, seen_spans = set(), set(), set()
     for path in _py_sources():
         with open(path, encoding="utf-8") as f:
             src = f.read()
         seen_counters.update(_COUNTER_CALL.findall(src))
         seen_hists.update(_HIST_CALL.findall(src))
+        seen_spans.update(_span_names(src))
+    assert {
+        "http.create", "http.create_decode", "watch.deliver",
+        "informer.dispatch", "sched.queue_pop_wait", "sched.wave_build",
+        "sched.loop_handoff_wait", "sched.wave_dispatch", "sched.wave_fetch",
+        "sched.wave_commit", "sched.wave_stall", "sched.scan_flush",
+    } <= seen_spans
     assert "wire.pool_open" in seen_counters
     assert "sched.time_to_bind_s" in seen_hists
     assert "watch.delivery_lag_s" in seen_hists
