@@ -10,8 +10,19 @@ tables are never checkpointed — they are reconstructed from the store
 (SURVEY.md §5.4 "cluster state store is the checkpoint; device arrays are
 reconstructable").
 
-Serialization is generic over the api.objects dataclasses via type-hint
-recursion, so new spec fields checkpoint automatically.
+Serialization is generic over the api.objects dataclasses, so new spec
+fields checkpoint automatically and need no edit here.  Encoding walks the
+object.  Decoding goes through a *plan*: a callable ``data -> object``
+derived ONCE per type from its annotation and kept for the life of the
+process (``_plan_for``) — a dataclass resolves its ``get_type_hints`` and
+``dataclasses.fields`` once and holds one plan per field, ``Optional[X]``
+is the plan of ``X``, ``List[X]`` / ``Tuple[X, ...]`` and ``Dict[K, V]``
+build a new list / dict over the plan of ``X`` / ``V``, and anything else
+(``str``, ``int``, ``Any``) is taken as it is.  api/objects.py is written
+under ``from __future__ import annotations``, so resolving a dataclass's
+hints compiles every annotation string: per type that is nothing, per
+object of every create it was most of the REST façade's CPU (PERF.md §6,
+PR 27).
 """
 
 from __future__ import annotations
@@ -20,10 +31,11 @@ import dataclasses
 import json
 import os
 import typing
-from typing import Any, Dict, Optional, get_args, get_origin, get_type_hints
+from typing import Any, Callable, Dict, Optional, get_args, get_origin
 
 from minisched_tpu.api import objects
 from minisched_tpu.controlplane.store import ObjectStore
+from minisched_tpu.observability import counters
 
 CHECKPOINT_VERSION = 1
 
@@ -53,28 +65,109 @@ def _encode(obj: Any) -> Any:
     return obj
 
 
-def _decode(tp: Any, data: Any) -> Any:
-    if data is None:
-        return None
+Plan = Callable[[Any], Any]
+
+#: type annotation → its decode plan; written only by ``_plan_for``, with
+#: finished plans, so a reader needs no lock
+_PLANS: Dict[Any, Plan] = {}
+
+
+def _as_is(data: Any) -> Any:
+    """The plan of a type with nothing to rebuild.  A container or a
+    dataclass that holds one copies the value without calling it."""
+    return data
+
+
+def _plan_for(tp: Any) -> Plan:
+    plan = _PLANS.get(tp)
+    if plan is None:
+        # plans reachable from ``tp`` are built aside and published when
+        # all of them are whole: a self-referring type finds its own plan
+        # in ``building``, and no other thread can call a dataclass's
+        # plan before every field is in it.  Two threads racing here
+        # build the same plans; the first to publish one is counted
+        building: Dict[Any, Plan] = {}
+        plan = _build_plan(tp, building)
+        for built_tp, built in building.items():
+            if _PLANS.setdefault(built_tp, built) is built:
+                counters.inc("decode.plans_built")
+        plan = _PLANS.setdefault(tp, plan)
+    return plan
+
+
+def _build_plan(tp: Any, building: Dict[Any, Plan]) -> Plan:
+    plan = _PLANS.get(tp) or building.get(tp)
+    if plan is not None:
+        return plan
     origin = get_origin(tp)
     if origin is typing.Union:  # Optional[X]
-        args = [a for a in get_args(tp) if a is not type(None)]
-        return _decode(args[0], data)
-    if origin in (list, tuple):
-        (item_tp,) = get_args(tp)[:1] or (Any,)
-        return [_decode(item_tp, v) for v in data]
-    if origin is dict:
-        _, val_tp = get_args(tp) or (Any, Any)
-        return {k: _decode(val_tp, v) for k, v in data.items()}
+        # every plan answers None with None, so X's plan is Optional[X]'s
+        return _build_plan(
+            [a for a in get_args(tp) if a is not type(None)][0], building
+        )
     if dataclasses.is_dataclass(tp):
-        hints = get_type_hints(tp)
-        kwargs = {
-            f.name: _decode(hints[f.name], data[f.name])
-            for f in dataclasses.fields(tp)
-            if f.name in data
-        }
+        # registered before its fields are planned: one may be ``tp`` again
+        field_plans: Dict[str, Plan] = {}
+        plan = building[tp] = _dataclass_plan(tp, field_plans)
+        hints = typing.get_type_hints(tp)  # the one call, and once a type
+        for f in dataclasses.fields(tp):
+            field_plans[f.name] = _build_plan(hints[f.name], building)
+    elif origin in (list, tuple):  # a tuple annotation decodes to a list
+        item = _build_plan((get_args(tp) or (Any,))[0], building)
+        plan = building[tp] = _list_plan(item)
+    elif origin is dict:
+        value = _build_plan((get_args(tp) or (Any, Any))[1], building)
+        plan = building[tp] = _dict_plan(value)
+    else:
+        plan = _as_is
+    return plan
+
+
+def _list_plan(item: Plan) -> Plan:
+    if item is _as_is:
+        return lambda data: None if data is None else list(data)
+    return lambda data: None if data is None else [item(v) for v in data]
+
+
+def _dict_plan(value: Plan) -> Plan:
+    if value is _as_is:
+        return lambda data: (
+            None if data is None else {k: v for k, v in data.items()}
+        )
+    return lambda data: (
+        None if data is None else {k: value(v) for k, v in data.items()}
+    )
+
+
+def _dataclass_plan(tp: Any, field_plans: Dict[str, Plan]) -> Plan:
+    """``field_plans`` is filled by the caller after this returns (a field
+    may refer back to ``tp``).  A key that is no field is ignored; a field
+    the data lacks is left to the dataclass's default, so documents from
+    before a field existed still load; data that is no mapping raises."""
+    plan_of = field_plans.get
+
+    def decode(data: Any) -> Any:
+        if data is None:
+            return None
+        kwargs = {}
+        for name, value in data.items():
+            plan = plan_of(name)
+            if plan is _as_is:
+                kwargs[name] = value
+            elif plan is not None:
+                kwargs[name] = plan(value)
         return tp(**kwargs)
-    return data
+
+    return decode
+
+
+def _decode(tp: Any, data: Any) -> Any:
+    return _plan_for(tp)(data)
+
+
+# planned here, not inside somebody's timed window at the first object
+for _tp in KIND_TYPES.values():
+    _plan_for(_tp)
 
 
 def build_snapshot_doc(

@@ -36,7 +36,12 @@ from typing import Any, Callable, Optional, Tuple
 from urllib.parse import parse_qs
 
 from minisched_tpu.api.objects import Binding, Node, Pod
-from minisched_tpu.controlplane.checkpoint import KIND_TYPES, _decode, _encode
+from minisched_tpu.controlplane.checkpoint import (
+    KIND_TYPES,
+    _decode,
+    _encode,
+    _plan_for,
+)
 from minisched_tpu.controlplane.client import (
     AlreadyBound,
     Client,
@@ -75,6 +80,10 @@ def _kind_for(collection: str) -> str:
 from minisched_tpu.api import objects as _objects  # noqa: E402
 
 REST_KINDS = {**KIND_TYPES, "Event": _objects.Event}
+
+# KIND_TYPES were planned as checkpoint was imported: with this one, every
+# kind a request can name has its decode plan before the first request
+_plan_for(_objects.Event)
 
 
 #: kinds stored under namespace "" regardless of URL/body (kube semantics)

@@ -130,6 +130,18 @@ bench records (``scheduler_over_http`` + ``wire_fanout``) alongside the
           double-encode races) and N−1 hits; every snapshot swap
           invalidates wholesale by replacing the cache's owner
 
+The object codec (controlplane/checkpoint: every REST body, watch line,
+checkpoint and WAL record is decoded through a plan kept per type):
+
+    decode.plans_built
+        — dataclass and container types (``Pod``, ``List[Container]``,
+          ``Dict[str, str]``, …) whose decode plan was derived from
+          their annotations: some dozens at import, for every REST
+          kind, and one more a type first met later.  It must stand
+          still while the process serves: a count that moves with the
+          objects decoded means ``get_type_hints`` is back on the
+          per-object path (PERF.md §6, PR 27)
+
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
 
