@@ -11,19 +11,30 @@ Commands (``op``):
 
 ``nodes``   create the configuration's nodes from the seed
 ``init``    open the pod watch, create the init pods, wait for their binds
-``burst``   create ``count`` measured pods at once, wait for their binds
+``burst``   create ``count`` measured pods, at once or in creates of
+            ``chunk``, and wait for their binds
 ``run``     offer one traffic mix for ``seconds``; answers when the window
             closes (what is outstanding then is left to ``grace``)
 ``grace``   wait, at most ``seconds``, for every pod sent to be bound
-``acks``    every bind the watch has carried: pod -> node, and re-binds
+``acks``    every bind the watch has carried: pod -> node, and re-binds;
+            every delete that was answered 200
 ``stop``    close the watch and exit
+
+The objects come from the configuration's maker: the module under
+``makers/`` that ``configs/<name>.json`` names under ``maker``, or
+``cluster`` where it names none; it is loaded once, at ``hello``.
 
 A traffic mix is data (``benchmarks/traffic/<mix>.json``); the two loops
 below are the one general generator that reads it.  ``closed``: keep
 ``outstanding`` pods created and not yet seen bound, topping up in
 ``chunk``s from ``senders`` threads, each on a connection of its own (one
 sender blocks in each create, and the stack binds faster than one stream
-of creates feeds it: the backlog would never form).  ``open``: Poisson arrivals at ``rate_per_s``; the gaps are one
+of creates feeds it: the backlog would never form).  A closed mix that
+sets ``live_target`` also runs ``deleters`` threads, each on a connection
+of its own: whenever more measured pods than that are seen bound and not
+yet deleted, the longest-bound of them is deleted, one ``DELETE`` a pod
+(the API has no collection delete); ``live_pod_cap`` then bounds the pods
+live and not the pods ever sent.  ``open``: Poisson arrivals at ``rate_per_s``; the gaps are one
 fixed sample of the exponential distribution, scaled to the rate, and the
 seed only shuffles their order, so that every seed offers the same
 arrivals in another order; each pod is timed from the instant it was due.
@@ -31,6 +42,8 @@ arrivals in another order; each pod is timed from the instant it was due.
 
 from __future__ import annotations
 
+import collections
+import importlib
 import json
 import os
 import random
@@ -68,6 +81,12 @@ def summary_ms(values_s: List[float]) -> Dict[str, Any]:
     }
 
 
+def load_maker(config: Dict[str, Any]) -> Any:
+    """The module that makes the configuration's objects."""
+    name = config.get("maker")
+    return importlib.import_module("makers." + name if name else "cluster")
+
+
 def arrival_offsets(rate_per_s: float, seconds: float, seed: int) -> List[float]:
     """Offsets from the window's start at which the open loop's pods are
     due: ``round(rate * seconds)`` of them in every seed."""
@@ -89,16 +108,23 @@ class Generator:
 
         self.config = config
         self.seed = seed
+        self.maker = load_maker(config)
         self.client = RemoteClient(base)
         self.pods = self.client.pods()
         self.mu = threading.Condition()
         #: pod name -> (node, instant the watch event was read)
         self.bound: Dict[str, Any] = {}
         self.rebinds: List[Any] = []
+        #: only a mix that deletes keeps these: the measured pods seen bound
+        #: and not yet handed to a deleter, longest-bound first
+        self.live: Optional[collections.deque] = None
+        self.deleted: List[str] = []
+        self.delete_errors = 0
         #: pod name -> the instant it was due (open) or sent (closed)
         self.due: Dict[str, float] = {}
         self.sent_total = 0
         self.serial = 0
+        self.init_prefix = f"s{seed}-init-"
         #: (seconds, instant it began) of the longest create since a run began
         self.slowest_send = (0.0, 0.0)
         self.base = base
@@ -119,7 +145,7 @@ class Generator:
                 if not line:
                     continue
                 msg = json.loads(line)
-                if msg["type"] == "SYNC":
+                if msg["type"] in ("SYNC", "DELETED"):
                     continue
                 node = msg["object"]["spec"]["node_name"]
                 if not node:
@@ -130,6 +156,8 @@ class Generator:
                     seen = self.bound.get(name)
                     if seen is None:
                         self.bound[name] = (node, now)
+                        if self.live is not None and not name.startswith(self.init_prefix):
+                            self.live.append(name)
                         self.mu.notify_all()
                     elif seen[0] != node:
                         self.rebinds.append([name, seen[0], node])
@@ -179,12 +207,10 @@ class Generator:
         start: Optional[int] = None,
         pods_api: Any = None,
     ) -> List[str]:
-        import cluster
-
         if start is None:
             with self.mu:
                 start = self._reserve(count)
-        pods = cluster.make_pods(self.config[kind], f"s{self.seed}-{phase}", start, count)
+        pods = self.maker.make_pods(self.config[kind], f"s{self.seed}-{phase}", start, count)
         names = [p.metadata.name for p in pods]
         t_send = time.monotonic()
         with self.mu:
@@ -211,9 +237,7 @@ class Generator:
 
     # -- commands ------------------------------------------------------
     def nodes(self, cmd: Dict[str, Any]) -> Dict[str, Any]:
-        import cluster
-
-        nodes = cluster.make_nodes(self.config, self.seed)
+        nodes = self.maker.make_nodes(self.config, self.seed)
         for i in range(0, len(nodes), 1000):
             self.client.nodes().create_many(nodes[i : i + 1000], return_objects=False)
         return {"nodes": len(nodes)}
@@ -233,17 +257,23 @@ class Generator:
 
     def burst(self, cmd: Dict[str, Any]) -> Dict[str, Any]:
         t0 = time.monotonic()
-        self._send("measured_pods", "warm", cmd["count"])
+        count = cmd["count"]
+        chunk = cmd.get("chunk") or count
+        for i in range(0, count, chunk):
+            self._send("measured_pods", "warm", min(chunk, count - i))
         self.wait_all_bound(cmd["deadline_s"])
         return {"unbound": self.outstanding(), "seconds": time.monotonic() - t0}
 
     def _closed_loop(self, traffic: Dict[str, Any], phase: str, t_end: float, names: List[str]) -> None:
         """``senders`` threads keep ``outstanding`` pods created and not yet
         seen bound.  A chunk counts as outstanding from the moment a sender
-        takes it, so the threads together never pass the target."""
+        takes it, so the threads together never pass the target.  With
+        ``live_target`` set, ``deleters`` threads hold the measured pods
+        seen bound to that many, and the cap counts the pods live."""
         from minisched_tpu.controlplane.remote import RemoteClient
 
         target, chunk = traffic["outstanding"], traffic["chunk"]
+        live_target = traffic.get("live_target")
         cap = self.config["live_pod_cap"]
         errors: List[BaseException] = []
 
@@ -253,8 +283,8 @@ class Generator:
             try:
                 while time.monotonic() < t_end and not errors:
                     with self.mu:
-                        need = min(chunk, cap - self.sent_total)
-                        if need <= 0 and self.outstanding() == 0:
+                        need = min(chunk, cap - self.sent_total + len(self.deleted))
+                        if need <= 0 and self.outstanding() == 0 and not live_target:
                             return  # the cluster is full: the window ends here
                         if need <= 0 or target - self.outstanding() < need:
                             self.mu.wait(0.005)
@@ -268,10 +298,48 @@ class Generator:
             finally:
                 client.store.close()
 
+        def deleter() -> None:
+            client = RemoteClient(self.base)
+            pods_api = client.pods()
+            try:
+                while time.monotonic() < t_end and not errors:
+                    with self.mu:
+                        if len(self.live) <= live_target:
+                            self.mu.wait(0.005)
+                            continue
+                        name = self.live.popleft()
+                    try:
+                        pods_api.delete(name)
+                    except KeyError:  # the façade's 404: the pod was not there to delete
+                        with self.mu:
+                            self.delete_errors += 1
+                        continue
+                    with self.mu:
+                        self.deleted.append(name)
+                        self.mu.notify_all()  # a sender may be waiting under the cap
+            except BaseException as err:
+                errors.append(err)
+            finally:
+                client.store.close()
+
         threads = [
             threading.Thread(target=sender, name=f"sender-{i}", daemon=True)
             for i in range(traffic["senders"])
         ]
+        if live_target:
+            with self.mu:
+                if self.live is None:
+                    # everything measured that is bound so far (the fill, the
+                    # warm bursts), in the order it was bound; the watch's
+                    # reader appends from here on
+                    by_instant = sorted(
+                        (t, n) for n, (_node, t) in self.bound.items() if not n.startswith(self.init_prefix)
+                    )
+                    self.live = collections.deque(n for _t, n in by_instant)
+            threads += [
+                threading.Thread(target=deleter, name=f"deleter-{i}", daemon=True)
+                for i in range(traffic["deleters"])
+            ]
         for t in threads:
             t.start()
         for t in threads:
@@ -287,6 +355,7 @@ class Generator:
         t0 = time.monotonic()
         t_end = t0 + seconds
         self.slowest_send = (0.0, t0)
+        deleted_before = len(self.deleted)
         if traffic["loop"] == "closed":
             self._closed_loop(traffic, phase, t_end, names)
         elif traffic["loop"] == "open":
@@ -314,6 +383,10 @@ class Generator:
                 1 for _node, t in self.bound.values() if t0 <= t < t_close
             )
             outstanding = self.outstanding()
+            deleting = {} if self.live is None else {
+                "deleted_in_window": len(self.deleted) - deleted_before,
+                "live_at_close": len(self.live),
+            }
         self.last = {"names": names, "late": late, "t0": t0, "t_close": t_close}
         return {
             "window_s": t_close - t0,
@@ -322,6 +395,7 @@ class Generator:
             "outstanding_at_close": outstanding,
             "slowest_create_s": self.slowest_send[0],
             "slowest_create_at_s": self.slowest_send[1] - t0,
+            **deleting,
         }
 
     def grace(self, cmd: Dict[str, Any]) -> Dict[str, Any]:
@@ -352,6 +426,8 @@ class Generator:
                 "acks": {name: node for name, (node, _t) in self.bound.items()},
                 "sent": sorted(self.due),
                 "rebinds": self.rebinds,
+                "deleted": self.deleted,
+                "delete_errors": self.delete_errors,
             }
 
     def stop(self, cmd: Dict[str, Any]) -> Dict[str, Any]:

@@ -1,10 +1,11 @@
 """The host half of a ``jax.profiler`` trace: the program's spans.
 
-``tracefile.load`` keeps the device planes.  The program opens a
+``tracefile.load`` keeps the device planes and, through ``spans`` here,
+these beside them as ``Trace.host``.  The program opens a
 ``jax.profiler.TraceAnnotation`` at every layer boundary
 (``minisched_tpu/observability/profiling.span``); with the options
 ``run.py`` sets they land on the plane ``/host:CPU``, one line a thread,
-every line named after the process.  ``load`` keeps those events as
+every line named after the process.  ``spans`` keeps those events as
 ``(name, start_ns, duration_ns, line, stats)``: ``line`` is the line's
 index in the plane (a thread is found by the spans on it), ``stats`` the
 span's ids (``wave``, ``n``, ...).  Device and host planes of one file
@@ -13,9 +14,7 @@ share one clock; ``started_inside`` shows it instead of assuming it.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Sequence, Tuple
-
-import tracefile
+from typing import Any, Dict, Iterable, List, Sequence, Tuple
 
 HOST_PLANE = "/host:CPU"
 #: a span's name starts with its layer (the benchmark imports nothing of
@@ -25,10 +24,8 @@ PREFIXES = ("sched.", "http.", "watch.", "informer.")
 HostEvent = Tuple[str, int, int, int, Dict[str, object]]
 
 
-def load(trace_dir: str) -> List[HostEvent]:
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(tracefile.newest_xplane(trace_dir))
+def spans(data: Any) -> List[HostEvent]:
+    """The spans of a trace that ``jax.profiler.ProfileData`` has read."""
     out: List[HostEvent] = []
     for plane in data.planes:
         if plane.name != HOST_PLANE:

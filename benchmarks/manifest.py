@@ -120,6 +120,12 @@ def check(manifest: Dict[str, Any]) -> List[str]:
             bad.append(f"config {c['name']}: {c['file']} is outside paths")
         for key in c["reduced"]:
             name_ok(f"{c['name']}.reduced", key)
+        if os.path.isfile(os.path.join(ROOT, c["file"])):
+            with open(os.path.join(ROOT, c["file"])) as f:
+                data = json.load(f)
+            for door, folder in (("maker", "makers"), ("reference", "references")):
+                if data.get(door) and not os.path.isfile(os.path.join(HERE, folder, data[door] + ".py")):
+                    bad.append(f"config {c['name']}: no benchmarks/{folder}/{data[door]}.py")
         if not any(w["config"] == c["name"] for w in manifest["workloads"]):
             bad.append(f"config {c['name']}: no cell uses it")
     pairs = set()

@@ -11,7 +11,9 @@ import re
 import urllib.request
 from typing import Dict, List, Optional, Tuple
 
-_LINE = re.compile(r"^([A-Za-z_:][A-Za-z0-9_:]*)(\{[^}]*\})?\s+(\S+)")
+#: a label's quoted value may hold a brace (route="pod/{name}"), and a bucket's
+#: line may go on after its value with an exemplar ( # {key="default/pod-1"} 0.043)
+_LINE = re.compile(r'^([A-Za-z_:][A-Za-z0-9_:]*)(\{(?:[^"}]|"(?:[^"\\]|\\.)*")*\})?\s+(\S+)')
 _LABEL = re.compile(r'([A-Za-z_][A-Za-z0-9_]*)="((?:[^"\\]|\\.)*)"')
 
 Sample = Tuple[str, Tuple[Tuple[str, str], ...], float]

@@ -12,19 +12,27 @@ cell's ``chips``.  The load comes from ``client.py``, a process of its own
 started before JAX is imported here; every end-to-end number is taken on
 that client's clock, over loopback HTTP.
 
-Everything that belongs to one cell is data found by name:
+Everything that belongs to one cell is found by name:
 ``workloads/<cell>.json`` names ``configs/<config>.json``,
 ``traffic/<mix>.json`` (which says what end-to-end metrics the mix reports)
 and, under ``per_layer``, its ``metrics/<metric>.json``, each of which names
-its reader under ``readers/``.  Adding a cell, a mix, a metric or a reader
-is adding files: no file that is there lists the cells.
+its reader under ``readers/``.  A configuration may name, under ``maker``,
+the module under ``makers/`` that makes its nodes and pods (``cluster``
+where it names none) and, under ``reference``, the module under
+``references/`` whose numbers are compared after the common ones.  A mix
+is parameters of the one generator (``client.py``): closed or open, and a
+closed one may hold a live set by deleting.  Adding a deployment, a cell, a
+mix, a metric or a reader is adding files, as long as the mix is one the
+generator's two loops can offer and the end-to-end metric is one
+``end_to_end`` below works out: no file that is there lists the cells.
 
 Order of a run: set-up (boot, nodes, init pods bound over the served path,
-the cell's own traffic until a whole stretch passes with no trace, lowering
-or compile event) -> the window -> drain-out grace -> memory peak -> read
-back through REST -> stop the stack -> the comparison that decides
-``correct`` (``audit.py``, ``reference.py``) -> one JSON line, last on
-standard output.
+a deleting mix's live set filled, the cell's own traffic until a whole
+stretch passes with no trace, lowering or compile event) -> the window ->
+drain-out grace -> memory peak -> read back through REST -> stop the stack
+-> the comparison that decides ``correct`` (``audit.py``, ``reference.py``,
+the configuration's own reference) -> one JSON line, last on standard
+output.
 
 The rehearsal (a tiny cluster on whatever device JAX has) is reached only
 as a Python argument, ``main(argv, rehearsal=True)``: no flag and no
@@ -56,6 +64,7 @@ for _p in (ROOT, HERE):
 import audit  # noqa: E402  (the benchmark's own modules: none imports JAX)
 import prom  # noqa: E402
 import tracefile  # noqa: E402
+from readers import trace_gaps  # noqa: E402
 
 #: what the rehearsal overrides: sizes a CPU can hold in seconds
 REHEARSAL = {
@@ -70,10 +79,14 @@ REHEARSAL = {
     "trace_s": 1,
     "deadline_s": 20,
     "warm_bursts": [40, 5],
-    "live_pod_cap": 1500,
+    "live_pod_cap": 2400,
+    "live_target": 192,
+    "deleters": 2,
 }
 
 COMPILE_EVENTS = "/jax/core/compile"
+#: where a traced run keeps its trace until it has read it
+TRACE_DIR = os.path.join(HERE, ".trace")
 #: the longest wait for the init pods or a warm burst to bind (a cold compile)
 DEADLINE_S = 900
 
@@ -109,7 +122,10 @@ def shrink(cell: Dict[str, Any]) -> None:
     cfg["nodes"]["count"] = REHEARSAL["nodes"]
     cfg["init_pods"]["count"] = REHEARSAL["init_pods"]
     cfg["live_pod_cap"] = REHEARSAL["live_pod_cap"]
-    for key in ("outstanding", "chunk", "rate_per_s", "warm_stretch_s", "warm_max_stretches", "grace_s", "trace_s"):
+    for key in (
+        "outstanding", "chunk", "rate_per_s", "warm_stretch_s", "warm_max_stretches", "grace_s", "trace_s",
+        "live_target", "deleters",
+    ):
         if traffic.get(key) is not None:
             traffic[key] = REHEARSAL[key]
     if cell.get("params", {}).get("warm_bursts"):
@@ -329,6 +345,13 @@ def warm_stack(
         for count in cell.get("params", {}).get("warm_bursts", []):
             b = client.call("burst", count=count, deadline_s=deadline_s)
             say(f"warm burst of {count}: {b['seconds']:.1f}s, {b['unbound']} unbound")
+        if st.traffic.get("live_target"):
+            # a mix that holds a live set opens its window in steady state:
+            # the set is filled here, and the deleters run from the first stretch
+            f = client.call(
+                "burst", count=st.traffic["live_target"], chunk=st.traffic["chunk"], deadline_s=deadline_s
+            )
+            say(f"live set of {st.traffic['live_target']} filled: {f['seconds']:.1f}s, {f['unbound']} unbound")
         for i in range(st.traffic["warm_max_stretches"]):
             seen = st.compiles.count
             w = client.call("run", traffic=st.traffic, phase="warm", seconds=st.traffic["warm_stretch_s"])
@@ -373,7 +396,6 @@ def main(
     if rehearsal:
         shrink(cell)
     metrics = cell_metrics(cell)
-    trace_dir = os.path.join(HERE, ".trace")
 
     with warm_stack(cell, args.seed, rehearsal, fault) as st:
         import jax
@@ -385,12 +407,12 @@ def main(
         st.full_gc.count, st.full_gc.longest_s = 0, 0.0
         cpu_before = time.process_time()
         if args.trace:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+            shutil.rmtree(TRACE_DIR, ignore_errors=True)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0  # the Python tracer slows the host it measures
             options.host_tracer_level = 1
             options.enable_hlo_proto = False
-            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            jax.profiler.start_trace(TRACE_DIR, profiler_options=options)
             t_trace = time.monotonic()
         setup_s = time.monotonic() - t_start
         say(f"window opens: setup_s={setup_s:.2f}")
@@ -426,13 +448,13 @@ def main(
     trace = None
     if args.trace:
         t0 = time.monotonic()
-        trace = tracefile.load(trace_dir, traced_s)
+        trace = tracefile.load(TRACE_DIR, traced_s)
         say(
             f"trace read in {time.monotonic() - t0:.1f}s: "
             + json.dumps({p: l for p, l in trace.layout.items() if l})[:1500]
         )
         say("programs in the trace [name, events, seconds]: " + json.dumps(tracefile.top_modules(trace)))
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
     counters = {
         "wave_parked": prom.total(after, "wave_parked") - prom.total(before, "wave_parked"),
@@ -440,7 +462,7 @@ def main(
         - prom.total(before, "wave_dispatch_healed"),
         "compiles_in_window": window["compiles"],
     }
-    compared = audit.checks(nodes, pods, acks, st.config["nodes"]["count"], counters)
+    compared = audit.checks(nodes, pods, acks, st.config["nodes"]["count"], counters, st.config)
     correct = audit.verdict(compared)
     failed = grace["unbound"]
     if not correct:
@@ -465,7 +487,7 @@ def main(
         result["metrics"] = per_layer(metrics, ctx)
         device["busy_s"] = tracefile.busy_s(trace)
         device["window_s"] = trace.window_s
-        result["breakdown"] = {"device_ops": tracefile.top_ops(trace), "idle_gaps": []}
+        result["breakdown"] = {"device_ops": tracefile.top_ops(trace), "idle_gaps": trace_gaps.table(trace)}
     else:
         result["metrics"] = end_to_end(traffic["end_to_end"], window, grace, setup_s)
     result["device"] = device

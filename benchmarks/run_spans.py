@@ -1,31 +1,21 @@
 #!/usr/bin/env python3
-"""One traced run of a cell, read with the program's spans (ISSUE 26).
+"""One traced run of a cell, with the tables that have no place in the
+contract's result line.
 
     python3 benchmarks/run_spans.py --workload <name> --seed <n> --seconds <s>
 
 This is ``run.py --trace 1`` and nothing else of its own: the same set-up,
-window, read-back and comparison, with what a ``tracing`` PR may not put
-inside the files the benchmark already has:
-
-* the cell reports, besides the per-layer metrics its own file names,
-  those that ``span_metrics.json`` names for it (each a file under
-  ``metrics/`` with a reader under ``readers/``, like the others);
-* the trace it hands to the readers also holds the host plane's spans
-  (``hosttrace.load``, as ``trace.host``);
-* the result line's ``breakdown`` gains: ``idle_gaps``, which ``run.py``
-  writes empty (``readers/trace_gaps.table``: the device's idle seconds by
-  the host span they fell under); ``started_inside``, the device programs
-  that start inside the host span that waits for them, to show that the
-  two planes share a clock; ``programs`` and ``host_spans``, both by total
-  time in the trace; ``span_clocks``, every span's wall and CPU seconds a
-  second of window from the two scrapes; ``thread_cpu``, the process's
-  threads by CPU seconds a second of window from ``/proc/self/task``, so
-  that CPU no span covers still has a thread's name.
-
-A ``benchmark`` PR that wants these in every traced run appends the names
-of ``span_metrics.json`` to ``workloads/<cell>.json``'s ``per_layer``, lets
-``tracefile.load`` call ``hosttrace.load`` and ``run.py`` fill its
-``breakdown`` from here; this file and ``span_metrics.json`` then go.
+window, read-back, comparison, per-layer metrics and ``idle_gaps``.  The
+result line's ``breakdown`` gains, for a builder's look at one run:
+``started_inside``, the device programs that start inside the host span
+that waits for them, to show that the two planes share a clock;
+``programs`` and ``host_spans``, both by total time in the trace;
+``span_clocks``, every span's wall and CPU seconds a second of window from
+the two scrapes; ``thread_cpu``, the process's threads by CPU seconds a
+second of window from ``/proc/self/task``, so that CPU no span covers still
+has a thread's name.  It looks over ``run.py``'s shoulder (the trace it
+loads, the context it hands the readers, the instants it scrapes) and
+changes nothing of the run.
 
 The rehearsal is reached only as a Python argument, as in ``run.py``.
 """
@@ -53,7 +43,6 @@ import hosttrace  # noqa: E402
 import prom  # noqa: E402
 import run  # noqa: E402
 import tracefile  # noqa: E402
-from readers import trace_gaps  # noqa: E402
 
 ALL_THREADS = "(the process)"
 ENDED = "(threads that ended in the window)"
@@ -118,29 +107,15 @@ def span_clocks(ctx: Dict[str, Any]) -> List[List]:
 
 def main(argv: Optional[List[str]] = None, rehearsal: bool = False) -> int:
     argv = list(sys.argv[1:] if argv is None else argv) + ["--trace", "1"]
-    with open(os.path.join(HERE, "span_metrics.json")) as f:
-        added = json.load(f)
-    import jax  # no backend yet: run.py starts its generator's process before it asks for one
-
-    load_cell, load_trace, per_layer, scrape = run.load_cell, tracefile.load, run.per_layer, prom.scrape
-    start_trace = jax.profiler.start_trace
+    load_trace, per_layer, scrape = tracefile.load, run.per_layer, prom.scrape
     seen: Dict[str, Any] = {"threads": []}
-
-    def cell_with_span_metrics(name: str) -> Dict[str, Any]:
-        cell = load_cell(name)
-        cell["per_layer"] = cell["per_layer"] + [m for m in added.get(name, []) if m not in cell["per_layer"]]
-        return cell
 
     # a directory of this run's own: run.py's is one fixed path, which two
     # traced runs at once (the tests' workers) would empty under each other
     trace_dir = tempfile.mkdtemp(prefix="run_spans.")
 
-    def start_trace_here(_dir: str, **kw: Any) -> None:
-        start_trace(trace_dir, **kw)
-
-    def trace_with_host_spans(_dir: str, window_s: float) -> Any:
-        trace = seen["trace"] = load_trace(trace_dir, window_s)
-        trace.host = hosttrace.load(trace_dir)
+    def trace_seen(where: str, window_s: float) -> Any:
+        trace = seen["trace"] = load_trace(where, window_s)
         return trace
 
     def per_layer_seen(metrics: List[Dict[str, Any]], ctx: Dict[str, Any]) -> Dict[str, Any]:
@@ -154,11 +129,11 @@ def main(argv: Optional[List[str]] = None, rehearsal: bool = False) -> int:
 
     out = io.StringIO()
     try:
-        with mock.patch.object(run, "load_cell", cell_with_span_metrics), mock.patch.object(
-            tracefile, "load", trace_with_host_spans
+        with mock.patch.object(run, "TRACE_DIR", trace_dir), mock.patch.object(
+            tracefile, "load", trace_seen
         ), mock.patch.object(run, "per_layer", per_layer_seen), mock.patch.object(
             prom, "scrape", scrape_and_threads
-        ), mock.patch.object(jax.profiler, "start_trace", start_trace_here), redirect_stdout(out):
+        ), redirect_stdout(out):
             rc = run.main(argv, rehearsal=rehearsal)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -168,7 +143,6 @@ def main(argv: Optional[List[str]] = None, rehearsal: bool = False) -> int:
     threads = {name: (s - before.get(name, 0.0)) / ctx["window_s"] for name, s in after.items()}
     threads[ENDED] = 2 * threads[ALL_THREADS] - sum(threads.values())
     result["breakdown"].update(
-        idle_gaps=trace_gaps.table(trace),
         started_inside=started_inside(trace),
         programs=tracefile.top_modules(trace),
         host_spans=hosttrace.top_spans(trace.host),

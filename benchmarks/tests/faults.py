@@ -1,8 +1,9 @@
 """Ways to break the timed path underneath a run, for the tests and the
 control: each returns a ``fault(service)`` for ``run.main(..., fault=)``.
 
-They patch the program's bind path (``controlplane.client._PodAPI``), which
-every placement the engine makes goes through on its way to the store:
+All but the last patch the program's bind path
+(``controlplane.client._PodAPI``), which every placement the engine makes
+goes through on its way to the store:
 
 ``overcommit``  the control of the basic cells: the store's capacity gate
                 is off and every bind is altered, where it is produced, to
@@ -12,7 +13,9 @@ every placement the engine makes goes through on its way to the store:
                 first zone, so the zones end skewed;
 ``drop_half``   half of each batch is left out: acknowledged to the engine,
                 never written;
-``drop_all``    a step that returns its state unchanged: nothing is written.
+``drop_all``    a step that returns its state unchanged: nothing is written;
+``delete_swallowed``  the control of the numbers a mix that deletes adds:
+                the façade answers a ``DELETE`` 200 and deletes nothing.
 
 ``patch`` is ``setattr`` or pytest's ``monkeypatch.setattr``.
 """
@@ -87,3 +90,12 @@ def drop_half(patch):
 
 def drop_all(patch):
     return _drop(patch, lambda b: "-init-" in b.pod_name)
+
+
+def delete_swallowed(patch):
+    def fault(_service):
+        from minisched_tpu.controlplane import httpserver
+
+        patch(httpserver._Handler, "_handle_delete", lambda self: self._send(200, {}))
+
+    return fault

@@ -98,6 +98,16 @@ def test_check_refuses(on_disk, breakage, says):
     assert any(says in line for line in manifest.check(broken)), manifest.check(broken)
 
 
+def test_a_configuration_that_names_a_maker_it_has_no_file_for_is_refused(tmp_path, monkeypatch):
+    import overlay
+
+    copy_dir = overlay.build(tmp_path, "churn", "pool")
+    os.remove(os.path.join(copy_dir, "makers", "pool.py"))
+    monkeypatch.setattr(manifest, "HERE", copy_dir)
+    monkeypatch.setattr(manifest, "ROOT", str(tmp_path))
+    assert any("no benchmarks/makers/pool.py" in line for line in manifest.check(manifest.build()))
+
+
 def test_a_metric_that_moves_what_its_cell_does_not_report_is_refused(on_disk):
     broken = copy.deepcopy(on_disk)
     drains = [m for m in broken["per_layer"] if m["moves"] == "pods_bound_per_s"]

@@ -118,6 +118,18 @@ def test_prom_totals_and_buckets(scrapes):
     assert prom.buckets(scrapes["after"], "sched_time_to_bind_seconds")[0.2] == 100
 
 
+def test_prom_reads_a_label_value_that_holds_a_brace_and_stops_before_an_exemplar():
+    """The façade labels a request by the shape of its path."""
+    text = 'http_request_seconds_count{route="pod/{name}",verb="DELETE"} 7\nhttp_request_seconds_sum{route="pod/{name}",verb="DELETE"} 0.014\n'
+    text += 'sched_time_to_bind_seconds_bucket{priority="0",le="0.0256"} 182 # {key="default/pod-{1}"} 0.0005\n'
+    samples = prom.parse(text)
+    assert prom.total(samples, "http_request_seconds_count", {"verb": "DELETE", "route": "pod/{name}"}) == 7
+    assert prom.buckets(samples, "sched_time_to_bind_seconds") == {0.0256: 182.0}  # the value, not the exemplar's
+    assert reader("hist_mean").read(
+        {"before": [], "after": samples}, histogram="http_request_seconds", labels={"route": "pod/{name}"}
+    ) == pytest.approx(2.0)
+
+
 def test_hist_mean(scrapes):
     # (0.35 - 0.05) s over 10 waves = 30 ms
     assert reader("hist_mean").read(scrapes, histogram="sched_wave_build_seconds") == pytest.approx(30.0)

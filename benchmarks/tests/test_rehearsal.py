@@ -20,6 +20,12 @@ import run
 
 CELLS = [w["name"] for w in manifest.build()["workloads"]]
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+COMPARED = [
+    "nodes_missing", "pods_missing", "pods_unsent", "pods_twice", "unbound_after_grace", "on_unknown_node",
+    "on_unschedulable", "selector_broken", "nodes_over_allocatable", "skew_over_max", "ack_not_on_readback",
+    "bound_never_acked", "acked_twice", "wave_parked", "dispatch_healed", "compiles_in_window",
+    "deleted_still_there", "delete_errors", "deleted_on_unknown_node",
+]
 
 
 def rehearse(capfd, cell, trace, fault=None, seconds="2"):
@@ -45,6 +51,10 @@ def test_rehearsal_prints_the_contract_line(capfd, cell, trace):
         wanted = set(run.load_cell(cell)["traffic_data"]["end_to_end"]) | {"setup_s"}
         assert set(result["metrics"]) == wanted
         assert all(m["value"] > 0 for m in result["metrics"].values())
+    # the sixteen numbers every run has compared since PR 25, under their
+    # names and in their order, then the three a mix that deletes adds: 0 here
+    assert list(result["compared"]) == COMPARED
+    assert all(result["compared"][name] == {"number": 0, "limit": 0} for name in COMPARED[16:])
     # every number compared is printed beside its limit, last on stderr
     assert err.strip().splitlines()[-1] == "correct: True"
     assert f"compared nodes_over_allocatable: 0 (limit 0)" in err

@@ -4,8 +4,10 @@
 ``jax.profiler.ProfileData`` and keeps, for every device plane
 (``/device:TPU:n``), two lists of ``(name, start_ns, duration_ns)``: the
 device's operations (the line ``XLA Ops``) and its programs (the line
-``XLA Modules``).  The readers under ``readers/`` work on that plain
-structure, so that a test can hand them intervals it wrote by hand.
+``XLA Modules``), and beside them the program's spans from the host plane
+of the same file (``hosttrace.spans``, as ``Trace.host``).  The readers
+under ``readers/`` work on that plain structure, so that a test can hand
+them intervals it wrote by hand.
 """
 
 from __future__ import annotations
@@ -14,6 +16,8 @@ import glob
 import os
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Tuple
+
+import hosttrace
 
 Event = Tuple[str, int, int]  # name, start_ns, duration_ns
 
@@ -34,6 +38,8 @@ class Trace:
     window_s: float
     #: plane -> line -> number of events, for a look by hand
     layout: Dict[str, Dict[str, int]] = field(default_factory=dict)
+    #: the program's spans on the host plane (``hosttrace.HostEvent``)
+    host: List[hosttrace.HostEvent] = field(default_factory=list)
 
 
 def newest_xplane(trace_dir: str) -> str:
@@ -65,7 +71,7 @@ def load(trace_dir: str, window_s: float) -> Trace:
                 dev.modules += events
         if dev is not None and (dev.ops or dev.modules):
             devices.append(dev)
-    return Trace(devices, window_s, layout)
+    return Trace(devices, window_s, layout, hosttrace.spans(data))
 
 
 def union_ns(events: Iterable[Event]) -> int:
