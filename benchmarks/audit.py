@@ -19,7 +19,11 @@ configuration that names a ``reference`` (a module under ``references/``
 with ``violations(nodes, pods, config, record) -> {name: number}``, which
 like ``reference.py`` imports nothing of the program) has its own numbers
 appended after them; ``record`` is the client's record (``acks``), which
-still holds the placement of a pod that has since been deleted.  A name
+still holds the placement of a pod that has since been deleted, and the
+instants, on the client's one monotonic clock, at which each bind was read
+off the watch (``bound_at``) and each ``DELETE`` was sent and answered
+(``deleted_at``): a guarantee about pods that shared a node is held to
+those, not to the pods that survive to the read-back.  A name
 that is already a common number's is an error, so a configuration can add
 to what it is held to and never replace it.
 

@@ -16,8 +16,9 @@ Commands (``op``):
 ``run``     offer one traffic mix for ``seconds``; answers when the window
             closes (what is outstanding then is left to ``grace``)
 ``grace``   wait, at most ``seconds``, for every pod sent to be bound
-``acks``    every bind the watch has carried: pod -> node, and re-binds;
-            every delete that was answered 200
+``acks``    the record: every bind the watch has carried (pod -> node, the
+            instant it was read, re-binds) and every delete that was
+            answered 200 (the instants it was sent and answered)
 ``stop``    close the watch and exit
 
 The objects come from the configuration's maker: the module under
@@ -119,6 +120,8 @@ class Generator:
         #: and not yet handed to a deleter, longest-bound first
         self.live: Optional[collections.deque] = None
         self.deleted: List[str] = []
+        #: pod name -> [instant its DELETE was sent, instant it was answered]
+        self.deleted_at: Dict[str, List[float]] = {}
         self.delete_errors = 0
         #: pod name -> the instant it was due (open) or sent (closed)
         self.due: Dict[str, float] = {}
@@ -308,6 +311,7 @@ class Generator:
                             self.mu.wait(0.005)
                             continue
                         name = self.live.popleft()
+                    t_sent = time.monotonic()  # before the request: the pod was there at least until now
                     try:
                         pods_api.delete(name)
                     except KeyError:  # the façade's 404: the pod was not there to delete
@@ -316,6 +320,7 @@ class Generator:
                         continue
                     with self.mu:
                         self.deleted.append(name)
+                        self.deleted_at[name] = [t_sent, time.monotonic()]
                         self.mu.notify_all()  # a sender may be waiting under the cap
             except BaseException as err:
                 errors.append(err)
@@ -428,6 +433,8 @@ class Generator:
                 "rebinds": self.rebinds,
                 "deleted": self.deleted,
                 "delete_errors": self.delete_errors,
+                "bound_at": {name: t for name, (_node, t) in self.bound.items()},
+                "deleted_at": self.deleted_at,
             }
 
     def stop(self, cmd: Dict[str, Any]) -> Dict[str, Any]:

@@ -21,6 +21,8 @@ import re
 import sys
 from typing import Any, Dict, List
 
+import run
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -126,6 +128,10 @@ def check(manifest: Dict[str, Any]) -> List[str]:
             for door, folder in (("maker", "makers"), ("reference", "references")):
                 if data.get(door) and not os.path.isfile(os.path.join(HERE, folder, data[door] + ".py")):
                     bad.append(f"config {c['name']}: no benchmarks/{folder}/{data[door]}.py")
+            if run.rehearsal_keys_refused(data):
+                bad.append(
+                    f"config {c['name']}: rehearsal {run.rehearsal_keys_refused(data)}: not sizes a configuration may state"
+                )
         if not any(w["config"] == c["name"] for w in manifest["workloads"]):
             bad.append(f"config {c['name']}: no cell uses it")
     pairs = set()

@@ -19,12 +19,14 @@ and, under ``per_layer``, its ``metrics/<metric>.json``, each of which names
 its reader under ``readers/``.  A configuration may name, under ``maker``,
 the module under ``makers/`` that makes its nodes and pods (``cluster``
 where it names none) and, under ``reference``, the module under
-``references/`` whose numbers are compared after the common ones.  A mix
-is parameters of the one generator (``client.py``): closed or open, and a
-closed one may hold a live set by deleting.  Adding a deployment, a cell, a
-mix, a metric or a reader is adding files, as long as the mix is one the
-generator's two loops can offer and the end-to-end metric is one
-``end_to_end`` below works out: no file that is there lists the cells.
+``references/`` whose numbers are compared after the common ones, and,
+under ``rehearsal``, the sizes its rehearsal takes in place of
+``REHEARSAL``'s.  A mix is parameters of the one generator
+(``client.py``): closed or open, and a closed one may hold a live set by
+deleting.  Adding a deployment, a cell, a mix, a metric or a reader is
+adding files, as long as the mix is one the generator's two loops can offer
+and the end-to-end metric is one ``end_to_end`` below works out: no file
+that is there lists the cells.
 
 Order of a run: set-up (boot, nodes, init pods bound over the served path,
 a deleting mix's live set filled, the cell's own traffic until a whole
@@ -83,6 +85,11 @@ REHEARSAL = {
     "live_target": 192,
     "deleters": 2,
 }
+#: the sizes among them that follow from a deployment's capacity, which a
+#: configuration may state for itself under ``rehearsal``
+REHEARSAL_OWN = (
+    "nodes", "init_pods", "live_target", "outstanding", "chunk", "deleters", "live_pod_cap", "warm_bursts",
+)
 
 COMPILE_EVENTS = "/jax/core/compile"
 #: where a traced run keeps its trace until it has read it
@@ -115,21 +122,33 @@ def cell_metrics(cell: Dict[str, Any]) -> List[Dict[str, Any]]:
     return [load_json("metrics", name + ".json") for name in cell["per_layer"]]
 
 
+def rehearsal_keys_refused(config: Dict[str, Any]) -> List[str]:
+    """The keys of a configuration's ``rehearsal`` that are not sizes it may
+    state (``shrink`` raises on them, ``manifest.check`` lists them)."""
+    return sorted(set(config.get("rehearsal") or {}) - set(REHEARSAL_OWN))
+
+
 def shrink(cell: Dict[str, Any]) -> None:
     """Cut a cell to the rehearsal's size (never reached from the command
-    line)."""
+    line): ``REHEARSAL``, with the configuration's own ``rehearsal`` laid
+    over it.  A deployment whose capacity is its node count cannot hold
+    the pods that 64 nodes of 110 hold, so it states sizes that fit."""
     cfg, traffic = cell["config_data"], cell["traffic_data"]
-    cfg["nodes"]["count"] = REHEARSAL["nodes"]
-    cfg["init_pods"]["count"] = REHEARSAL["init_pods"]
-    cfg["live_pod_cap"] = REHEARSAL["live_pod_cap"]
+    unknown = rehearsal_keys_refused(cfg)
+    if unknown:
+        raise ValueError(f"configs/{cfg['name']}.json: rehearsal {unknown}: a configuration may state {REHEARSAL_OWN}")
+    sizes = {**REHEARSAL, **(cfg.get("rehearsal") or {})}
+    cfg["nodes"]["count"] = sizes["nodes"]
+    cfg["init_pods"]["count"] = sizes["init_pods"]
+    cfg["live_pod_cap"] = sizes["live_pod_cap"]
     for key in (
         "outstanding", "chunk", "rate_per_s", "warm_stretch_s", "warm_max_stretches", "grace_s", "trace_s",
         "live_target", "deleters",
     ):
         if traffic.get(key) is not None:
-            traffic[key] = REHEARSAL[key]
+            traffic[key] = sizes[key]
     if cell.get("params", {}).get("warm_bursts"):
-        cell["params"]["warm_bursts"] = REHEARSAL["warm_bursts"]
+        cell["params"]["warm_bursts"] = sizes["warm_bursts"]
 
 
 class Client:

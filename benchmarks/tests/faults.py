@@ -1,7 +1,7 @@
 """Ways to break the timed path underneath a run, for the tests and the
 control: each returns a ``fault(service)`` for ``run.main(..., fault=)``.
 
-All but the last patch the program's bind path
+All but the last two patch the program's bind path
 (``controlplane.client._PodAPI``), which every placement the engine makes
 goes through on its way to the store:
 
@@ -15,7 +15,10 @@ goes through on its way to the store:
                 never written;
 ``drop_all``    a step that returns its state unchanged: nothing is written;
 ``delete_swallowed``  the control of the numbers a mix that deletes adds:
-                the façade answers a ``DELETE`` 200 and deletes nothing.
+                the façade answers a ``DELETE`` 200 and deletes nothing;
+``green_as_plain``  the control of the anti-affinity deployment: the engine
+                routes every pod as a plain one, so the green pods ride the
+                packed wave, whose pods are blind to each other.
 
 ``patch`` is ``setattr`` or pytest's ``monkeypatch.setattr``.
 """
@@ -97,5 +100,15 @@ def delete_swallowed(patch):
         from minisched_tpu.controlplane import httpserver
 
         patch(httpserver._Handler, "_handle_delete", lambda self: self._send(200, {}))
+
+    return fault
+
+
+def green_as_plain(patch):
+    def fault(_service):
+        from minisched_tpu.engine import device_scheduler
+
+        # both the loop and the build worker look the function up at each wave
+        patch(device_scheduler, "_is_cross_pod", lambda _pod: False)
 
     return fault

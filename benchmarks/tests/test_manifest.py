@@ -108,6 +108,22 @@ def test_a_configuration_that_names_a_maker_it_has_no_file_for_is_refused(tmp_pa
     assert any("no benchmarks/makers/pool.py" in line for line in manifest.check(manifest.build()))
 
 
+def test_a_configuration_that_states_a_rehearsal_size_it_may_not_is_refused(tmp_path, monkeypatch):
+    import overlay
+
+    copy_dir = overlay.build(tmp_path, "churn", "antiaffinity")
+    monkeypatch.setattr(manifest, "HERE", copy_dir)
+    monkeypatch.setattr(manifest, "ROOT", str(tmp_path))
+    assert manifest.check(manifest.build()) == []
+    path = os.path.join(copy_dir, "configs", "antiaffinity-5000n.json")
+    with open(path) as f:
+        data = json.load(f)
+    data["rehearsal"]["grace_s"] = 1
+    with open(path, "w") as f:
+        json.dump(data, f)
+    assert any("rehearsal ['grace_s']" in line for line in manifest.check(manifest.build()))
+
+
 def test_a_metric_that_moves_what_its_cell_does_not_report_is_refused(on_disk):
     broken = copy.deepcopy(on_disk)
     drains = [m for m in broken["per_layer"] if m["moves"] == "pods_bound_per_s"]

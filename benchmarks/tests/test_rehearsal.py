@@ -5,6 +5,7 @@ The rehearsal is reached only as a Python argument of ``run.main``: the
 same argv through the command line ends at the TPU gate.
 """
 
+import importlib
 import json
 import os
 import subprocess
@@ -35,10 +36,18 @@ def rehearse(capfd, cell, trace, fault=None, seconds="2"):
     return json.loads(out.strip().splitlines()[-1]), err
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("cell", CELLS)
-def test_rehearsal_prints_the_contract_line(capfd, cell, trace):
-    result, err = rehearse(capfd, cell, trace)
+def own_numbers(config):
+    """The names a configuration's own reference returns (none where it
+    names none), asked of the reference itself with nothing to count."""
+    if not config.get("reference"):
+        return []
+    nothing = {"acks": {}, "sent": [], "rebinds": [], "deleted": [], "delete_errors": 0, "bound_at": {}, "deleted_at": {}}
+    return list(importlib.import_module("references." + config["reference"]).violations([], [], config, nothing))
+
+
+def holds_the_contract_line(result, err, cell, trace):
+    """What every sound rehearsal's last line and last lines on standard
+    error have to say; ``test_doors.py`` holds the fixture cells to it too."""
     assert RESULT_KEYS <= set(result)
     assert list(result)[-1] == "compared"
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
@@ -52,12 +61,72 @@ def test_rehearsal_prints_the_contract_line(capfd, cell, trace):
         assert set(result["metrics"]) == wanted
         assert all(m["value"] > 0 for m in result["metrics"].values())
     # the sixteen numbers every run has compared since PR 25, under their
-    # names and in their order, then the three a mix that deletes adds: 0 here
-    assert list(result["compared"]) == COMPARED
-    assert all(result["compared"][name] == {"number": 0, "limit": 0} for name in COMPARED[16:])
+    # names and in their order, then the three a mix that deletes adds; after
+    # these nineteen the configuration's own, as its reference names them
+    compared = list(result["compared"])
+    assert compared[:19] == COMPARED
+    assert compared[19:] == own_numbers(run.load_cell(cell)["config_data"])
+    assert all(result["compared"][name] == {"number": 0, "limit": 0} for name in compared)
     # every number compared is printed beside its limit, last on stderr
     assert err.strip().splitlines()[-1] == "correct: True"
-    assert f"compared nodes_over_allocatable: 0 (limit 0)" in err
+    assert err.strip().splitlines()[-1 - len(compared):-1] == [f"compared {name}: 0 (limit 0)" for name in compared]
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_prints_the_contract_line(capfd, cell, trace):
+    result, err = rehearse(capfd, cell, trace)
+    holds_the_contract_line(result, err, cell, trace)
+
+
+# -- what the rehearsal is cut to ------------------------------------------
+
+
+def cut(config_rehearsal=None, cell="spread-5000n.drain"):
+    loaded = run.load_cell(cell)
+    if config_rehearsal is not None:
+        loaded["config_data"]["rehearsal"] = config_rehearsal
+    run.shrink(loaded)
+    return loaded
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_configuration_that_states_no_rehearsal_is_cut_to_the_common_one(cell):
+    """Pinned against ``REHEARSAL`` itself: both configurations that exist
+    name none and shrink as they did before a configuration could."""
+    assert "rehearsal" not in run.load_json("configs", run.load_cell(cell)["config"] + ".json")
+    got, R = cut(cell=cell), run.REHEARSAL
+    cfg, traffic = got["config_data"], got["traffic_data"]
+    assert (cfg["nodes"]["count"], cfg["init_pods"]["count"], cfg["live_pod_cap"]) == (
+        R["nodes"], R["init_pods"], R["live_pod_cap"])
+    whole = run.load_cell(cell)["traffic_data"]
+    for key in ("outstanding", "chunk", "rate_per_s", "warm_stretch_s", "warm_max_stretches", "grace_s",
+                "trace_s", "live_target", "deleters"):
+        assert traffic.get(key) == (R[key] if whole.get(key) is not None else whole.get(key)), key
+    if got["params"].get("warm_bursts"):
+        assert got["params"]["warm_bursts"] == R["warm_bursts"]
+
+
+def test_a_configuration_may_state_its_rehearsal():
+    own = {"nodes": 32, "init_pods": 8, "outstanding": 16, "chunk": 8, "live_pod_cap": 32, "warm_bursts": [9]}
+    got, R = cut(own), run.REHEARSAL
+    cfg, traffic = got["config_data"], got["traffic_data"]
+    assert (cfg["nodes"]["count"], cfg["init_pods"]["count"], cfg["live_pod_cap"]) == (32, 8, 32)
+    assert (traffic["outstanding"], traffic["chunk"], got["params"]["warm_bursts"]) == (16, 8, [9])
+    # what it does not state, and what is not a deployment's to state, stays
+    assert (traffic["warm_stretch_s"], traffic["grace_s"]) == (R["warm_stretch_s"], R["grace_s"])
+    assert traffic.get("live_target") is None  # the drain holds no live set: nothing to cut
+
+
+@pytest.mark.parametrize("key", ["grace_s", "warm_stretch_s", "deadline_s", "seconds", "zones"])
+def test_a_rehearsal_key_that_is_not_a_deployments_size_is_refused(key):
+    assert key not in run.REHEARSAL_OWN
+    with pytest.raises(ValueError, match=key):
+        cut({"nodes": 32, key: 1})
+
+
+def test_what_a_configuration_may_state_are_sizes_of_the_common_rehearsal():
+    assert set(run.REHEARSAL_OWN) <= set(run.REHEARSAL)
 
 
 def test_the_command_line_ends_at_the_tpu_gate():
