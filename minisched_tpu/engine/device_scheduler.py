@@ -1141,6 +1141,7 @@ class DeviceScheduler(Scheduler):
             interaction_sets,
             order_into_blocks,
         )
+        from minisched_tpu.observability import counters
 
         # wave-style dispatch gating (see _bind_batch): the previous
         # wave's thousands of bind events drain inside this lane's
@@ -1158,6 +1159,8 @@ class DeviceScheduler(Scheduler):
                     sets = interaction_sets([q.pod for q in pending])
                     blocks = order_into_blocks(pending, sets, B)
                     flat = [m for blk in blocks for m in blk]
+                counters.inc("scan.rows_live", len(pending))
+                counters.inc("scan.rows_total", len(flat))
                 retry: List[QueuedPodInfo] = []
                 for start in range(0, len(flat), self.BLOCKED_MAX_CHUNK):
                     if fresh is None:

@@ -15,6 +15,15 @@ per-group FIFO order: a block rejected for an earlier same-group pod
 keeps rejecting later ones (blocks only grow), so a group's members land
 in strictly increasing blocks — the within-group sequential semantics the
 blocked kernel's exactness claim rests on.
+
+The same fact makes first fit near-linear: a block REFUSES an identity
+when it is full or its union holds the identity, and a refusal is for
+good — a union only gains identities and a full block stays full.  So
+the search keeps, per identity, the lowest block not yet known to refuse
+it, and the lowest block that is not full; every block below the largest
+of those over a pod's identities refuses the pod, and its walk starts
+there instead of at block 0.  The pointers only move forward, so the
+blocks chosen are exactly those of the walk from 0.
 """
 
 from __future__ import annotations
@@ -91,18 +100,33 @@ def order_into_blocks(
     """First-fit the items into blocks of ``block_size`` with pairwise-
     disjoint sets; short blocks are padded with None.  Items appear in
     non-decreasing block order per interaction group (see module doc)."""
-    blocks: List[Tuple[List[Any], Set]] = []
+    members: List[List[Any]] = []
+    unions: List[Set] = []
+    # identity -> lowest block not yet known to refuse it
+    lowest: Dict[Any, int] = {}
+    open_from = 0  # lowest block that is not full
     for item, s in zip(items, sets):
-        placed = False
-        for members, union in blocks:
-            if len(members) < block_size and not (union & s):
-                members.append(item)
-                union |= s
-                placed = True
-                break
-        if not placed:
-            blocks.append(([item], set(s)))
-    return [
-        members + [None] * (block_size - len(members))
-        for members, _ in blocks
-    ]
+        n = len(members)
+        while open_from < n and len(members[open_from]) >= block_size:
+            open_from += 1
+        b = open_from
+        for g in s:
+            k = max(lowest.get(g, 0), open_from)
+            while k < n and (
+                len(members[k]) >= block_size or g in unions[k]
+            ):
+                k += 1
+            lowest[g] = k
+            if k > b:
+                b = k
+        while b < n and (
+            len(members[b]) >= block_size or (unions[b] & s)
+        ):
+            b += 1
+        if b == n:
+            members.append([item])
+            unions.append(set(s))
+        else:
+            members[b].append(item)
+            unions[b] |= s
+    return [m + [None] * (block_size - len(m)) for m in members]

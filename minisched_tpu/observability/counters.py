@@ -142,6 +142,17 @@ checkpoint and WAL record is decoded through a plan kept per type):
           objects decoded means ``get_type_hints`` is back on the
           per-object path (PERF.md §6, PR 27)
 
+The blocked scan lane (engine/scan_groups + ops/sequential) says how
+full its blocks are, once a grouping (a flush of the scan backlog, and
+each retry round of it):
+
+    scan.rows_live / scan.rows_total
+        — pods handed to the blocked kernel, and the rows they were laid
+          out in (blocks × SCAN_BLOCK_SIZE, ``None`` padding included).
+          live ÷ total is the block fill: 1 ÷ 32 where every pod shares
+          one interaction group, near 1 where groups are many and
+          small.  No benchmark metric reads them (PERF.md §7 row 11)
+
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
 

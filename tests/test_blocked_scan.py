@@ -4,8 +4,11 @@ giving up within-group sequential semantics."""
 
 from __future__ import annotations
 
+import random
 import time
 from collections import Counter
+
+import pytest
 
 from minisched_tpu.api.objects import (
     Affinity,
@@ -90,6 +93,113 @@ def test_matching_direction_counts_as_interaction():
     assert sets[0] & sets[1], (sets[0], sets[1])
     blocks = order_into_blocks([chaser, target], sets, block_size=4)
     assert len(blocks) == 2  # forced into separate blocks
+
+
+def _first_fit_oracle(items, sets, block_size):
+    """The plain first fit ``order_into_blocks`` was until PR 30, kept as
+    the reference: every item walks every block from the first."""
+    blocks = []
+    for item, s in zip(items, sets):
+        for members, union in blocks:
+            if len(members) < block_size and not (union & s):
+                members.append(item)
+                union |= s
+                break
+        else:
+            blocks.append(([item], set(s)))
+    return [
+        members + [None] * (block_size - len(members))
+        for members, _ in blocks
+    ]
+
+
+def _shape_sets(shape, rng, n):
+    if shape == "one_group":
+        return [{0} for _ in range(n)]
+    if shape == "all_disjoint":
+        return [{i} for i in range(n)]
+    if shape == "mixed":  # 0-3 identities of 12, so blocks fill and refuse
+        return [set(rng.sample(range(12), rng.randrange(4))) for _ in range(n)]
+    if shape == "empty_sets":
+        return [set() for _ in range(n)]
+    assert shape == "no_items"
+    return []
+
+
+@pytest.mark.parametrize("block_size", [1, 2, 4, 32])
+@pytest.mark.parametrize(
+    "shape", ["one_group", "all_disjoint", "mixed", "empty_sets", "no_items"]
+)
+@pytest.mark.parametrize("seed", [11, 12, 13])
+def test_order_into_blocks_is_first_fit(seed, shape, block_size):
+    """List for list what the plain first fit returns: same blocks, same
+    order within a block, same None padding."""
+    rng = random.Random(seed)
+    for _ in range(20):
+        sets = _shape_sets(shape, rng, rng.randrange(1, 160))
+        items = [f"i{k}" for k in range(len(sets))]
+        want = _first_fit_oracle(items, [set(s) for s in sets], block_size)
+        got = order_into_blocks(items, [set(s) for s in sets], block_size)
+        assert got == want, (seed, shape, block_size, sets)
+
+
+class _Probes:
+    """Counts block probes without a clock: every hash of an identity (a
+    membership test against a block's union, a lookup of the identity's
+    pointer) and every intersection of an item's set with a union."""
+
+    def __init__(self):
+        self.n = 0
+
+    def sets(self, identities):
+        probes = self
+
+        class Ident:
+            def __init__(self, g):
+                self.g = g
+
+            def __hash__(self):
+                probes.n += 1
+                return hash(self.g)
+
+            def __eq__(self, other):
+                return self.g == other.g
+
+        class CountingSet(set):
+            def __rand__(self, other):  # plain_union & self lands here
+                probes.n += 1
+                return set.__and__(self, other)
+
+            __and__ = __rand__
+
+        idents = {}
+        out = [
+            CountingSet(idents.setdefault(g, Ident(g)) for g in s)
+            for s in identities
+        ]
+        self.n = 0  # building the sets hashed every identity once
+        return out
+
+
+@pytest.mark.parametrize(
+    "shape, oracle_probes",
+    [
+        ("one_group", lambda m, B: m * (m - 1) // 2),  # item i walks i blocks
+        ("all_disjoint", lambda m, B: m - m // B),  # full blocks cost no `&`
+    ],
+)
+def test_order_into_blocks_probes_linear_in_backlog(shape, oracle_probes):
+    """Work, not time: a backlog of 8,192 costs at most 4 probes an item,
+    where walking every block from the first costs n² ÷ 2 with one group
+    — read off the oracle, at a size that takes no time, to show that the
+    count sees the walk."""
+    n, m, B = 8192, 512, 32
+    identities = _shape_sets(shape, None, n)
+    probes = _Probes()
+    order_into_blocks(list(range(n)), probes.sets(identities), B)
+    assert probes.n <= 4 * n, probes.n
+    _first_fit_oracle(list(range(m)), probes.sets(identities[:m]), B)
+    assert probes.n == oracle_probes(m, B)
 
 
 # -- kernel -----------------------------------------------------------------
@@ -186,13 +296,25 @@ def test_blocked_kernel_capacity_race_is_flagged_not_lost():
 # -- live engine ------------------------------------------------------------
 
 
-def test_live_engine_blocked_lane_places_spread_burst():
+def test_live_engine_blocked_lane_places_spread_burst(monkeypatch):
     """End to end: a burst of DoNotSchedule spread pods through the live
-    device engine's blocked lane — all bind, max-skew holds per app."""
+    device engine's blocked lane — all bind, max-skew holds per app, and
+    the lane's block fill is counted once a grouping."""
     from minisched_tpu.controlplane.client import Client
+    from minisched_tpu.engine import scan_groups
+    from minisched_tpu.observability import counters, hist
     from minisched_tpu.service.config import default_full_roster_config
     from minisched_tpu.service.service import SchedulerService
 
+    handed = []  # (pods in, blocks out) of every grouping the lane made
+
+    def recording(items, sets, block_size):
+        blocks = order_into_blocks(items, sets, block_size)
+        handed.append((len(items), len(blocks)))
+        return blocks
+
+    monkeypatch.setattr(scan_groups, "order_into_blocks", recording)
+    before = counters.snapshot()
     client = Client()
     zones = ["za", "zb", "zc", "zd"]
     for i in range(32):
@@ -221,6 +343,14 @@ def test_live_engine_blocked_lane_places_spread_burst():
         sum(1 for p in pods if not p.spec.node_name),
         "unbound",
     )
+    assert handed, "no burst reached the blocked lane"
+    live = counters.get("scan.rows_live") - before.get("scan.rows_live", 0)
+    total = counters.get("scan.rows_total") - before.get("scan.rows_total", 0)
+    assert live == sum(n for n, _ in handed)
+    assert total == sched.SCAN_BLOCK_SIZE * sum(b for _, b in handed)
+    metrics = hist.render_prometheus()
+    assert f"scan_rows_live {counters.get('scan.rows_live')}\n" in metrics
+    assert f"scan_rows_total {counters.get('scan.rows_total')}\n" in metrics
     zone_of = {
         n.metadata.name: n.metadata.labels["zone"] for n in client.nodes().list()
     }
