@@ -3,7 +3,7 @@
 
 NATIVE_SO  := minisched_tpu/native/libminisched_native.so
 
-.PHONY: test native chip-smoke start serve bench bench-wave bench-mesh bench-gang bench-churn bench-wire bench-wal bench-relist bench-repl bench-readscale bench-shard chaos chaos-proc chaos-ha chaos-disk chaos-repl chaos-partition chaos-read chaos-shard chaos-split metrics-smoke docker clean
+.PHONY: test native chip-smoke start serve benchmark chaos chaos-proc chaos-ha chaos-disk chaos-repl chaos-partition chaos-read chaos-shard chaos-split metrics-smoke docker clean
 
 test: native
 	python -m pytest tests/ -q -m 'not slow'
@@ -11,117 +11,20 @@ test: native
 # chaos soak under a FIXED fault-schedule seed: the fabric's injection
 # decisions are a pure function of (seed, point, key, ordinal), so a
 # failure here reproduces byte-for-byte — override the seed with
-# MINISCHED_CHAOS_SEED=<n> to explore other schedules.  Runs with the
-# wave PIPELINE explicitly on (its default): fault-injection and the
-# overlapped build/evaluate stages must compose — a regression that only
-# reproduces serially would otherwise hide behind the kill-switch
+# MINISCHED_CHAOS_SEED=<n> to explore other schedules
 chaos: native
-	MINISCHED_CHAOS_SEED=$${MINISCHED_CHAOS_SEED:-1234} MINISCHED_PIPELINE=1 \
+	MINISCHED_CHAOS_SEED=$${MINISCHED_CHAOS_SEED:-1234} \
 		python -m pytest tests/test_chaos_soak.py tests/test_faults.py -q
 
-# pipelined-wave micro-bench (CPU): two laps of the live full-roster
-# wave engine; FAILS when the loop thread's stall time reaches the build
-# time (the pipeline has regressed to serial) or any audit trips
-bench-wave: native
-	JAX_PLATFORMS=cpu MINISCHED_PIPELINE=1 python bench.py --only wave
-
-# multi-chip live wave engine (ISSUE 7) on an 8-virtual-device CPU mesh:
-# the SAME uid-pinned workload through the single-device and the
-# mesh-sharded pipelined engine; FAILS on any placement difference, on
-# sharded device_total_s >= single-device, on stall >= build (pipeline
-# regressed), on any per-wave fallback, on the exactly-once/capacity
-# audits, or if XLA's >2s slow-constant-folding alarm fires.  On a real
-# multi-chip box drop the XLA_FLAGS forcing to shard over real devices.
-bench-mesh: native
-	JAX_PLATFORMS=cpu MINISCHED_PIPELINE=1 \
-		XLA_FLAGS="$$XLA_FLAGS --xla_force_host_platform_device_count=8" \
-		python bench.py --only mesh
-
-# gang churn role (CPU): mixed gang+singleton rounds over a sliced torus
-# cluster + a two-gang deadlock probe; FAILS on any stranded partial
-# gang, a deadlocked probe, an assume-ledger leak, or node overcommit
-bench-gang: native
-	JAX_PLATFORMS=cpu MINISCHED_PIPELINE=1 python bench.py --only gang
-
-# sustained-churn serving (ISSUE 8): Poisson arrivals/departures +
-# priority-preemption bursts over multi-tenant quota'd namespaces under a
-# fixed seed, env-reduced to a tier-1-safe smoke window by default
-# (scale up with BENCH_CHURN_WINDOW_S / _NODES / _ARRIVALS_PER_S).  FAILS
-# on p99 time-to-bind past BENCH_CHURN_P99_S, a stranded (partial) gang,
-# a namespace-quota violation, a quiet tail with zero zero-build waves,
-# per-watcher (unshared) fanout encoding, or any standing audit
-# (double-bind / node overcommit / assume-ledger leak)
-bench-churn: native
-	JAX_PLATFORMS=cpu MINISCHED_PIPELINE=1 python bench.py --only churn
-
-# wire-scale watch fanout (ISSUE 9): ≥1000 concurrent REAL HTTP watch
-# streams through the selector stream loop with a mutating store behind
-# them and deliberately-wedged slow watchers.  FAILS when server thread
-# count scales with watcher count (thread-per-watcher regressed), on
-# per-watcher (unshared) event encoding, when no slow watcher gets
-# evicted, on any missed/duplicated event across an eviction's
-# resume/410→relist reconnect, or on p99 delivery latency past
-# BENCH_WIRE_P99_S.  Scale with BENCH_WIRE_WATCHERS / _EVENTS_PER_S /
-# _WINDOW_S; MINISCHED_STREAMLOOP=0 skips (kill-switch restores the
-# thread-per-watcher path)
-bench-wire: native
-	JAX_PLATFORMS=cpu python bench.py --only wirefan
-
-# group-commit WAL (ISSUE 13): concurrent HTTP writers over fsync=True,
-# kill-switch baseline vs pipeline on the same box — fsyncs must
-# coalesce and throughput must clear 3x under a real durability barrier
-bench-wal: native
-	JAX_PLATFORMS=cpu python bench.py --only wal
-
-# replicated control plane (ISSUE 15): one leader + two followers
-# tailing the group-commit WAL stream over real HTTP, quorum-ack armed
-# at the barrier, versus the MINISCHED_REPL=0 kill-switch on the same
-# box.  FAILS on any acked mutation missing from a follower, follower
-# WALs diverging from the leader's bytes (fsck --compare), or quorum
-# timeouts on a healthy local plane; the record carries the mutate
-# p50/p99 replication tax and the storage.quorum_wait_s histogram.
-# Phase 3 (ISSUE 16): a FRESH follower attaches while writers run and
-# background compaction ships checkpoint generations — FAILS when the
-# catch-up blows BENCH_REPL_BOOTSTRAP_S, on any offset-0 re-tail, on a
-# deferred compaction, or when the leader's WAL peak exceeds ~2
-# compaction intervals of growth (unbounded history)
-bench-repl: native
-	JAX_PLATFORMS=cpu BENCH_REPL=1 python bench.py --only repl
-
-# relist storm (ISSUE 14): the COW read plane under a thundering herd —
-# a SIGKILL-free 410 mass eviction (history-ring compaction) and a
-# cold-boot storm of ≥200 simultaneous lists over real HTTP.  FAILS on
-# encodes NOT ≪ requests (the memoized list cache regressed), p99 list
-# latency past BENCH_RELIST_P99_S, write-path stalls during the storm
-# (reads holding the write lock), or any byte difference between the
-# MINISCHED_COW_READS=0 locked path and the COW cached/chunked path.
-# Scale with BENCH_RELIST_WATCHERS / _OBJECTS
-bench-relist: native
-	JAX_PLATFORMS=cpu python bench.py --only relist
-
-# follower-serving read plane (ISSUE 17, DESIGN.md §29): 1->3 replica
-# list-rate scaling over a real process plane (gated >=1.7x on >=4-core
-# boxes; informational where the replicas share one core), encode-once
-# list caching verified on EVERY serving replica, and read availability
-# across a leader SIGKILL — endpoint-aware min_rv-bounded readers must
-# ride the surviving followers through the election (max read gap
-# BENCH_READSCALE_GAP_S, zero errors, zero rv regressions).  Scale with
-# BENCH_READSCALE_CLIENTS / _PROCS / _OBJECTS / BENCH_READ_FAILOVER_S
-bench-readscale: native
-	JAX_PLATFORMS=cpu BENCH_READSCALE=1 python bench.py --only readscale
-
-# sharded write plane (ISSUE 18, DESIGN.md §30): the same ≥6-process
-# HTTP writer fleet through the shard router against a 1-group and then
-# a 2-group plane, every group fsync-armed with a real durability floor
-# (MINISCHED_FSYNC_FLOOR_US via BENCH_SHARD_FSYNC_FLOOR_US) — a second
-# leader group must BUY write throughput (gated ≥1.5x on ≥4-core boxes;
-# informational where the groups share one core, readscale precedent).
-# The cross-shard bind batch tax (two-shard commit: two round trips +
-# two barriers in parallel) is measured SEPARATELY — it is the price of
-# exactly-once across groups, not a regression.  Scale with
-# BENCH_SHARD_WRITERS / _WINDOW_S / _BIND_BATCHES
-bench-shard: native
-	JAX_PLATFORMS=cpu BENCH_SHARD=1 python bench.py --only shard
+# the benchmark (BENCHMARK.json, all of it under benchmarks/): checks the
+# manifest against the files it is built from and prints the command of
+# one run of one cell.  A run ends at a TPU gate anywhere else than on the
+# chip (this sandbox: `chiprun -- python3 benchmarks/run.py ...`); the
+# driver runs every cell, parent and change, after each PR
+benchmark:
+	python3 benchmarks/manifest.py
+	@echo "one cell, one run (cells: benchmarks/workloads/*.json):"
+	@echo "  python3 benchmarks/run.py --workload <cell> --seed <n> --seconds 51 --trace <0|1>"
 
 # process-level chaos: SIGKILL/restart the control-plane child process
 # mid-workload (faults/proc.ServerSupervisor) under the same fixed seed.
@@ -249,9 +152,6 @@ start: native
 serve: native
 	PORT=$${PORT:-10251} FRONTEND_URL=$${FRONTEND_URL:-http://localhost:3000} \
 		python -m minisched_tpu
-
-bench: native
-	python bench.py
 
 # containerized `make serve` with the WAL on a named volume (the
 # reference's docker-compose runs etcd + simulator; see docker-compose.yml)

@@ -221,7 +221,7 @@ def check_kernels(seed: int) -> None:
     # the XLA tail by the shape rule, not by a caught exception
     require(not fused._pallas_shape_ok(1, 5120), "P=1 must take the XLA tail")
 
-    # nodenumber_select_hosts (bench-only; ROADMAP S5/D8 decide its fate)
+    # nodenumber_select_hosts (no caller on the served path; ROADMAP D8 decides its fate)
     from minisched_tpu.api.objects import Toleration, make_node, make_pod
     from minisched_tpu.models.tables import build_node_table, build_pod_table
     from minisched_tpu.plugins.nodenumber import NodeNumber

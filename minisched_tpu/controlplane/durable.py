@@ -201,18 +201,6 @@ class DurableObjectStore(ObjectStore):
         self._ckpt_path = checkpoint_path or path + ".ckpt"
         self._archive = archive_compacted
         self._fsync = fsync
-        # slow-disk emulation: a FLOOR on every fsync's duration, in
-        # microseconds (MINISCHED_FSYNC_FLOOR_US; 0 = real device).
-        # The bench `wal` role arms it for BOTH its phases so the
-        # group-commit comparison models a disk whose durability
-        # barrier actually costs something — tmpfs/virtio fsyncs are
-        # near-free, which would hide any fsync-coalescing win.
-        try:
-            self._fsync_floor_s = (
-                float(os.environ.get("MINISCHED_FSYNC_FLOOR_US", "0")) / 1e6
-            )
-        except ValueError:
-            self._fsync_floor_s = 0.0
         self._salvage = salvage
         self._readonly = readonly
         self._closed = False
@@ -456,7 +444,7 @@ class DurableObjectStore(ObjectStore):
                     f"short WAL write ({n}/{len(frame)} bytes)",
                 )
             if not self._defer_flush and self._fsync:
-                self._fsync_now()
+                os.fsync(self._log.fileno())
             hist.observe("storage.wal_append_s", time.monotonic() - t0)
         except OSError as e:
             if pre_end is not None:
@@ -652,7 +640,7 @@ class DurableObjectStore(ObjectStore):
                 hist.observe("storage.wal_append_s", time.monotonic() - t0)
                 if self._fsync:
                     t0 = time.monotonic()
-                    self._fsync_now()
+                    os.fsync(self._log.fileno())
                     hist.observe(
                         "storage.wal_fsync_s", time.monotonic() - t0
                     )
@@ -841,18 +829,6 @@ class DurableObjectStore(ObjectStore):
 
         return self._gc_run(kind, build)
 
-    def _fsync_now(self) -> None:
-        """``os.fsync`` with the optional emulated duration floor
-        (MINISCHED_FSYNC_FLOOR_US — see __init__): when the real device
-        answers faster than the floor, sleep the remainder.  Never
-        swallows the OSError — the floor only stretches successes."""
-        t0 = time.monotonic()
-        os.fsync(self._log.fileno())
-        if self._fsync_floor_s > 0.0:
-            rem = self._fsync_floor_s - (time.monotonic() - t0)
-            if rem > 0.0:
-                time.sleep(rem)
-
     def _fsync_log(self) -> None:
         """The deferred-batch fsync barrier: raises StorageDegraded on
         failure — callers must not acknowledge (or fan out) a batch the
@@ -860,7 +836,7 @@ class DurableObjectStore(ObjectStore):
         if self._log is not None and self._fsync:
             try:
                 t0 = time.monotonic()
-                self._fsync_now()
+                os.fsync(self._log.fileno())
                 hist.observe("storage.wal_fsync_s", time.monotonic() - t0)
             except OSError as e:
                 self._enter_degraded(e)
@@ -2002,7 +1978,7 @@ class DurableObjectStore(ObjectStore):
                             f"short WAL write ({n}/{len(data)} bytes)",
                         )
                     if self._fsync:
-                        self._fsync_now()
+                        os.fsync(self._log.fileno())
                 except OSError as e:
                     try:
                         self._log.truncate(end)

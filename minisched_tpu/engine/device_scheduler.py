@@ -25,7 +25,7 @@ import time
 from typing import Any, List, Optional
 
 from minisched_tpu.api.objects import Pod
-from minisched_tpu.engine.pipeline import WavePipeline
+from minisched_tpu.engine.pipeline import WavePipeline, build_wave
 from minisched_tpu.engine.scheduler import Scheduler
 from minisched_tpu.framework.types import (
     CycleState,
@@ -63,7 +63,7 @@ profiling.register_phases("wave_pipeline_stall", cpu=False)
 
 import os as _os
 
-#: env-gated per-wave stderr trace (timeline debugging at bench scale)
+#: env-gated per-wave stderr trace (timeline debugging at cluster scale)
 _WAVE_LOG = _os.environ.get("MINISCHED_WAVE_LOG", "") not in ("", "0")
 
 
@@ -116,8 +116,7 @@ class DeviceScheduler(Scheduler):
         #: keeps current behavior), MINISCHED_MESH=0 pins single-device,
         #: unset auto-shards exactly when jax.device_count() > 1.
         #: ``mesh=False`` pins single-device EXPLICITLY (bypassing the
-        #: policy) — the mesh bench's baseline lap needs it on a box
-        #: whose device count would auto-shard.
+        #: policy) on a box whose device count would auto-shard.
         if mesh is None:
             from minisched_tpu.parallel.sharding import resolve_mesh
 
@@ -175,20 +174,14 @@ class DeviceScheduler(Scheduler):
         self._scan_scheduler: Any = None  # lazy SequentialScheduler
         self._blocked_scheduler: Any = None  # lazy BlockedSequentialScheduler
         #: two-stage wave pipeline (engine/pipeline.py): the host build
-        #: stage for wave N+1 runs on a worker thread while the device
-        #: evaluates wave N.  MINISCHED_PIPELINE=0 is the kill-switch —
-        #: the loop then takes the exact serial path (pop → snapshot →
-        #: build → evaluate → commit on one thread, byte-for-byte the
-        #: pre-pipeline code).  The pipeline engages only in packed
-        #: single-device mode (see _pipeline_active).
-        self.pipeline_enabled = _os.environ.get(
-            "MINISCHED_PIPELINE", "1"
-        ) not in ("", "0")
+        #: for wave N+1 runs on a worker thread while the device evaluates
+        #: wave N.  Started by the first ``schedule_one``; from then on
+        #: the worker owns queue popping.
         self._pipeline: Any = None
         #: commit-time re-arbitration only matters when the chain
         #: actually filters on capacity — chains without NodeResourcesFit
-        #: accept over-booking by design (the serial engine would too),
-        #: and rejecting there would CHANGE placements vs serial
+        #: accept over-booking by design (the scalar engine does too),
+        #: and rejecting there would CHANGE placements
         self._rearb_capacity = any(
             p.name() == "NodeResourcesFit" for p in self.filter_plugins
         )
@@ -204,9 +197,11 @@ class DeviceScheduler(Scheduler):
         #: record_results is on: each wave then also runs a diagnostics
         #: evaluation and records the same per-plugin artifact scalar
         #: cycles produce (O(pods × nodes × plugins) host dicts — a
-        #: simulator feature, not for headline-scale waves)
+        #: simulator feature, not for headline-scale waves).  The
+        #: recorder builds device tables of its own (see _record_wave).
         self.result_store: Any = None
         self._diag_evaluator: Any = None
+        self._record_builder: Any = None
         # cross-pod pods deferred across waves (see schedule_wave): every
         # scan-lane call re-ships the packed node/constraint tables and
         # dispatches a whole program however few pods it carries, so
@@ -584,18 +579,6 @@ class DeviceScheduler(Scheduler):
         self._forget(qpi.pod.metadata.uid)
         super().error_func(qpi, err, plugin)
 
-    @property
-    def _packed_mode(self) -> bool:
-        """Single-program packed waves: tables ride as flat host buffers
-        unpacked inside the evaluator's program — WITH or WITHOUT a mesh
-        (under one, the unpacked tables get sharding constraints and
-        GSPMD partitions the program; parallel/sharding.MeshPackedCaller).
-        Off only under record_results (the diagnostics evaluation needs
-        device tables).  One definition — prewarm and the live paths must
-        never disagree, or the first live wave compiles a second
-        executable mid-run."""
-        return self.result_store is None
-
     def _get_evaluator(self) -> RepairingEvaluator:
         if self._evaluator is None:
             self._evaluator = RepairingEvaluator(
@@ -644,7 +627,7 @@ class DeviceScheduler(Scheduler):
         from minisched_tpu.observability import counters
 
         # pad-waste ledger: rows shipped beyond the live roster/wave —
-        # the bench divides by waves to watch mesh-alignment overhead
+        # divide by wave_mesh.waves for the mesh-alignment overhead a wave
         counters.inc("wave_mesh.pad_pod_rows", pod_table.capacity - n_pods)
         counters.inc("wave_mesh.pad_node_rows", node_agg.capacity - n_nodes)
         try:
@@ -760,8 +743,8 @@ class DeviceScheduler(Scheduler):
         compile at first use.
 
         Shapes must match the live waves exactly or the warm executable is
-        wasted: pod capacity is the wave capacity (_build_and_evaluate
-        pads to max_wave), node capacity is pad_to(current node count).
+        wasted: pod capacity is the wave capacity (``_wave_cap``), node
+        capacity is the table builder's for the current node count.
         A throwaway table builder keeps the real one's static-column cache
         out of it.
         """
@@ -794,82 +777,45 @@ class DeviceScheduler(Scheduler):
         wave_caps = sorted({pod_capacity, self._wave_cap(1)})
         nodes = [make_node("warm0"), make_node("warm1")]
         pods = [make_pod("warmpod", requests={"cpu": "1"})]
-        # pod tables have TWO packed-transfer schemas per capacity: the
-        # vectorized fast path (simple pods; zero columns declared, not
-        # shipped) and the full slow path (any pod with tolerations/
-        # selector/affinity).  The fast schemas are warmed by the table
-        # builds below; warm the SLOW one per capacity the engine uses —
-        # the first wave containing a non-simple pod otherwise compiles
-        # its splitter mid-run.  force_packed:
-        # small-capacity slow tables fall under the packed-path size
-        # threshold and would silently warm nothing.
+        # pod tables have TWO packed schemas per capacity: the vectorized
+        # fast path (simple pods; zero columns declared, not shipped) and
+        # the full slow path (any pod with tolerations/selector/affinity)
         complex_pod = make_pod(
             "warmsel", requests={"cpu": "1"}, node_selector={"warm": "true"}
         )
-        packed_mode = self._packed_mode
-        if not packed_mode:
-            # the unpacked path ships pod tables through per-capacity
-            # splitter executables; packed mode never invokes them
-            warm_caps = set(wave_caps)
-            if self._has_cross_pod and scan:
-                warm_caps |= {self.SCAN_MIN_CAP, self.SCAN_MAX_CHUNK}
-                if self.SCAN_BLOCK_SIZE > 1:
-                    warm_caps.add(self.BLOCKED_MAX_CHUNK)
-            for cap in warm_caps:
-                build_pod_table([complex_pod], capacity=cap, force_packed=True)
         infos = build_node_infos(nodes, [])
-        if packed_mode:
-            # warm the single-program packed entry points for BOTH pod
-            # schemas a live wave can take: the fast (simple-pod) schema
-            # and the slow one (any pod with selector/affinity/...), each
-            # a distinct executable keyed on the packed metas.  The
-            # throwaway builder carries the mesh so the warm statics are
-            # sharded exactly like the live ones.
-            node_static, node_agg, _ = CachedNodeTableBuilder(
-                mesh=self.mesh
-            ).build_packed(
-                infos, capacity=node_capacity, prof_capacity=prof_capacity
-            )
-            for wave_cap in wave_caps:
-                for warm_pods in (pods, pods + [complex_pod]):
-                    pt, _ = build_pod_table(
-                        warm_pods, capacity=wave_cap, device=False
-                    )
-                    extra = None
-                    if self._needs_extra:
-                        extra = build_constraint_tables(
-                            warm_pods, nodes, [],
-                            pod_capacity=wave_cap,
-                            node_capacity=node_capacity,
-                            scan_planes=False, device=False,
-                        )
-                    out = self._get_evaluator().call_packed(
-                        pt, node_static, node_agg, extra
-                    )
-                    jax.block_until_ready(out[1])
-        else:
-            for wave_cap in wave_caps:
-                node_table, _ = CachedNodeTableBuilder().build(
-                    infos, capacity=node_capacity, prof_capacity=prof_capacity
+        # warm the single-program packed entry points for BOTH pod
+        # schemas a live wave can take, each a distinct executable keyed
+        # on the packed metas.  The throwaway builder carries the mesh so
+        # the warm statics are sharded exactly like the live ones.
+        node_static, node_agg, _ = CachedNodeTableBuilder(
+            mesh=self.mesh
+        ).build_packed(
+            infos, capacity=node_capacity, prof_capacity=prof_capacity
+        )
+        for wave_cap in wave_caps:
+            for warm_pods in (pods, pods + [complex_pod]):
+                pt, _ = build_pod_table(
+                    warm_pods, capacity=wave_cap, device=False
                 )
-                pod_table, _ = build_pod_table(pods, capacity=wave_cap)
                 extra = None
                 if self._needs_extra:
                     extra = build_constraint_tables(
-                        pods, nodes, [],
-                        pod_capacity=wave_cap, node_capacity=node_capacity,
-                        scan_planes=False,
+                        warm_pods, nodes, [],
+                        pod_capacity=wave_cap,
+                        node_capacity=node_capacity,
+                        scan_planes=False, device=False,
                     )
-                out = self._get_evaluator()(pod_table, node_table, extra)
+                out = self._get_evaluator().call_packed(
+                    pt, node_static, node_agg, extra
+                )
                 jax.block_until_ready(out[1])
         if self._has_cross_pod and scan:
             # cross-pod-constrained pods ride the sequential scan — warm
             # BOTH chunk capacities (_schedule_scan uses exactly these
             # two; a partial chunk would otherwise compile the small one
-            # mid-run).  Fresh node table: the mesh-mode repair warm above
-            # donates its (re-sharded) argument and must not alias this.
-            # the blocked lane has one extra (bigger) tier than the exact
-            # lane — warm each executable only at the caps it runs
+            # mid-run).  The blocked lane has one extra (bigger) tier than
+            # the exact lane — warm each executable only at the caps it runs
             scan_caps = {self.SCAN_MIN_CAP, self.SCAN_MAX_CHUNK}
             blocked_caps = (
                 scan_caps | {self.BLOCKED_MAX_CHUNK}
@@ -877,131 +823,108 @@ class DeviceScheduler(Scheduler):
                 else set()
             )
             all_caps = sorted(scan_caps | blocked_caps)
-            if packed_mode:
-                # scan chunks carry cross-pod pods, which are never
-                # "simple" — the live schema is the SLOW pod table; warm
-                # exactly that packed entry per chunk capacity.  The
-                # blocked lane's schema also depends on which
-                # SCAN_ELIDE_GROUPS the chunk's workload leaves all-zero:
-                # warm its two common corners — a spread-only burst
-                # (affinity + volume groups elided) and the kitchen sink
-                # (nothing elided); a mixed burst in between compiles
-                # once mid-run and persists in the compile cache.
-                from minisched_tpu.api.objects import (
-                    Affinity,
-                    LabelSelector,
-                    PodAffinity,
-                    PodAffinityTerm,
-                    PodAntiAffinity,
-                    TopologySpreadConstraint,
-                    WeightedPodAffinityTerm,
-                )
+            # scan chunks carry cross-pod pods, which are never
+            # "simple" — the live schema is the SLOW pod table; warm
+            # exactly that packed entry per chunk capacity.  The
+            # blocked lane's schema also depends on which
+            # SCAN_ELIDE_GROUPS the chunk's workload leaves all-zero:
+            # warm its two common corners — a spread-only burst
+            # (affinity + volume groups elided) and the kitchen sink
+            # (nothing elided); a mixed burst in between compiles
+            # once mid-run and persists in the compile cache.
+            from minisched_tpu.api.objects import (
+                Affinity,
+                LabelSelector,
+                PodAffinity,
+                PodAffinityTerm,
+                PodAntiAffinity,
+                TopologySpreadConstraint,
+                WeightedPodAffinityTerm,
+            )
 
-                def _spread(name):
-                    p = make_pod(
-                        name, requests={"cpu": "1"}, labels={"app": "warm"}
+            def _spread(name):
+                p = make_pod(
+                    name, requests={"cpu": "1"}, labels={"app": "warm"}
+                )
+                p.spec.topology_spread_constraints = [
+                    TopologySpreadConstraint(
+                        max_skew=1,
+                        topology_key="warmzone",
+                        when_unsatisfiable="DoNotSchedule",
+                        label_selector=LabelSelector(
+                            match_labels={"app": "warm"}
+                        ),
                     )
-                    p.spec.topology_spread_constraints = [
-                        TopologySpreadConstraint(
-                            max_skew=1,
-                            topology_key="warmzone",
-                            when_unsatisfiable="DoNotSchedule",
-                            label_selector=LabelSelector(
-                                match_labels={"app": "warm"}
+                ]
+                return p
+
+            sink_pod = _spread("warmsink")
+            sel = LabelSelector(match_labels={"app": "warm"})
+            sink_pod.spec.affinity = Affinity(
+                pod_affinity=PodAffinity(
+                    required=[
+                        PodAffinityTerm(
+                            label_selector=sel, topology_key="warmzone"
+                        )
+                    ],
+                    preferred=[
+                        WeightedPodAffinityTerm(
+                            weight=1,
+                            term=PodAffinityTerm(
+                                label_selector=sel,
+                                topology_key="warmzone",
                             ),
                         )
-                    ]
-                    return p
-
-                sink_pod = _spread("warmsink")
-                sel = LabelSelector(match_labels={"app": "warm"})
-                sink_pod.spec.affinity = Affinity(
-                    pod_affinity=PodAffinity(
-                        required=[
-                            PodAffinityTerm(
-                                label_selector=sel, topology_key="warmzone"
-                            )
-                        ],
-                        preferred=[
-                            WeightedPodAffinityTerm(
-                                weight=1,
-                                term=PodAffinityTerm(
-                                    label_selector=sel,
-                                    topology_key="warmzone",
-                                ),
-                            )
-                        ],
-                    ),
-                    pod_anti_affinity=PodAntiAffinity(
-                        required=[
-                            PodAffinityTerm(
-                                label_selector=LabelSelector(
-                                    match_labels={"app": "other"}
-                                ),
-                                topology_key="warmzone",
-                            )
-                        ]
-                    ),
-                )
-                sink_pod.spec.volumes = ["warmclaim"]
-                blocked_sets = ([_spread("warmspread")], [sink_pod])
-                for cap in all_caps:
-                    if cap in scan_caps:
-                        scan_pods, _ = build_pod_table(
-                            pods + [complex_pod], capacity=cap, device=False
+                    ],
+                ),
+                pod_anti_affinity=PodAntiAffinity(
+                    required=[
+                        PodAffinityTerm(
+                            label_selector=LabelSelector(
+                                match_labels={"app": "other"}
+                            ),
+                            topology_key="warmzone",
                         )
-                        scan_extra = build_constraint_tables(
-                            pods + [complex_pod], nodes, [],
+                    ]
+                ),
+            )
+            sink_pod.spec.volumes = ["warmclaim"]
+            blocked_sets = ([_spread("warmspread")], [sink_pod])
+            for cap in all_caps:
+                if cap in scan_caps:
+                    scan_pods, _ = build_pod_table(
+                        pods + [complex_pod], capacity=cap, device=False
+                    )
+                    scan_extra = build_constraint_tables(
+                        pods + [complex_pod], nodes, [],
+                        pod_capacity=cap,
+                        node_capacity=node_capacity,
+                        scan_planes=True, device=False,
+                        elide_zeros=False,
+                    )
+                    _, choice, _ = self._get_scan_scheduler().call_packed(
+                        scan_pods, node_static, node_agg, scan_extra
+                    )
+                    jax.block_until_ready(choice)
+                if cap in blocked_caps:
+                    for warm_set in blocked_sets:
+                        bp, _ = build_pod_table(
+                            warm_set, capacity=cap, device=False
+                        )
+                        bx = build_constraint_tables(
+                            warm_set, nodes, [],
                             pod_capacity=cap,
                             node_capacity=node_capacity,
                             scan_planes=True, device=False,
                             elide_zeros=False,
+                            elide_groups=SCAN_ELIDE_GROUPS,
                         )
-                        _, choice, _ = self._get_scan_scheduler().call_packed(
-                            scan_pods, node_static, node_agg, scan_extra
+                        _, bc, _, _ = (
+                            self._get_blocked_scheduler().call_packed(
+                                bp, node_static, node_agg, bx
+                            )
                         )
-                        jax.block_until_ready(choice)
-                    if cap in blocked_caps:
-                        for warm_set in blocked_sets:
-                            bp, _ = build_pod_table(
-                                warm_set, capacity=cap, device=False
-                            )
-                            bx = build_constraint_tables(
-                                warm_set, nodes, [],
-                                pod_capacity=cap,
-                                node_capacity=node_capacity,
-                                scan_planes=True, device=False,
-                                elide_zeros=False,
-                                elide_groups=SCAN_ELIDE_GROUPS,
-                            )
-                            _, bc, _, _ = (
-                                self._get_blocked_scheduler().call_packed(
-                                    bp, node_static, node_agg, bx
-                                )
-                            )
-                            jax.block_until_ready(bc)
-                return
-            node_table, _ = CachedNodeTableBuilder().build(
-                infos, capacity=node_capacity, prof_capacity=prof_capacity
-            )
-            for cap in all_caps:
-                scan_pods, _ = build_pod_table(pods, capacity=cap)
-                scan_extra = build_constraint_tables(
-                    pods, nodes, [],
-                    pod_capacity=cap,
-                    node_capacity=node_capacity,
-                    scan_planes=True,
-                )
-                if cap in scan_caps:
-                    _, choice, _ = self._get_scan_scheduler()(
-                        scan_pods, node_table, scan_extra
-                    )
-                    jax.block_until_ready(choice)
-                if cap in blocked_caps:
-                    _, bc, _, _ = self._get_blocked_scheduler()(
-                        scan_pods, node_table, scan_extra
-                    )
-                    jax.block_until_ready(bc)
+                        jax.block_until_ready(bc)
 
     def _get_scan_scheduler(self):
         if self._scan_scheduler is None:
@@ -1216,83 +1139,52 @@ class DeviceScheduler(Scheduler):
             pad_rows = [i for i, m in enumerate(cur) if m is None]
             pods_ = [m.pod if m is not None else dummy for m in cur]
             gang_view = self._gang_view(pods_)
-            packed_mode = self._packed_mode
-            if packed_mode:
-                with self.metrics.timed("scan_build"):
-                    with self.metrics.timed("scan_build_nodes"):
-                        node_static, node_agg, node_names = (
-                            self._table_builder.build_packed(
-                                node_infos, agg_delta=agg_delta
-                            )
+            with self.metrics.timed("scan_build"):
+                with self.metrics.timed("scan_build_nodes"):
+                    node_static, node_agg, node_names = (
+                        self._table_builder.build_packed(
+                            node_infos, agg_delta=agg_delta
                         )
-                    with self.metrics.timed("scan_build_pods"):
-                        pod_table, _ = build_pod_table(
-                            pods_, capacity=cap, device=False,
-                            invalid_rows=pad_rows, gang_view=gang_view,
-                        )
-                    with self.metrics.timed("scan_build_constraints"):
-                        extra = self._build_constraints(
-                            pods_, nodes, assigned,
-                            pod_capacity=cap,
-                            node_capacity=node_agg.capacity,
-                            scan_planes=True,
-                            device=False,
-                            # per-capacity schema discipline: full elision
-                            # made every STATE-driven zero-set flip (combo
-                            # counts appearing mid-run) a fresh executable
-                            # to compile or load — but the
-                            # WORKLOAD-driven groups (affinity terms, pod
-                            # volumes, spread slots) elide as units, so a
-                            # spread-only burst's program folds the other
-                            # lanes entirely (~2× per-step)
-                            elide_zeros=False,
-                            elide_groups=SCAN_ELIDE_GROUPS,
-                        )
-                # gate opens for the device call: held event batches
-                # drain against GIL-free device compute
-                self.informer_factory.resume_dispatch()
-                with self.metrics.timed(
-                    "scan_evaluate", call=self._scan_call, n=len(part_live)
-                ):
-                    with self.metrics.timed("scan_dispatch"):
-                        _, choice, _, accepted = (
-                            self._get_blocked_scheduler().call_packed(
-                                pod_table, node_static, node_agg, extra
-                            )
-                        )
-                    with self.metrics.timed("scan_fetch"):
-                        choice, accepted = jax.device_get(
-                            (choice, accepted)
-                        )
-            else:
-                with self.metrics.timed("scan_build"):
-                    node_table, node_names = self._table_builder.build(
-                        node_infos, agg_delta=agg_delta
                     )
+                with self.metrics.timed("scan_build_pods"):
                     pod_table, _ = build_pod_table(
-                        pods_, capacity=cap, invalid_rows=pad_rows,
-                        gang_view=gang_view,
+                        pods_, capacity=cap, device=False,
+                        invalid_rows=pad_rows, gang_view=gang_view,
                     )
+                with self.metrics.timed("scan_build_constraints"):
                     extra = self._build_constraints(
                         pods_, nodes, assigned,
                         pod_capacity=cap,
-                        node_capacity=node_table.capacity,
+                        node_capacity=node_agg.capacity,
                         scan_planes=True,
+                        device=False,
+                        # per-capacity schema discipline: full elision
+                        # made every STATE-driven zero-set flip (combo
+                        # counts appearing mid-run) a fresh executable
+                        # to compile or load — but the
+                        # WORKLOAD-driven groups (affinity terms, pod
+                        # volumes, spread slots) elide as units, so a
+                        # spread-only burst's program folds the other
+                        # lanes entirely (~2× per-step)
+                        elide_zeros=False,
+                        elide_groups=SCAN_ELIDE_GROUPS,
                     )
-                self.informer_factory.resume_dispatch()
-                with self.metrics.timed(
-                    "scan_evaluate", call=self._scan_call, n=len(part_live)
-                ):
-                    with self.metrics.timed("scan_dispatch"):
-                        _, choice, _, accepted = (
-                            self._get_blocked_scheduler()(
-                                pod_table, node_table, extra
-                            )
+            # gate opens for the device call: held event batches
+            # drain against GIL-free device compute
+            self.informer_factory.resume_dispatch()
+            with self.metrics.timed(
+                "scan_evaluate", call=self._scan_call, n=len(part_live)
+            ):
+                with self.metrics.timed("scan_dispatch"):
+                    _, choice, _, accepted = (
+                        self._get_blocked_scheduler().call_packed(
+                            pod_table, node_static, node_agg, extra
                         )
-                    with self.metrics.timed("scan_fetch"):
-                        choice, accepted = jax.device_get(
-                            (choice, accepted)
-                        )
+                    )
+                with self.metrics.timed("scan_fetch"):
+                    choice, accepted = jax.device_get(
+                        (choice, accepted)
+                    )
             return node_names, choice.tolist(), accepted.tolist()
 
         live = [m for m in part if m is not None]
@@ -1358,65 +1250,39 @@ class DeviceScheduler(Scheduler):
             def build_and_scan(part_):
                 pods_ = [qpi.pod for qpi in part_]
                 gang_view = self._gang_view(pods_)
-                packed_mode = self._packed_mode
-                if packed_mode:
-                    # single-program chunk: flat host buffers unpacked
-                    # inside the scan executable (see _build_and_evaluate)
-                    with self.metrics.timed("scan_build"):
-                        node_static, node_agg, node_names = (
-                            self._table_builder.build_packed(
-                                node_infos, agg_delta=agg_delta
-                            )
-                        )
-                        pod_table, _ = build_pod_table(
-                            pods_, capacity=cap, device=False,
-                            gang_view=gang_view,
-                        )
-                        extra = self._build_constraints(
-                            pods_, nodes, assigned,
-                            pod_capacity=cap,
-                            node_capacity=node_agg.capacity,
-                            scan_planes=True,  # the scan's commits need it
-                            device=False,
-                            elide_zeros=False,  # one packed schema per cap
-                        )
-                    with self.metrics.timed(
-                        "scan_evaluate", call=self._scan_call, n=len(pods_)
-                    ):
-                        with self.metrics.timed("scan_dispatch"):
-                            _, choice, _ = (
-                                self._get_scan_scheduler().call_packed(
-                                    pod_table, node_static, node_agg, extra
-                                )
-                            )
-                        with self.metrics.timed("scan_fetch"):
-                            choice = jax.device_get(choice)
-                    return node_names, choice.tolist()[: len(pods_)]
+                # single-program chunk: flat host buffers unpacked
+                # inside the scan executable (see pipeline.build_wave)
                 with self.metrics.timed("scan_build"):
-                    node_table, node_names = self._table_builder.build(
-                        node_infos, agg_delta=agg_delta
+                    node_static, node_agg, node_names = (
+                        self._table_builder.build_packed(
+                            node_infos, agg_delta=agg_delta
+                        )
                     )
                     pod_table, _ = build_pod_table(
-                        pods_, capacity=cap, gang_view=gang_view
+                        pods_, capacity=cap, device=False,
+                        gang_view=gang_view,
                     )
                     extra = self._build_constraints(
                         pods_, nodes, assigned,
                         pod_capacity=cap,
-                        node_capacity=node_table.capacity,
+                        node_capacity=node_agg.capacity,
                         scan_planes=True,  # the scan's commits need it
+                        device=False,
+                        elide_zeros=False,  # one packed schema per cap
                     )
-                if self.result_store is not None:
-                    # scan pods get the same per-plugin artifact as wave
-                    # pods (diagnostics against the pre-decision snapshot)
-                    self._record_wave(
-                        pods_, pod_table, node_table, node_names, extra
-                    )
+                # scan pods get the same per-plugin artifact as wave
+                # pods (diagnostics against the pre-decision snapshot)
+                self._record_wave(
+                    part_, node_infos, assigned, agg_delta, cap
+                )
                 with self.metrics.timed(
                     "scan_evaluate", call=self._scan_call, n=len(pods_)
                 ):
                     with self.metrics.timed("scan_dispatch"):
-                        _, choice, _ = self._get_scan_scheduler()(
-                            pod_table, node_table, extra
+                        _, choice, _ = (
+                            self._get_scan_scheduler().call_packed(
+                                pod_table, node_static, node_agg, extra
+                            )
                         )
                     with self.metrics.timed("scan_fetch"):
                         choice = jax.device_get(choice)
@@ -1533,24 +1399,7 @@ class DeviceScheduler(Scheduler):
             gc.collect(0)
 
     # the loop: one wave per iteration instead of one pod ------------------
-    def _pipeline_active(self) -> bool:
-        """Pipelined waves in packed mode — single-device AND mesh (the
-        mesh-packed program consumes the same host-built flat buffers, so
-        depth-1 overlap, incremental dirty-row encoding, and commit-time
-        re-arbitration survive unchanged; ISSUE 7 tentpole).  Only
-        record_results keeps the serial loop (it needs device tables).
-        Latched once the worker exists (it owns queue popping from then
-        on)."""
-        if self._pipeline is not None:
-            return True
-        return self.pipeline_enabled and self.result_store is None
-
     def schedule_one(self, timeout: Optional[float] = 0.5) -> bool:
-        if self._pipeline_active():
-            return self._schedule_one_pipelined(timeout)
-        return self._schedule_one_serial(timeout)
-
-    def _schedule_one_pipelined(self, timeout: Optional[float]) -> bool:
         """One loop-thread turn of the two-stage pipeline: take the next
         item off the bounded handoff queue (the build worker pops,
         snapshots, and builds tables concurrently with this thread's
@@ -1558,8 +1407,8 @@ class DeviceScheduler(Scheduler):
         Handoff wait lands in ``loop_pop`` (the accounting identity
         pop+wave+scan_flush+gc ≈ loop wall must keep summing) and — when
         the item is a wave — in ``wave_pipeline_stall``: time the device
-        sat idle because the next build wasn't ready.  A fully-serial
-        regression shows stall ≈ build; `make bench-wave` gates on it."""
+        sat idle because the next build wasn't ready (stall ≈ build is
+        what a loop with no overlap looks like)."""
         from minisched_tpu.observability import counters
 
         pipe = self._pipeline
@@ -1577,13 +1426,20 @@ class DeviceScheduler(Scheduler):
         if item is None or item[0] == "empty":
             if self._scan_backlog:
                 # queue drained with constrained pods still deferred:
-                # flush the lane now (same as the serial idle path)
+                # flush the lane now (the backlog, not the queue, holds
+                # the remaining work)
                 try:
                     self._flush_scan_backlog_timed()
                 finally:
                     with self.metrics.timed("loop_gc"):
                         self._wave_gc()
                 return True
+            # idle: the gate a bind may have closed (see _bind_batch) must
+            # not delay the events that will wake us; and with the
+            # automatic collector off, idle churn (informer handlers,
+            # exception cycles) still needs a periodic sweep.  Assume
+            # leases must expire HERE too — with the queue drained, no
+            # wave snapshot is coming to notice a lost bind's leak.
             self.informer_factory.resume_dispatch()
             self._expire_assume_leases()
             with self.metrics.timed("loop_gc"):
@@ -1592,9 +1448,9 @@ class DeviceScheduler(Scheduler):
         partial = True
         try:
             if item[0] == "raw":
-                # build-stage fallback (encode overflow, empty roster,
-                # priority bypass, injected build fault): the serial wave
-                # path owns every one of those cases already
+                # the worker handed the batch back (encode overflow, empty
+                # roster, priority bypass, all-constrained batch, injected
+                # build fault): schedule_wave owns every one of those
                 _tag, qpis, partial, wave_id = item
                 self.schedule_wave(qpis, wave_id)
             else:
@@ -1626,6 +1482,10 @@ class DeviceScheduler(Scheduler):
                     ):
                         self._flush_scan_backlog_timed()
                 self._run_prepared_wave(prepared)
+            # a partial pop means the queue is (momentarily) drained —
+            # don't sit on deferred constrained pods waiting for a burst
+            # that may never come; the wave-count bound keeps a sustained
+            # stream of full plain waves from starving them indefinitely
             if self._scan_backlog:
                 self._scan_backlog_waves += 1
                 if (
@@ -1635,45 +1495,59 @@ class DeviceScheduler(Scheduler):
                 ):
                     self._flush_scan_backlog_timed()
         finally:
+            # every exit path (incl. scan-only waves and early returns)
+            # collects
             with self.metrics.timed("loop_gc"):
                 self._wave_gc()
         return True
 
     def _run_prepared_wave(self, prepared: Any) -> None:
-        # same metric contract as schedule_wave: every exit observes
-        with self.metrics.timed(
-            "wave", wave=prepared.wave_id, n=len(prepared.qpis)
-        ):
-            self._run_prepared_wave_inner(prepared)
-
-    def _run_prepared_wave_inner(self, prepared: Any) -> None:
-        """Device-evaluate a wave the worker built, then re-arbitrate its
-        winners against state the OVERLAPPED previous wave committed
-        after the build's snapshot, and commit through the unchanged
-        permit/bind tail (AlreadyBound / Conflict / OutOfCapacity still
-        backstop at the store)."""
-        import jax
-
+        """A wave the worker built: the loop thread's bookkeeping for it,
+        then the finish."""
         from minisched_tpu.observability import counters, trace
 
         qpis = prepared.qpis
-        # the worker skips lease expiry (store probes would stall its
-        # overlap window); the loop thread keeps the serial cadence
-        self._expire_assume_leases()
-        counters.inc("wave_pipeline.dirty_rows", prepared.dirty_rows)
-        if prepared.build_skipped:
-            # idle-wave gate fired: this wave reused the previous tables
-            # wholesale (zero node-table build work; ISSUE 8)
-            counters.inc("wave_pipeline.zero_build_waves")
-        # the id the build worker drew at pop: its sched.wave_build span,
-        # this thread's spans and the trace ring's carry the same number
-        wave_id = self._wave_seq = prepared.wave_id
-        trace.span(
-            "wave_build", wave=wave_id, size=len(qpis),
-            build_s=round(prepared.build_s, 6),
-            skipped=prepared.build_skipped or None,
-            dirty_rows=prepared.dirty_rows or None,
-            mesh=self._mesh_shards,
+        # same metric contract as schedule_wave: every exit observes
+        with self.metrics.timed("wave", wave=prepared.wave_id, n=len(qpis)):
+            # the worker skips lease expiry (store probes would stall its
+            # overlap window); the loop thread keeps the per-wave cadence
+            self._expire_assume_leases()
+            counters.inc("wave_pipeline.dirty_rows", prepared.dirty_rows)
+            if prepared.build_skipped:
+                # idle-wave gate fired: this wave reused the previous
+                # tables wholesale (zero node-table build work)
+                counters.inc("wave_pipeline.zero_build_waves")
+            # the id the build worker drew at pop: its sched.wave_build
+            # span, this thread's spans and the trace ring's carry the
+            # same number
+            self._wave_seq = prepared.wave_id
+            trace.span(
+                "wave_build", wave=prepared.wave_id, size=len(qpis),
+                build_s=round(prepared.build_s, 6),
+                skipped=prepared.build_skipped or None,
+                dirty_rows=prepared.dirty_rows or None,
+                mesh=self._mesh_shards,
+            )
+            self._finish_wave(prepared)
+
+    def _finish_wave(self, prepared: Any) -> None:
+        """The one wave body after the build, whoever built it:
+        device-evaluate, sort winners from losers, re-arbitrate a wave
+        built ahead against what the OVERLAPPED previous wave committed
+        after its snapshot, and commit through the permit/bind tail
+        (AlreadyBound / Conflict / OutOfCapacity still backstop at the
+        store)."""
+        import jax
+
+        from minisched_tpu.observability import trace
+
+        qpis = prepared.qpis
+        wave_id = self._wave_seq
+        # before the device call and before any bind of this wave lands:
+        # the store's update hook flushes the record onto the pod then
+        self._record_wave(
+            qpis, prepared.node_infos, prepared.assigned,
+            prepared.agg_delta, prepared.pod_table.capacity,
         )
         # gate opens for the device call: the previous wave's held bind
         # events drain against GIL-free device compute — and the build
@@ -1701,9 +1575,12 @@ class DeviceScheduler(Scheduler):
                     out_devices = sorted(
                         f"{d.platform}:{d.id}" for d in choice.devices()
                     )
+                    # ONE host fetch for both results (each device_get is
+                    # a blocking device→host copy)
                     with self.metrics.timed("wave_fetch"):
                         choice, unsched = jax.device_get((choice, unsched))
                 with self.metrics.timed("wave_postfetch"):
+                    # bool[K, P] → per-pod failing-plugin sets
                     unsched = unsched.tolist()
                     plugin_names = [p.name() for p in self.filter_plugins]
                     fail_sets = [
@@ -1716,8 +1593,8 @@ class DeviceScheduler(Scheduler):
                     ]
                     placements = choice.tolist()[: len(qpis)]
         except Exception as err:
-            # tables were already built, so no encode retry applies here
-            # — park the batch exactly like the serial exception path
+            # the tables are built, so no encode retry applies here —
+            # never lose a popped wave: requeue all
             self._note_park(err, len(qpis))
             for qpi in qpis:
                 self.error_func(qpi, err)
@@ -1727,13 +1604,15 @@ class DeviceScheduler(Scheduler):
         node_names = prepared.node_names
         losers: List[Any] = []
         winners: List[Any] = []
+        rejected: List[Any] = []
         with self.metrics.timed("wave_winners"):
             for qpi, c, fails in zip(qpis, placements, fail_sets):
                 if c < 0:
                     losers.append((qpi, qpi.pod, fails))
                 else:
                     winners.append((qpi, qpi.pod, node_names[c]))
-            winners, rejected = self._rearbitrate_winners(winners)
+            if prepared.built_ahead:
+                winners, rejected = self._rearbitrate_winners(winners)
             for _qpi, pod, node_name in winners:
                 self._assume(pod, node_name)
             for _qpi, pod, _node in rejected:
@@ -1751,6 +1630,16 @@ class DeviceScheduler(Scheduler):
         if losers:
             self._handle_wave_losers(
                 losers, prepared.node_infos, len(prepared.node_infos)
+            )
+        if _WAVE_LOG:
+            import sys
+
+            print(
+                f"[wave t={time.monotonic():.2f}] size={len(qpis)} "
+                f"winners={len(winners)} losers={len(losers)} "
+                f"requeued={len(rejected)}",
+                file=sys.stderr,
+                flush=True,
             )
 
     def _rearbitrate_winners(self, winners: List[Any]):
@@ -1826,56 +1715,6 @@ class DeviceScheduler(Scheduler):
                     counters.inc("gang.rearb_atomic_release", len(moved))
             counters.inc("wave_pipeline.rearb_requeued", len(reject))
         return keep, reject
-
-    def _schedule_one_serial(self, timeout: Optional[float] = 0.5) -> bool:
-        # loop_pop/loop_gc/scan_flush: together with "wave" these account
-        # for the engine thread's whole wall — the e2e budget must sum
-        # (VERDICT r4: ~1.5s of 9.5s was invisible to the breakdown)
-        with self.metrics.timed("loop_pop"):
-            qpis = self.queue.pop_batch(self.max_wave, timeout=timeout)
-        if not qpis:
-            if self._scan_backlog:
-                # queue drained with constrained pods still deferred:
-                # flush the lane now (the backlog, not the queue, holds
-                # the remaining work)
-                try:
-                    self._flush_scan_backlog_timed()
-                finally:
-                    with self.metrics.timed("loop_gc"):
-                        self._wave_gc()
-                return True
-            # idle: the gate a bind may have closed (see _bind_batch) must
-            # not delay the events that will wake us; and with the
-            # automatic collector off, idle churn (informer handlers,
-            # exception cycles) still needs a periodic sweep.  Assume
-            # leases must expire HERE too — with the queue drained, no
-            # wave snapshot is coming to notice a lost bind's leak.
-            self.informer_factory.resume_dispatch()
-            self._expire_assume_leases()
-            with self.metrics.timed("loop_gc"):
-                self._wave_gc()
-            return False
-        partial = len(qpis) < self.max_wave
-        try:
-            self.schedule_wave(qpis)
-            # a partial pop means the queue is (momentarily) drained —
-            # don't sit on deferred constrained pods waiting for a burst
-            # that may never come; the wave-count bound keeps a sustained
-            # stream of full plain waves from starving them indefinitely
-            if self._scan_backlog:
-                self._scan_backlog_waves += 1
-                if (
-                    partial
-                    or len(self._scan_backlog) >= self.BLOCKED_MAX_CHUNK
-                    or self._scan_backlog_waves >= self.SCAN_DEFER_MAX_WAVES
-                ):
-                    self._flush_scan_backlog_timed()
-        finally:
-            # every exit path (incl. scan-only waves and early returns)
-            # collects; schedule_wave's own call was only on the main path
-            with self.metrics.timed("loop_gc"):
-                self._wave_gc()
-        return True
 
     def _flush_scan_backlog_timed(self) -> None:
         """The flush as the loop's own phase (``scan_flush``); the wave's
@@ -1998,13 +1837,11 @@ class DeviceScheduler(Scheduler):
     def schedule_wave(
         self, qpis: List[QueuedPodInfo], wave_id: Optional[int] = None
     ) -> None:
-        """``wave_id``: the id the build worker drew when it popped a
-        batch it then handed back raw; a wave popped here draws its own."""
-        # the 'wave' metric must observe EVERY exit path (empty-node
-        # return, parked batch, scan-only wave, a raise) — the bench's
-        # e2e accounting asserts pop+wave+scan_flush+gc sums to the loop
-        # wall, and an invisible exit breaks the invariant (advisor r5)
-        t_wave = time.monotonic()
+        """A popped batch on the loop thread, from split to commit: what
+        ``schedule_one`` runs for a batch the worker handed back raw, and
+        what a caller without a loop (tests) runs directly.  ``wave_id``:
+        the id the build worker drew when it popped the batch; a wave
+        popped here draws its own."""
         self._wave_seq = wave_id or self._next_wave_id()
         from minisched_tpu.observability import trace
 
@@ -2012,12 +1849,13 @@ class DeviceScheduler(Scheduler):
             "wave_build", wave=self._wave_seq, size=len(qpis),
             serial=True, mesh=self._mesh_shards,
         )
+        # the 'wave' metric must observe EVERY exit path (empty-node
+        # return, parked batch, scan-only wave, a raise): pop + wave +
+        # scan_flush + gc account for the loop thread's whole wall
         with self.metrics.timed("wave", wave=self._wave_seq, n=len(qpis)):
-            self._schedule_wave_inner(qpis, t_wave)
+            self._schedule_wave_inner(qpis)
 
-    def _schedule_wave_inner(
-        self, qpis: List[QueuedPodInfo], t_wave: float
-    ) -> None:
+    def _schedule_wave_inner(self, qpis: List[QueuedPodInfo]) -> None:
 
         # cross-pod-constrained pods run on device via the sequential scan
         # (they see each other's commits in the carried combo planes —
@@ -2040,7 +1878,7 @@ class DeviceScheduler(Scheduler):
                 self._scan_backlog.extend(constrained)
                 plain = [qpi for qpi in qpis if not _is_cross_pod(qpi.pod)]
                 if not plain:
-                    return  # schedule_wave's finally observes the metric
+                    return  # nothing for the wave: no snapshot, no build
                 qpis = plain
             # priority-inversion bypass (advisor r4): deferral reorders
             # constrained pods behind up to SCAN_DEFER_MAX_WAVES full
@@ -2060,155 +1898,23 @@ class DeviceScheduler(Scheduler):
                     self._flush_scan_backlog()
 
         with self.metrics.timed("wave_snapshot"):
-            if self._pipeline is not None:
-                # raw-fallback wave while the pipeline runs: the build
-                # worker is the single ordered consumer of the cache's
-                # dirty-set — draining it here too would interleave two
-                # snapshot orders into one aggregate base (stale-row
-                # overwrites).  Untracked builds never touch the base;
-                # the accumulated dirt stays pending for the worker.
-                node_infos, agg_delta, assumed_pods = (
-                    self._snapshot_for_wave()
-                )
-                dirty, epoch = DIRTY_UNTRACKED, None
-            else:
-                node_infos, agg_delta, assumed_pods, dirty, epoch = (
-                    self._snapshot_for_tables()
-                )
-        if not node_infos:
+            # while a build worker exists it is the single ordered
+            # consumer of the cache's dirty-set — draining it here too
+            # would interleave two snapshot orders into one aggregate base
+            # (stale-row overwrites).  Untracked builds never touch the
+            # base; the accumulated dirt stays pending for the worker.
+            snapshot = self._snapshot_for_tables(
+                want_dirty=self._pipeline is None
+            )
+        if not snapshot[0]:
             for qpi in qpis:
                 self.error_func(qpi, FitError(qpi.pod, 0, Diagnosis()))
             return
-
-        with self.metrics.timed("wave_assigned_list"):
-            nodes = [ni.node for ni in node_infos]  # name-sorted by snapshot
-            # with a live index the build never walks the population; the
-            # index-less build must see the assumed pods explicitly now
-            # that the snapshot no longer folds them into NodeInfos
-            assigned = (
-                ()
-                if self.constraint_index is not None
-                else [p for ni in node_infos for p in ni.pods] + assumed_pods
-            )
-
-        def build_and_evaluate(qpis_):
-            with self.metrics.timed("wave_evaluate"):
-                return self._build_and_evaluate(
-                    qpis_, node_infos, nodes, assigned, agg_delta, dirty,
-                    epoch,
-                )
-
-        qpis, result = self._evaluate_or_park(qpis, build_and_evaluate)
-        if result is None:
-            return
-        node_names, placements, fail_sets = result
-        pods = [qpi.pod for qpi in qpis]
-
-        losers: List[Any] = []
-        winners: List[Any] = []
-        with self.metrics.timed("wave_winners"):
-            for qpi, pod, c, fails in zip(qpis, pods, placements, fail_sets):
-                if c < 0:
-                    losers.append((qpi, pod, fails))
-                    continue
-                self._assume(pod, node_names[c])
-                winners.append((qpi, pod, node_names[c]))
-        self._commit_winners(winners)
-        if losers:
-            self._handle_wave_losers(losers, node_infos, len(nodes))
-        dur = time.monotonic() - t_wave
-        if _WAVE_LOG:
-            import sys
-
-            print(
-                f"[wave t={time.monotonic():.2f}] size={len(qpis)} "
-                f"dur={dur:.2f}s winners={len(winners)} losers={len(losers)}",
-                file=sys.stderr,
-                flush=True,
-            )
-
-    def _build_and_evaluate(
-        self, qpis_, node_infos, nodes, assigned, agg_delta=None,
-        dirty=DIRTY_UNTRACKED, epoch=None,
-    ):
-        """One repair-wave evaluation: tables → fused repair evaluator →
-        (node_names, placements, per-pod failing-plugin sets).
-
-        Packed waves: tables stay host-side as flat buffers and the
-        evaluator unpacks them inside its one jitted program — a wave is
-        one dispatch and three flat transfers instead of a per-table
-        splitter program alternating with the evaluator.  record_results
-        (which needs device tables for the diagnostics evaluation) keeps
-        the unpacked path."""
-        import jax
-
-        pods_ = [qpi.pod for qpi in qpis_]
-        packed_mode = self._packed_mode
-        pod_capacity = self._wave_cap(len(pods_))
-        gang_view = self._gang_view(pods_)
-        with self.metrics.timed("wave_build_tables"):
-            if packed_mode:
-                node_static, node_agg, node_names = (
-                    self._table_builder.build_packed(
-                        node_infos, agg_delta=agg_delta, dirty=dirty,
-                        epoch=epoch,
-                    )
-                )
-                node_capacity = node_agg.capacity
-                pod_table, _ = build_pod_table(
-                    pods_, capacity=pod_capacity, device=False,
-                    gang_view=gang_view,
-                )
-            else:
-                node_table, node_names = self._table_builder.build(
-                    node_infos, agg_delta=agg_delta, dirty=dirty,
-                    epoch=epoch,
-                )
-                node_capacity = node_table.capacity
-                pod_table, _ = build_pod_table(
-                    pods_, capacity=pod_capacity, gang_view=gang_view
-                )
-        extra = None
-        if self._needs_extra:
-            with self.metrics.timed("wave_build_constraints"):
-                extra = self._build_constraints(
-                    pods_, nodes, assigned,
-                    pod_capacity=pod_capacity,
-                    node_capacity=node_capacity,
-                    scan_planes=False,  # wave mode never runs the scan
-                    device=not packed_mode,
-                )
-        if self.result_store is not None:
-            self._record_wave(pods_, pod_table, node_table, node_names, extra)
-        # the device call releases the GIL for the whole evaluation —
-        # let the event handlers for the previous wave's binds run there
-        self.informer_factory.resume_dispatch()
-        with self.metrics.timed(
-            "wave_device", wave=self._wave_seq, n=len(pods_)
-        ):
-            with self.metrics.timed("wave_dispatch"):
-                if packed_mode:
-                    _, choice, _, unsched = self._eval_packed_wave(
-                        pod_table, node_static, node_agg, extra,
-                        len(pods_), len(node_infos),
-                    )
-                else:
-                    _, choice, _, unsched = self._get_evaluator()(
-                        pod_table, node_table, extra
-                    )
-            # ONE host fetch for both results (each device_get is a
-            # blocking device→host copy); bool[K, P] → per-pod
-            # failing-plugin sets
-            with self.metrics.timed("wave_fetch"):
-                choice, unsched = jax.device_get((choice, unsched))
-        with self.metrics.timed("wave_postfetch"):
-            unsched = unsched.tolist()
-            plugin_names = [p.name() for p in self.filter_plugins]
-            fail_sets = [
-                {name for k, name in enumerate(plugin_names) if unsched[k][i]}
-                for i in range(len(pods_))
-            ]
-            return node_names, choice.tolist()[: len(pods_)], fail_sets
+        qpis, prepared = self._evaluate_or_park(
+            qpis, lambda qpis_: build_wave(self, qpis_, snapshot)
+        )
+        if prepared is not None:
+            self._finish_wave(prepared)
 
     def _handle_wave_losers(
         self, losers: List[Any], node_infos: List[Any], n_nodes: int
@@ -2369,15 +2075,24 @@ class DeviceScheduler(Scheduler):
         return good
 
     def _record_wave(
-        self, pods_, pod_table, node_table, node_names, extra
+        self, qpis, node_infos, assigned, agg_delta, pod_capacity
     ) -> None:
-        """record_results support for the wave path: one diagnostics-
-        enabled fused evaluation of the wave against the pre-wave snapshot
-        (the decision basis), ingested via ``Store.record_batch_result`` —
-        the wave emits the same per-plugin artifact the scalar recorders
-        produce (SURVEY §2 row 10): same annotation keys, same canonical
-        rejection strings — flushed onto pod annotations by the store's
-        update hook when the binds land."""
+        """record_results support: one diagnostics-enabled fused
+        evaluation of the wave against its snapshot (the decision basis),
+        ingested via ``Store.record_batch_result`` — the wave emits the
+        same per-plugin artifact the scalar recorders produce (SURVEY §2
+        row 10): same annotation keys, same canonical rejection strings —
+        flushed onto pod annotations by the store's update hook when the
+        binds land.
+
+        The engine's tables are packed host buffers; the diagnostics
+        evaluator wants device tables, so this builds its own from the
+        same snapshot, with a builder of its own and untracked: it never
+        drains the cache's dirty set nor touches ``_table_builder``'s
+        aggregate base."""
+        if self.result_store is None:
+            return
+        pods_ = [qpi.pod for qpi in qpis]
         from minisched_tpu.ops.fused import FusedEvaluator
         from minisched_tpu.plugins.registry import canonical_filter_reasons
 
@@ -2389,7 +2104,23 @@ class DeviceScheduler(Scheduler):
                 weights=self.score_weights,
                 with_diagnostics=True,
             )
+            self._record_builder = CachedNodeTableBuilder()
         try:
+            node_table, node_names = self._record_builder.build(
+                node_infos, agg_delta=agg_delta
+            )
+            pod_table, _ = build_pod_table(
+                pods_, capacity=pod_capacity,
+                gang_view=self._gang_view(pods_),
+            )
+            extra = None
+            if self._needs_extra:
+                extra = self._build_constraints(
+                    pods_, [ni.node for ni in node_infos], assigned,
+                    pod_capacity=pod_capacity,
+                    node_capacity=node_table.capacity,
+                    scan_planes=False,  # a fused evaluation, never a scan
+                )
             result = self._diag_evaluator(pod_table, node_table, extra)
         except Exception:
             import traceback
@@ -2509,8 +2240,8 @@ class DeviceScheduler(Scheduler):
         # close the dispatch gate BEFORE the events fan out: the informer
         # threads then hold this wave's thousands of bind events through
         # the next wave's host stretch (pop/snapshot/build) and process
-        # them inside its GIL-free device call — _build_and_evaluate
-        # reopens the gate, schedule_one reopens it when the queue idles.
+        # them inside its GIL-free device call — _finish_wave reopens
+        # the gate, schedule_one reopens it when the queue idles.
         # The handler work is identical either way (the assume-cache
         # carries placements until the events land); only WHEN it contends
         # for the GIL changes.

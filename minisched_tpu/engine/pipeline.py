@@ -1,40 +1,39 @@
 """Two-stage wave pipeline: host build overlapped with device evaluate.
 
-BENCH_r05 showed the device kernel placing 70M pods/s while the full
-chain landed at 10.4k: of a 7.9s wave loop, device evaluate was 4.0s and
-the host-side phases (snapshot, pack tables, build constraints, commit,
-gc) ran strictly serially around it — the TPU sat idle for most of every
-wave.  This module overlaps them: a BUILD WORKER thread pops wave N+1
-from the scheduling queue, snapshots, and packs its tables while the
-loop thread blocks (GIL released) in wave N's device call; a bounded
-handoff queue (depth 1) is the backpressure between the stages, and the
-loop thread's commit/losers handling for wave N overlaps the worker's
-build of wave N+2 the same way.
+A BUILD WORKER thread pops wave N+1 from the scheduling queue, snapshots,
+and packs its tables while the loop thread blocks (GIL released) in wave
+N's device call; a bounded handoff queue (depth 1) is the backpressure
+between the stages, and the loop thread's commit/losers handling for wave
+N overlaps the worker's build of wave N+2 the same way.
 
-Correctness: wave N+1's snapshot predates wave N's commits, so its
-winners are RE-ARBITRATED on the loop thread against the current
-capacity view before assume/commit (DeviceScheduler._rearbitrate_winners
-— losers requeue and re-place against a fresh snapshot), and the bind
-transaction's AlreadyBound / Conflict / OutOfCapacity preconditions
-remain the store-side backstop, unchanged.  Anything the build stage
-cannot handle (encode overflow, an empty roster, the cross-pod priority
-bypass, an injected build fault) is handed back RAW and takes the exact
-serial wave path.
+The build itself is ``build_wave``: one function, called by the worker
+for a wave it builds ahead and by the loop thread
+(``DeviceScheduler.schedule_wave``) for a batch the worker hands back.
+Either way the result is a ``PreparedWave`` and the loop thread finishes
+it in ``DeviceScheduler._finish_wave``.
 
-Mesh composition (ISSUE 7): the build stage's output is packed HOST
-buffers, so the same pipeline drives the mesh-sharded evaluator
-unchanged — the shared table builder pads capacities to the mesh-axis
-multiples and keeps the static node columns device-resident sharded;
-the loop thread's device call dispatches the sharded program
-(DeviceScheduler._eval_packed_wave, with its own per-wave single-device
-fallback ladder).  Nothing in this module is mesh-aware by design.
+Correctness: a wave built ahead has a snapshot that predates wave N's
+commits, so its winners are RE-ARBITRATED on the loop thread against the
+current capacity view before assume/commit
+(DeviceScheduler._rearbitrate_winners — losers requeue and re-place
+against a fresh snapshot), and the bind transaction's AlreadyBound /
+Conflict / OutOfCapacity preconditions remain the store-side backstop,
+unchanged.  Anything the worker cannot handle (encode overflow, an empty
+roster, the cross-pod priority bypass, an injected build fault) is handed
+back RAW: the loop thread owns the backlog, the retry and the park.
 
-``MINISCHED_PIPELINE=0`` disables the whole stage — the engine then runs
-the untouched serial loop (DeviceScheduler._schedule_one_serial).
+Mesh composition: the build's output is packed HOST buffers, so the same
+pipeline drives the mesh-sharded evaluator unchanged — the shared table
+builder pads capacities to the mesh-axis multiples and keeps the static
+node columns device-resident sharded; the loop thread's device call
+dispatches the sharded program (DeviceScheduler._eval_packed_wave, with
+its own per-wave single-device fallback ladder).  Nothing in this module
+is mesh-aware by design.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import queue as _queue
 import threading
 from typing import Any, List, Optional
@@ -51,48 +50,95 @@ profiling.register_phases(
 )
 
 
+@dataclasses.dataclass(slots=True)
 class PreparedWave:
-    """One wave's build-stage output, handed loop-ward over the queue."""
+    """One wave's build output: what ``build_wave`` hands the loop
+    thread's finish (over the handoff queue when the worker built it)."""
 
-    __slots__ = (
-        "qpis",
-        "constrained",
-        "partial",
-        "node_infos",
-        "node_names",
-        "node_static",
-        "node_agg",
-        "pod_table",
-        "extra",
-        "build_s",
-        "dirty_rows",
-        "build_skipped",
-        "wave_id",
-    )
+    qpis: List[Any]
+    #: the snapshot the tables encode — the losers' preemption base, and
+    #: with ``agg_delta`` / ``assigned`` what the record_results recorder
+    #: rebuilds device tables from
+    node_infos: List[Any]
+    agg_delta: Any
+    assigned: Any = ()
+    node_names: Any = ()
+    node_static: Any = None
+    node_agg: Any = None
+    pod_table: Any = None
+    extra: Any = None
+    #: the cross-pod pods of the popped batch, for the loop's backlog
+    constrained: Any = ()
+    partial: bool = True
+    #: the worker built this wave while an earlier one was still in
+    #: flight: its snapshot predates commits the loop thread has made
+    #: since, so the finish re-arbitrates its winners.  A wave built on
+    #: the loop thread sees every commit and keeps them all.
+    built_ahead: bool = False
+    build_s: float = 0.0
+    dirty_rows: int = 0
+    #: the node-table build was skipped wholesale (idle-wave gate: nothing
+    #: dirty, roster epoch unchanged, same assume-delta); the loop thread
+    #: counts these per wave
+    build_skipped: bool = False
+    #: one id for this wave on every thread, assigned at pop: the build
+    #: worker's spans, the loop's, and the trace ring's carry it
+    wave_id: int = 0
 
-    def __init__(self) -> None:
-        self.qpis: List[Any] = []
-        self.constrained: List[Any] = []
-        self.partial = True
-        self.node_infos: List[Any] = []
-        self.node_names: List[str] = []
-        self.node_static: Any = None
-        self.node_agg: Any = None
-        self.pod_table: Any = None
-        self.extra: Any = None
-        self.build_s = 0.0
-        self.dirty_rows = 0
-        #: the node-table build was skipped wholesale (idle-wave gate:
-        #: nothing dirty, roster epoch unchanged, same assume-delta —
-        #: ISSUE 8); the loop thread counts these per wave
-        self.build_skipped = False
-        #: one id for this wave on every thread, assigned at pop: the
-        #: build worker's spans, the loop's, and the trace ring's carry it
-        self.wave_id = 0
+
+def build_wave(sched: Any, qpis: List[Any], snapshot: Any) -> PreparedWave:
+    """One wave's tables from the snapshot the caller took: packed node
+    tables → pod table → constraints, all flat HOST buffers the
+    evaluator unpacks inside its one jitted program.
+
+    ``snapshot`` is ``DeviceScheduler._snapshot_for_tables``' five.  The
+    cache's dirty set has ONE ordered consumer — the worker while it
+    exists, else the loop thread — and only that consumer passes a
+    tracked (dirty, epoch); anyone else passes ``DIRTY_UNTRACKED`` and
+    the builder's aggregate base is left alone.  An encode overflow
+    raises ValueError (the callers own the retry)."""
+    from minisched_tpu.models.tables import build_pod_table
+
+    node_infos, agg_delta, assumed_pods, dirty, epoch = snapshot
+    prepared = PreparedWave(qpis, node_infos, agg_delta)
+    pods_ = [q.pod for q in qpis]
+    with sched.metrics.timed("wave_assigned_list"):
+        nodes = [ni.node for ni in node_infos]  # name-sorted by snapshot
+        # with a live index the build never walks the population; the
+        # index-less build must see the assumed pods explicitly (the
+        # snapshot carries them as a numeric delta, not in the NodeInfos)
+        prepared.assigned = assigned = (
+            ()
+            if sched.constraint_index is not None
+            else [p for ni in node_infos for p in ni.pods] + assumed_pods
+        )
+    pod_capacity = sched._wave_cap(len(pods_))
+    # placed-gang aggregates for this wave's members (assume-cache folded
+    # in), against the same snapshot the tables encode
+    gang_view = sched._gang_view(pods_)
+    with sched.metrics.timed("wave_build_tables"):
+        prepared.node_static, prepared.node_agg, prepared.node_names = (
+            sched._table_builder.build_packed(
+                node_infos, agg_delta=agg_delta, dirty=dirty, epoch=epoch
+            )
+        )
+        prepared.pod_table, _ = build_pod_table(
+            pods_, capacity=pod_capacity, device=False, gang_view=gang_view
+        )
+    if sched._needs_extra:
+        with sched.metrics.timed("wave_build_constraints"):
+            prepared.extra = sched._build_constraints(
+                pods_, nodes, assigned,
+                pod_capacity=pod_capacity,
+                node_capacity=prepared.node_agg.capacity,
+                scan_planes=False,  # wave mode never runs the scan
+                device=False,
+            )
+    return prepared
 
 
 class _BuildFallback(Exception):
-    """Internal: this batch must take the serial wave path."""
+    """Internal: the loop thread must take this batch (hand it back raw)."""
 
 
 class WavePipeline:
@@ -101,12 +147,12 @@ class WavePipeline:
     Items on the handoff queue:
 
     * ``("wave", PreparedWave)`` — tables built, ready for the device.
-    * ``("raw", qpis, partial, wave_id)`` — build-stage fallback; the loop
-      thread runs the serial ``schedule_wave`` over the original batch.
+    * ``("raw", qpis, partial, wave_id)`` — the worker could not build
+      it; the loop thread runs ``schedule_wave`` over the original batch.
     * ``("empty",)`` — a pop window elapsed with nothing to do; the loop
       thread runs its idle path (lease expiry, backlog flush, gc).
 
-    The worker is the ONLY queue popper while the pipeline is active, so
+    The worker is the ONLY queue popper, so
     pop order (priority/FIFO) is preserved; the handoff depth of 1 means
     at most two waves' pods are ever outside the queues (one on device,
     one built/building), and ``drain()`` hands any stranded ones back to
@@ -222,89 +268,45 @@ class WavePipeline:
             return ("raw", qpis, partial, wave_id)
         except Exception:
             # encode overflow (ValueError), an injected store fault in
-            # the constraint build, anything unforeseen: the serial path
+            # the constraint build, anything unforeseen: schedule_wave
             # owns the retry/park machinery for all of them
             counters.inc("wave_pipeline.build_fallback")
             return ("raw", qpis, partial, wave_id)
 
     def _build(self, qpis: List[Any]) -> PreparedWave:
+        """The worker's front — what must not run here raises
+        ``_BuildFallback`` — then ``build_wave`` on a tracked snapshot."""
         from minisched_tpu.engine.device_scheduler import _is_cross_pod
-        from minisched_tpu.models.tables import build_pod_table
 
         sched = self._sched
-        prepared = PreparedWave()
-        prepared.qpis = qpis
+        plain, constrained = qpis, []
         if sched._has_cross_pod:
             constrained = [q for q in qpis if _is_cross_pod(q.pod)]
             if constrained:
-                prepared.constrained = constrained
-                prepared.qpis = [
-                    q for q in qpis if not _is_cross_pod(q.pod)
-                ]
+                plain = [q for q in qpis if not _is_cross_pod(q.pod)]
             # priority-inversion bypass (see _schedule_wave_inner): when
             # a deferred constrained pod outranks a plain pod about to
             # run, the backlog must flush FIRST — backlog flushing is
             # loop-thread work, so hand the batch back raw.  The backlog
             # read is a cross-thread peek; the GIL makes it safe and the
-            # loop re-checks authoritatively on the serial path.
-            pool = list(sched._scan_backlog) + prepared.constrained
-            if pool and prepared.qpis:
+            # loop re-checks authoritatively in schedule_one.
+            pool = list(sched._scan_backlog) + constrained
+            if pool and plain:
                 hi = max(q.pod.spec.priority for q in pool)
-                if hi > min(q.pod.spec.priority for q in prepared.qpis):
+                if hi > min(q.pod.spec.priority for q in plain):
                     raise _BuildFallback()
-        if not prepared.qpis:
-            raise _BuildFallback()  # all-constrained batch: serial path
-        pods_ = [q.pod for q in prepared.qpis]
+        if not plain:
+            raise _BuildFallback()  # all-constrained batch: backlog work
         # leases expire on the loop thread (store probes must not stall
         # the overlap window); the dirty-set drain is atomic with the
-        # snapshot and this worker is the only wave-path snapshotter
+        # snapshot and this worker is its only consumer
         with sched.metrics.timed("wave_snapshot"):
-            node_infos, agg_delta, assumed_pods, dirty, epoch = (
-                sched._snapshot_for_tables(expire_leases=False)
-            )
-        if not node_infos:
-            raise _BuildFallback()  # empty roster: serial error path
-        prepared.node_infos = node_infos
-        nodes = [ni.node for ni in node_infos]
-        with sched.metrics.timed("wave_assigned_list"):
-            assigned = (
-                ()
-                if sched.constraint_index is not None
-                else [p for ni in node_infos for p in ni.pods]
-                + assumed_pods
-            )
-        pod_capacity = sched._wave_cap(len(pods_))
-        # placed-gang aggregates for this wave's members (assume-cache
-        # folded in): computed on the worker against the same snapshot
-        # the tables encode; the loop thread's re-arbitration handles
-        # anything the overlapped wave commits after this
-        gang_view = sched._gang_view(pods_)
-        with sched.metrics.timed("wave_build_tables"):
-            node_static, node_agg, node_names = (
-                sched._table_builder.build_packed(
-                    node_infos, agg_delta=agg_delta, dirty=dirty,
-                    epoch=epoch,
-                )
-            )
-            prepared.dirty_rows = sched._table_builder.last_dirty_rows
-            prepared.build_skipped = (
-                sched._table_builder.last_build_skipped
-            )
-            pod_table, _ = build_pod_table(
-                pods_, capacity=pod_capacity, device=False,
-                gang_view=gang_view,
-            )
-        prepared.node_static = node_static
-        prepared.node_agg = node_agg
-        prepared.node_names = node_names
-        prepared.pod_table = pod_table
-        if sched._needs_extra:
-            with sched.metrics.timed("wave_build_constraints"):
-                prepared.extra = sched._build_constraints(
-                    pods_, nodes, assigned,
-                    pod_capacity=pod_capacity,
-                    node_capacity=node_agg.capacity,
-                    scan_planes=False,  # wave mode never runs the scan
-                    device=False,
-                )
+            snapshot = sched._snapshot_for_tables(expire_leases=False)
+        if not snapshot[0]:
+            raise _BuildFallback()  # empty roster: the loop's error path
+        prepared = build_wave(sched, plain, snapshot)
+        prepared.constrained = constrained
+        prepared.built_ahead = True
+        prepared.dirty_rows = sched._table_builder.last_dirty_rows
+        prepared.build_skipped = sched._table_builder.last_build_skipped
         return prepared

@@ -7,8 +7,9 @@ snapshot swapped at the publish point — so a reader NEVER sees a
 half-applied group (no torn lists), a publisher sees its own group
 before its ack returns (read-your-writes), and the kill switch
 (``MINISCHED_COW_READS=0``) restores the locked read path with
-byte-identical results.  bench.py's ``relist`` role owns the
-storm-scale numbers; this file owns the correctness pins.
+byte-identical results.  This file owns the correctness pins; no
+cell of the benchmark drives a relist storm yet, so there are no
+storm-scale numbers.
 """
 
 from __future__ import annotations

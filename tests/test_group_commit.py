@@ -9,8 +9,8 @@ apply and watch fanout in strict rv order — before any waiter is acked.
 
 This file owns the pipeline's direct contracts; the chaos suites
 (test_disk_chaos / test_proc_chaos) own its failure atomicity under
-injected ENOSPC and SIGKILL, and bench.py's `wal` role owns the
-throughput claim.
+injected ENOSPC and SIGKILL.  The throughput claim has no owner until
+a durable cell exists (ROADMAP R9).
 """
 
 from __future__ import annotations

@@ -396,16 +396,12 @@ def test_record_batch_result_from_diagnostics():
     assert final["n1"]["NodeNumber"] == 10
 
 
-def test_device_mode_records_wave_results_onto_annotations():
-    """record_results=True + device_mode=True: the wave engine ingests a
-    diagnostics evaluation per wave (record_batch_result) and the flush
-    hook lands the same scheduler-simulator/* annotations the scalar
-    recorders produce (SURVEY §2 row 10 — the batch path emits the same
-    artifact)."""
-    import json
+def _recorded_pods(pods, nodes, n_bound):
+    """Drive ``record_results=True, device_mode=True`` over ``nodes`` and
+    ``pods``; returns (the engine, the bound pods) once ``n_bound`` pods
+    are bound AND carry both result annotations."""
     import time
 
-    from minisched_tpu.api.objects import make_node, make_pod
     from minisched_tpu.controlplane.client import Client
     from minisched_tpu.observability.annotation import (
         FILTER_RESULT,
@@ -415,43 +411,110 @@ def test_device_mode_records_wave_results_onto_annotations():
     from minisched_tpu.service.service import SchedulerService
 
     client = Client()
-    for i in range(4):
-        client.nodes().create(
-            make_node(f"node{i}", capacity={"cpu": "2", "memory": "4Gi",
-                                            "pods": 110})
-        )
-    for i in range(3):
-        client.pods().create(make_pod(f"pod{i}", requests={"cpu": "250m"}))
+    for node in nodes:
+        client.nodes().create(node)
+    for pod in pods:
+        client.pods().create(pod)
     svc = SchedulerService(client)
-    svc.start_scheduler(
+    sched = svc.start_scheduler(
         default_full_roster_config(), record_results=True, device_mode=True,
         max_wave=8,
     )
     try:
-        deadline = time.time() + 60
-        annotated = None
+        deadline = time.time() + 120
         while time.time() < deadline:
-            pods = client.pods().list()
-            bound = [p for p in pods if p.spec.node_name]
+            bound = [p for p in client.pods().list() if p.spec.node_name]
             withann = [
                 p for p in bound
                 if FILTER_RESULT in p.metadata.annotations
+                and SCORE_RESULT in p.metadata.annotations
             ]
-            if len(bound) == 3 and len(withann) == 3:
-                annotated = withann
-                break
+            if len(bound) == n_bound and len(withann) == n_bound:
+                return sched, withann
             time.sleep(0.1)
-        assert annotated, "pods never got wave result annotations"
-        rec = json.loads(
-            annotated[0].metadata.annotations[FILTER_RESULT]
+        raise AssertionError(
+            f"{len(bound)} bound, {len(withann)} annotated of {n_bound}"
         )
-        # per-node filter verdicts for the in-tree roster, unwrapped names
-        assert "node0" in rec
-        assert rec["node0"]["NodeUnschedulable"] == "passed"
-        assert "NodeResourcesFit" in rec["node0"]
-        score = json.loads(
-            annotated[0].metadata.annotations[SCORE_RESULT]
-        )
-        assert "TaintToleration" in score["node0"]
     finally:
         svc.shutdown_scheduler()
+
+
+def test_device_mode_records_wave_results_onto_annotations():
+    """record_results=True + device_mode=True: the engine is the one
+    pipelined, packed engine (a build worker exists), it ingests a
+    diagnostics evaluation per wave (record_batch_result) and the flush
+    hook lands the same scheduler-simulator/* annotations the scalar
+    recorders produce on EVERY bound pod (SURVEY §2 row 10 — the batch
+    path emits the same artifact)."""
+    import json
+
+    from minisched_tpu.api.objects import make_node, make_pod
+    from minisched_tpu.engine.pipeline import WavePipeline
+    from minisched_tpu.observability.annotation import (
+        FILTER_RESULT,
+        SCORE_RESULT,
+    )
+
+    sched, annotated = _recorded_pods(
+        [make_pod(f"pod{i}", requests={"cpu": "250m"}) for i in range(3)],
+        [
+            make_node(f"node{i}", capacity={"cpu": "2", "memory": "4Gi",
+                                            "pods": 110})
+            for i in range(4)
+        ],
+        n_bound=3,
+    )
+    assert isinstance(sched._pipeline, WavePipeline)
+    rec = json.loads(annotated[0].metadata.annotations[FILTER_RESULT])
+    # per-node filter verdicts for the in-tree roster, unwrapped names
+    assert "node0" in rec
+    assert rec["node0"]["NodeUnschedulable"] == "passed"
+    assert "NodeResourcesFit" in rec["node0"]
+    score = json.loads(annotated[0].metadata.annotations[SCORE_RESULT])
+    assert "TaintToleration" in score["node0"]
+
+
+def test_device_mode_records_exact_scan_lane_results():
+    """The twin through the cross-pod lane: a zone-spread pod is deferred
+    to the scan backlog, rides the exact scan (a burst of one), and gets
+    the same per-plugin record — PodTopologySpread's verdicts among them —
+    beside the plain pod of its batch."""
+    import json
+
+    from minisched_tpu.api.objects import (
+        LabelSelector,
+        TopologySpreadConstraint,
+        make_node,
+        make_pod,
+    )
+    from minisched_tpu.observability import counters
+    from minisched_tpu.observability.annotation import FILTER_RESULT
+
+    spread = make_pod("spread0", requests={"cpu": "250m"},
+                      labels={"app": "web"})
+    spread.spec.topology_spread_constraints = [
+        TopologySpreadConstraint(
+            max_skew=1,
+            topology_key="zone",
+            when_unsatisfiable="DoNotSchedule",
+            label_selector=LabelSelector(match_labels={"app": "web"}),
+        )
+    ]
+    scans_before = counters.get("scan.rows_live")
+    sched, annotated = _recorded_pods(
+        [spread, make_pod("plain0", requests={"cpu": "250m"})],
+        [
+            make_node(f"node{i}", labels={"zone": f"z{i % 2}"},
+                      capacity={"cpu": "2", "memory": "4Gi", "pods": 110})
+            for i in range(4)
+        ],
+        n_bound=2,
+    )
+    assert sched._scan_scheduler is not None, "the exact lane never ran"
+    assert counters.get("scan.rows_live") == scans_before  # not the blocked
+    rec = json.loads(
+        next(p for p in annotated if p.metadata.name == "spread0")
+        .metadata.annotations[FILTER_RESULT]
+    )
+    assert set(rec) == {f"node{i}" for i in range(4)}
+    assert rec["node0"]["PodTopologySpread"] == "passed"

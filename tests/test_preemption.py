@@ -96,6 +96,43 @@ def test_preemption_evicts_lowest_priority_first():
     assert "low" not in names and "mid" in names and "blocker" in names
 
 
+def test_preemption_never_strands_a_partial_gang():
+    """A bound gang member is never a victim: evicting one would leave its
+    siblings a partial gang.  The gang's node is passed over, the
+    singleton's is chosen, and every member is still there afterwards;
+    with nothing but gang members below the preemptor, nothing is
+    evicted at all (the old bench's churn role audited this at scale)."""
+    from minisched_tpu.api.objects import GangSpec
+    from minisched_tpu.observability import counters
+
+    def member(name, node):
+        p = _assigned(name, node, "1")
+        p.spec.gang = GangSpec("g", 3)
+        return p
+
+    client = Client()
+    gang = [member("g-a", "n1"), member("g-b", "n1"), member("g-c", "n2")]
+    infos = _cluster(client, gang + [_assigned("single", "n2", "1")])
+    dp = DefaultPreemption()
+    dp.h = _Handle(client, [NodeResourcesFit()])
+    before = counters.get("gang.preempt_shielded")
+    pod = make_pod("wants-1cpu", requests={"cpu": "1"}, priority=10)
+    nominated, status = dp.post_filter(CycleState(), pod, infos, Diagnosis())
+    assert status.is_success() and nominated == "n2"
+    names = {p.metadata.name for p in client.pods().list()}
+    assert names == {"g-a", "g-b", "g-c"}
+    assert counters.get("gang.preempt_shielded") > before
+
+    # only gang members left below the preemptor: no candidate, no victim
+    infos = build_node_infos(client.nodes().list(), gang)
+    nominated, status = dp.post_filter(
+        CycleState(), make_pod("wants-2cpu", requests={"cpu": "2"}, priority=10),
+        infos, Diagnosis(),
+    )
+    assert not nominated and not status.is_success()
+    assert {p.metadata.name for p in client.pods().list()} == names
+
+
 def test_preemption_skips_unresolvable_nodes():
     client = Client()
     assigned = [_assigned("small", "n1", "2", priority=0)]

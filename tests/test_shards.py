@@ -757,3 +757,51 @@ def test_router_discovers_follower_endpoints(monkeypatch):
     assert ShardedStore._discover_endpoints(["http://dead"]) == [
         "http://dead"
     ]
+
+
+def test_autosplit_watcher_splits_hottest_owned_namespace_once():
+    """The load watcher, driven tick by tick (the old bench's shard role
+    only ever saw it fire behind a timer): a hot windowed p99 of
+    ``storage.group_wait_s`` for ``hot_samples`` ticks splits the hottest
+    namespace THIS group owns to another group — never "" (cluster-scoped
+    objects stay home), never a namespace another group owns — then the
+    cooldown holds the next trigger back, and a cool window resets the
+    streak."""
+    from minisched_tpu.controlplane.shards import AutoSplitWatcher
+    from minisched_tpu.observability import counters, hist
+
+    topo = ShardTopology({"g0": ["http://a"], "g1": ["http://b"]})
+    mine = [ns for ns in (f"ns{i}" for i in range(40)) if topo.owner(ns) == "g0"]
+    theirs = next(ns for ns in (f"ns{i}" for i in range(40)) if topo.owner(ns) == "g1")
+    shard = ShardInfo("g0", topo)
+    splits = []
+
+    def split(topology, ns, target):
+        splits.append((ns, target))
+        return {"namespace": ns, "target": target}
+
+    class _Store:
+        _gc_stage = ()
+
+    w = AutoSplitWatcher(
+        _Store(), shard, p99_hot_s=0.05, depth_hot=10**9, hot_samples=2,
+        cooldown_s=3600.0, split=split,
+    )
+
+    def tick(wait_s):
+        for _ in range(20):
+            hist.observe("storage.group_wait_s", wait_s)
+        return w.sample()
+
+    before = counters.get("shard.autosplit.triggered")
+    assert w.sample()["split"] is None  # first tick only seeds the window
+    shard.note_writes([""] * 50 + [theirs] * 30 + [mine[0]] * 5 + [mine[1]] * 9)
+    assert tick(0.5)["hot"] and not splits  # streak 1 of 2
+    assert not tick(0.0001)["hot"]  # a cool window resets it
+    assert tick(0.5)["streak"] == 1 and not splits
+    out = tick(0.5)
+    assert splits == [(mine[1], "g1")] and out["split"]["namespace"] == mine[1]
+    assert counters.get("shard.autosplit.triggered") == before + 1
+    # hot again at once: the cooldown holds it
+    tick(0.5)
+    assert tick(0.5)["split"] is None and len(splits) == 1

@@ -212,15 +212,10 @@ def _span_names(src: str):
 
 
 def _py_sources():
-    roots = [os.path.join(REPO, "minisched_tpu"), os.path.join(REPO, "bench.py")]
-    for root in roots:
-        if os.path.isfile(root):
-            yield root
-            continue
-        for dirpath, _dirs, files in os.walk(root):
-            for fn in files:
-                if fn.endswith(".py"):
-                    yield os.path.join(dirpath, fn)
+    for dirpath, _dirs, files in os.walk(os.path.join(REPO, "minisched_tpu")):
+        for fn in files:
+            if fn.endswith(".py"):
+                yield os.path.join(dirpath, fn)
 
 
 def test_every_metric_name_is_documented():
