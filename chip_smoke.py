@@ -14,10 +14,13 @@ gives), and drives it ONLY over loopback HTTP with
   5000Nodes_10000Pods``) generated from ``--seed``: 9,500 plain
   ``500m/256Mi`` pods, a minority with a node selector or a toleration so
   both packed pod schemas compile; then 480 ``DoNotSchedule`` topology-
-  spread pods in ONE burst (> SCAN_BLOCK_SIZE: the blocked scan lane and
-  its P=32 Pallas step); then 20 more (≤ 32: the exact P=1 lane and the
-  XLA tail).  All three device programs the engine can dispatch compile
-  and run inside the real loop.
+  spread pods in ONE burst (> SCAN_BLOCK_SIZE: the blocked scan lane), the
+  first half spread over 32 services, whose blocks fill (the wide layout
+  and its P=32 Pallas step), the second half all of one service, whose
+  blocks hold one pod each (the narrow layout, a pod a row: P=1 and the
+  XLA tail); then 20 more (≤ 32: the exact P=1 lane and the XLA tail).
+  All four device programs the engine can dispatch compile and run inside
+  the real loop.
 * The safety audit reads back through REST; parks, dispatch heals, the
   device and the wave outputs' placement are read off ``/metrics`` and
   ``/debug/trace``.
@@ -329,7 +332,9 @@ def _make_cluster(sizes: Sizes, seed: int):
         plain.append(make_pod(f"pod{i:05d}", requests=requests, **spec))
 
     def spread(i: int):
-        app = f"app{i % N_APPS}"
+        # the burst's first half goes round the services, so its blocks
+        # fill; from there on every pod is the last service's, a block each
+        app = f"app{i % N_APPS if i < sizes.burst // 2 else N_APPS - 1}"
         pod = make_pod(f"spread{i:04d}", requests=requests, labels={"app": app})
         pod.spec.topology_spread_constraints = [
             TopologySpreadConstraint(
@@ -467,7 +472,7 @@ def drive_and_audit(
         )
 
     send_and_wait(plain, "plain waves")
-    send_and_wait(burst, "spread burst (blocked scan lane)")
+    send_and_wait(burst, "spread burst (blocked scan lane, wide and narrow)")
     send_and_wait(tail, "spread tail (exact scan lane)")
 
     _audit(client.nodes().list(), client.pods().list(), sizes)
@@ -518,7 +523,7 @@ def drive_and_audit(
     wave_schemas = 2 if simple_head(sizes) >= sched.max_wave else 1
     require(
         counts["wave"] >= wave_schemas and counts["blocked_scan"] >= 1
-        and counts["exact_scan"] >= 1,
+        and counts["narrow_scan"] >= 1 and counts["exact_scan"] >= 1,
         f"programs dispatched per lane: {counts}",
     )
     say(f"programs dispatched per lane: {counts}")
@@ -542,13 +547,14 @@ def check_programs(service, programs: dict, n_devices: int) -> None:
                     "tpu_custom_call" in text,
                     f"a {lane} program holds no Mosaic custom call",
                 )
-        require(
-            not any("tpu_custom_call" in t for t in programs["exact_scan"]),
-            "the P=1 exact scan program should take the XLA tail",
-        )
+        for lane in ("narrow_scan", "exact_scan"):
+            require(
+                not any("tpu_custom_call" in t for t in programs[lane]),
+                f"a P=1 {lane} program should take the XLA tail",
+            )
         say(
-            "Mosaic custom call present in every wave and blocked-scan "
-            "program; the exact scan takes the XLA tail"
+            "Mosaic custom call present in every wave and (wide) blocked-"
+            "scan program; the narrow and the exact scan take the XLA tail"
         )
         return
     snap = counters.snapshot()

@@ -22,7 +22,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from typing import Any, List, Optional
+from typing import Any, List, Optional, Tuple
 
 from minisched_tpu.api.objects import Pod
 from minisched_tpu.engine.pipeline import WavePipeline, build_wave
@@ -173,6 +173,7 @@ class DeviceScheduler(Scheduler):
         self._evaluator: Optional[RepairingEvaluator] = None
         self._scan_scheduler: Any = None  # lazy SequentialScheduler
         self._blocked_scheduler: Any = None  # lazy BlockedSequentialScheduler
+        self._narrow_scheduler: Any = None  # the same at SCAN_NARROW_WIDTH
         #: two-stage wave pipeline (engine/pipeline.py): the host build
         #: for wave N+1 runs on a worker thread while the device evaluates
         #: wave N.  Started by the first ``schedule_one``; from then on
@@ -205,10 +206,11 @@ class DeviceScheduler(Scheduler):
         # cross-pod pods deferred across waves (see schedule_wave): every
         # scan-lane call re-ships the packed node/constraint tables and
         # dispatches a whole program however few pods it carries, so
-        # constrained pods accumulate here and the lane runs once per
+        # constrained pods accumulate here and the lane is entered once per
         # ~BLOCKED_MAX_CHUNK of them — or at queue drain, whichever comes
-        # first.  Pop order is preserved, so per-group FIFO (the lane's
-        # exactness contract) is unchanged.
+        # first — and cuts them into the calls their grouping asks for
+        # (_plan_blocked_calls).  Pop order is preserved, so per-group FIFO
+        # (the lane's exactness contract) is unchanged.
         self._scan_backlog: List[QueuedPodInfo] = []
         self._scan_backlog_waves = 0  # full waves survived since first defer
         # assume-pod cache (upstream's scheduler cache AssumePod): a placed
@@ -657,18 +659,22 @@ class DeviceScheduler(Scheduler):
                 extra,
             )
 
-    #: scan chunks pad to power-of-two capacities ≥ this (few executables,
-    #: each persistent-cached) and never exceed this many pods per chunk
-    #: times 8 — chunking bounds executable size; chunk k+1 re-snapshots so
-    #: it sees chunk k's binds (sequential semantics across chunks)
+    #: the exact lane's two chunk capacities (_scan_cap): few executables,
+    #: each persistent-cached.  A chunk carries at most SCAN_MAX_CHUNK
+    #: pods — chunking bounds executable size and the lump that binds
+    #: land in; chunk k+1 re-snapshots so it sees chunk k's binds
+    #: (sequential semantics across chunks).  The blocked lane's narrow
+    #: layout carries the same number of live pods a call
     SCAN_MIN_CAP = 128
     SCAN_MAX_CHUNK = 1024
-    #: blocked-lane chunk stride/top tier: every call pays one dispatch
-    #: plus the packed node/constraint transfer whatever its size, so the
-    #: blocked lane takes FEWER, BIGGER calls than the exact lane — with
-    #: cross-wave deferral (schedule_wave) a 5k-pod cross-pod burst is
-    #: ONE call at this tier; fully-padded trailing blocks skip their
-    #: step via lax.cond, so the tier's padding costs ~nothing on device
+    #: the size of the cross-pod backlog that flushes it (schedule_one),
+    #: and the wide layout's chunk stride and top tier, in ROWS: every
+    #: call pays one dispatch, one packed node/constraint build and one
+    #: static pass over all its rows whatever they hold, so full blocks
+    #: ride FEWER, BIGGER calls than the exact lane (8,192 live pods a
+    #: call when every block is full; fully-padded trailing blocks skip
+    #: their step via lax.cond).  Blocks of ONE live pod do not come
+    #: here: see SCAN_NARROW_WIDTH
     BLOCKED_MAX_CHUNK = 8192
     #: small-wave pod capacity: partial and requeue waves (a 2k-pod
     #: backoff replay after a 16k-pod drain) evaluate at this capacity
@@ -689,7 +695,17 @@ class DeviceScheduler(Scheduler):
     #: kernel step (ops/sequential.blocked_scan_schedule) — within-group
     #: sequential exactness, repair-acceptance safety across groups.
     #: ≤1 disables it (every cross-pod pod rides the exact per-pod scan).
+    #: 32 rows a step is the WIDE layout, for the blocks the grouping
+    #: could fill with more than one pod.
     SCAN_BLOCK_SIZE = 32
+    #: the NARROW layout: the grouping's trailing blocks of exactly one
+    #: live pod (all of them, where every pod shares one selector) are
+    #: laid out this many rows a pod and run through the same kernel at
+    #: this block size, SCAN_MAX_CHUNK live pods a call at ONE capacity
+    #: (_plan_blocked_calls) — at 32 rows a pod a call built 8,192 rows
+    #: and ran 256 steps of 32 for 256 live pods.  PERF.md §6 PR 32 has
+    #: the chip reading that chose the width.
+    SCAN_NARROW_WIDTH = 1
     #: blocked rounds before leftover capacity-race losers fall back to
     #: the exact per-pod scan
     SCAN_BLOCK_RETRIES = 3
@@ -715,17 +731,51 @@ class DeviceScheduler(Scheduler):
         return cls.SCAN_MIN_CAP if n_pods <= cls.SCAN_MIN_CAP else cls.SCAN_MAX_CHUNK
 
     @classmethod
-    def _blocked_cap(cls, n_pods: int) -> int:
-        """Blocked-lane capacity tiers: {128, 1024, 8192}.  Same shape
-        discipline as _scan_cap, one more tier — the blocked kernel's
-        padded blocks skip their whole step via lax.cond, so the big
-        tier costs (almost) only its live blocks while amortizing the
-        per-call dispatch and table transfer."""
-        if n_pods <= cls.SCAN_MIN_CAP:
+    def _blocked_cap(cls, n_rows: int) -> int:
+        """The wide layout's capacity tiers, in rows: {128, 1024, 8192}.
+        Same shape discipline as _scan_cap, one more tier — the kernel's
+        fully-padded blocks skip their step via lax.cond, so the big tier
+        runs only its live blocks' steps (its build and its static pass
+        still cover every row) while it amortizes the per-call dispatch
+        and table transfer."""
+        if n_rows <= cls.SCAN_MIN_CAP:
             return cls.SCAN_MIN_CAP
-        if n_pods <= cls.SCAN_MAX_CHUNK:
+        if n_rows <= cls.SCAN_MAX_CHUNK:
             return cls.SCAN_MAX_CHUNK
         return cls.BLOCKED_MAX_CHUNK
+
+    @classmethod
+    def _plan_blocked_calls(
+        cls, blocks: List[List[Optional[Any]]]
+    ) -> List[Tuple[bool, List[Optional[Any]], int]]:
+        """(narrow, rows, capacity) of every kernel call one grouping
+        takes, in the order they must run.  The width of a call's rows
+        follows the fill the grouping found: the longest SUFFIX of blocks
+        that hold exactly one live pod is laid out SCAN_NARROW_WIDTH rows
+        a pod, SCAN_MAX_CHUNK live pods a call, at one capacity whatever
+        its length (a short last call pads up; its padded steps are
+        skipped); the head keeps SCAN_BLOCK_SIZE rows a block at
+        _blocked_cap's tiers and runs first.  A group's members sit in
+        strictly increasing blocks (scan_groups), so head-then-suffix in
+        block order is still FIFO within every group."""
+        head = len(blocks)
+        while head and blocks[head - 1][1] is None:
+            head -= 1
+        wide = [m for blk in blocks[:head] for m in blk]
+        calls = []
+        for i in range(0, len(wide), cls.BLOCKED_MAX_CHUNK):
+            part = wide[i : i + cls.BLOCKED_MAX_CHUNK]
+            calls.append((False, part, cls._blocked_cap(len(part))))
+        W = cls.SCAN_NARROW_WIDTH
+        pad: List[Optional[Any]] = [None] * (W - 1)
+        for i in range(head, len(blocks), cls.SCAN_MAX_CHUNK):
+            rows = [
+                m
+                for blk in blocks[i : i + cls.SCAN_MAX_CHUNK]
+                for m in (blk[0], *pad)
+            ]
+            calls.append((True, rows, cls.SCAN_MAX_CHUNK * W))
+        return calls
 
     def prewarm(self, scan: bool = True) -> None:
         """Compile (or cache-load) the wave evaluator executable for the
@@ -814,8 +864,10 @@ class DeviceScheduler(Scheduler):
             # cross-pod-constrained pods ride the sequential scan — warm
             # BOTH chunk capacities (_schedule_scan uses exactly these
             # two; a partial chunk would otherwise compile the small one
-            # mid-run).  The blocked lane has one extra (bigger) tier than
-            # the exact lane — warm each executable only at the caps it runs
+            # mid-run).  The blocked lane's wide layout has one extra
+            # (bigger) tier than the exact lane, its narrow layout one
+            # capacity of its own — warm each executable only at the caps
+            # it runs
             scan_caps = {self.SCAN_MIN_CAP, self.SCAN_MAX_CHUNK}
             blocked_caps = (
                 scan_caps | {self.BLOCKED_MAX_CHUNK}
@@ -890,6 +942,25 @@ class DeviceScheduler(Scheduler):
             )
             sink_pod.spec.volumes = ["warmclaim"]
             blocked_sets = ([_spread("warmspread")], [sink_pod])
+
+            def warm_blocked(scheduler, cap):
+                for warm_set in blocked_sets:
+                    bp, _ = build_pod_table(
+                        warm_set, capacity=cap, device=False
+                    )
+                    bx = build_constraint_tables(
+                        warm_set, nodes, [],
+                        pod_capacity=cap,
+                        node_capacity=node_capacity,
+                        scan_planes=True, device=False,
+                        elide_zeros=False,
+                        elide_groups=SCAN_ELIDE_GROUPS,
+                    )
+                    _, bc, _, _ = scheduler.call_packed(
+                        bp, node_static, node_agg, bx
+                    )
+                    jax.block_until_ready(bc)
+
             for cap in all_caps:
                 if cap in scan_caps:
                     scan_pods, _ = build_pod_table(
@@ -907,24 +978,12 @@ class DeviceScheduler(Scheduler):
                     )
                     jax.block_until_ready(choice)
                 if cap in blocked_caps:
-                    for warm_set in blocked_sets:
-                        bp, _ = build_pod_table(
-                            warm_set, capacity=cap, device=False
-                        )
-                        bx = build_constraint_tables(
-                            warm_set, nodes, [],
-                            pod_capacity=cap,
-                            node_capacity=node_capacity,
-                            scan_planes=True, device=False,
-                            elide_zeros=False,
-                            elide_groups=SCAN_ELIDE_GROUPS,
-                        )
-                        _, bc, _, _ = (
-                            self._get_blocked_scheduler().call_packed(
-                                bp, node_static, node_agg, bx
-                            )
-                        )
-                        jax.block_until_ready(bc)
+                    warm_blocked(self._get_blocked_scheduler(), cap)
+            if blocked_caps:
+                warm_blocked(
+                    self._get_narrow_scheduler(),
+                    self.SCAN_MAX_CHUNK * self.SCAN_NARROW_WIDTH,
+                )
 
     def _get_scan_scheduler(self):
         if self._scan_scheduler is None:
@@ -939,28 +998,43 @@ class DeviceScheduler(Scheduler):
             )
         return self._scan_scheduler
 
+    def _new_blocked_scheduler(self, block_size: int):
+        from minisched_tpu.ops.sequential import BlockedSequentialScheduler
+
+        return BlockedSequentialScheduler(
+            self.filter_plugins,
+            self.pre_score_plugins,
+            self.score_plugins,
+            weights=self.score_weights,
+            block_size=block_size,
+            mesh=self.mesh,
+        )
+
     def _get_blocked_scheduler(self):
         if self._blocked_scheduler is None:
-            from minisched_tpu.ops.sequential import BlockedSequentialScheduler
-
-            self._blocked_scheduler = BlockedSequentialScheduler(
-                self.filter_plugins,
-                self.pre_score_plugins,
-                self.score_plugins,
-                weights=self.score_weights,
-                block_size=self.SCAN_BLOCK_SIZE,
-                mesh=self.mesh,
+            self._blocked_scheduler = self._new_blocked_scheduler(
+                self.SCAN_BLOCK_SIZE
             )
         return self._blocked_scheduler
+
+    def _get_narrow_scheduler(self):
+        """The blocked kernel at SCAN_NARROW_WIDTH rows a step: same
+        plugins, same static hoist, same program name (``scan_blocked``)."""
+        if self._narrow_scheduler is None:
+            self._narrow_scheduler = self._new_blocked_scheduler(
+                self.SCAN_NARROW_WIDTH
+            )
+        return self._narrow_scheduler
 
     def dispatched_programs(self) -> dict:
         """lane → StableHLO text of every packed program that lane has
         dispatched so far (PackedCaller.lowered_texts; [] for a lane that
-        never ran).  chip_smoke.py checks all three lanes ran and what
+        never ran).  chip_smoke.py checks all four lanes ran and what
         their programs are made of."""
         lanes = {
             "wave": self._evaluator,
             "blocked_scan": self._blocked_scheduler,
+            "narrow_scan": self._narrow_scheduler,
             "exact_scan": self._scan_scheduler,
         }
         return {
@@ -1055,11 +1129,12 @@ class DeviceScheduler(Scheduler):
         agg_delta: Any,
         assumed_pods: Any,
     ) -> None:
-        """Blocked lane: group → order → chunked blocked-kernel calls;
-        feasible pods that lose a same-node capacity race retry in later
-        rounds (re-grouped against fresh state); leftovers after
-        SCAN_BLOCK_RETRIES ride the exact per-pod scan — a sequential
-        order never fails them, so neither may this lane."""
+        """Blocked lane: group → order → the kernel calls the fill asks
+        for (_plan_blocked_calls: full blocks wide, the trailing one-pod
+        blocks narrow); feasible pods that lose a same-node capacity race
+        retry in later rounds (re-grouped against fresh state); leftovers
+        after SCAN_BLOCK_RETRIES ride the exact per-pod scan — a
+        sequential order never fails them, so neither may this lane."""
         from minisched_tpu.engine.scan_groups import (
             interaction_sets,
             order_into_blocks,
@@ -1081,15 +1156,28 @@ class DeviceScheduler(Scheduler):
                 with self.metrics.timed("scan_grouping"):
                     sets = interaction_sets([q.pod for q in pending])
                     blocks = order_into_blocks(pending, sets, B)
-                    flat = [m for blk in blocks for m in blk]
+                    calls = self._plan_blocked_calls(blocks)
                 counters.inc("scan.rows_live", len(pending))
-                counters.inc("scan.rows_total", len(flat))
+                counters.inc(
+                    "scan.rows_total", sum(len(part) for _, part, _ in calls)
+                )
+                counters.inc(
+                    "scan.rows_narrow",
+                    sum(len(part) for narrow, part, _ in calls if narrow)
+                    // self.SCAN_NARROW_WIDTH,
+                )
                 retry: List[QueuedPodInfo] = []
-                for start in range(0, len(flat), self.BLOCKED_MAX_CHUNK):
+                for narrow, part, cap in calls:
                     if fresh is None:
                         fresh = self._snapshot_for_wave()
-                    part = flat[start : start + self.BLOCKED_MAX_CHUNK]
-                    retry += self._run_blocked_chunk(part, *fresh)
+                    scheduler = (
+                        self._get_narrow_scheduler()
+                        if narrow
+                        else self._get_blocked_scheduler()
+                    )
+                    retry += self._run_blocked_chunk(
+                        part, cap, scheduler, *fresh
+                    )
                     fresh = None
                 if not retry:
                     return
@@ -1106,13 +1194,16 @@ class DeviceScheduler(Scheduler):
     def _run_blocked_chunk(
         self,
         part: List[Optional[QueuedPodInfo]],
+        cap: int,
+        scheduler: Any,
         node_infos: List[Any],
         agg_delta: Any,
         assumed_pods: Any,
     ) -> List[QueuedPodInfo]:
-        """One blocked-kernel call over ``part`` (None = block padding).
-        Commits winners, parks infeasible pods, returns the capacity-race
-        retries."""
+        """One blocked-kernel call over ``part`` (None = block padding)
+        at pod capacity ``cap`` through ``scheduler``, whose block size
+        the rows were laid out for.  Commits winners, parks infeasible
+        pods, returns the capacity-race retries."""
         import jax
 
         from minisched_tpu.api.objects import make_pod
@@ -1125,7 +1216,6 @@ class DeviceScheduler(Scheduler):
             + list(assumed_pods)
         )
         dummy = make_pod("scan-pad")
-        cap = self._blocked_cap(len(part))
 
         def build_and_scan(part_live):
             # the padded layout, restricted to the currently-live qpis —
@@ -1176,10 +1266,8 @@ class DeviceScheduler(Scheduler):
                 "scan_evaluate", call=self._scan_call, n=len(part_live)
             ):
                 with self.metrics.timed("scan_dispatch"):
-                    _, choice, _, accepted = (
-                        self._get_blocked_scheduler().call_packed(
-                            pod_table, node_static, node_agg, extra
-                        )
+                    _, choice, _, accepted = scheduler.call_packed(
+                        pod_table, node_static, node_agg, extra
                     )
                 with self.metrics.timed("scan_fetch"):
                     choice, accepted = jax.device_get(
@@ -1863,9 +1951,9 @@ class DeviceScheduler(Scheduler):
         # DEFERRED rather than run per wave: each lane call pays one
         # packed transfer + dispatch however few pods it carries, so
         # constrained pods accumulate in pop order across waves and the
-        # lane runs once per ~BLOCKED_MAX_CHUNK — or when the queue drains
-        # (schedule_one).  The global order is thus [plain…×k, constrained…]
-        # — per-group FIFO (the exactness contract) is untouched, and the
+        # lane is entered once per ~BLOCKED_MAX_CHUNK — or when the queue
+        # drains (schedule_one).  The global order is thus
+        # [plain…×k, constrained…] — per-group FIFO (the exactness contract) is untouched, and the
         # lane's acceptance/audit guarantees don't depend on WHEN it runs.
         # A chain WITHOUT cross-pod plugins never evaluates the constraints
         # at all (reference semantics with the plugin disabled) — no scan.

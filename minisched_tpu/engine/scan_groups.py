@@ -16,6 +16,14 @@ keeps rejecting later ones (blocks only grow), so a group's members land
 in strictly increasing blocks — the within-group sequential semantics the
 blocked kernel's exactness claim rests on.
 
+The blocks' FILL is the grouping's other finding, and the engine lays its
+rows out by it (DeviceScheduler._plan_blocked_calls): many small groups
+fill their blocks and run 32 rows a kernel step; one large group (every
+pod under one selector) gets a block a pod, and such one-pod blocks at the
+end of the order run a pod a row through the same kernel at a narrow
+block size, after the blocks before them — block order is kept, so a
+group's members still run in FIFO order.
+
 The same fact makes first fit near-linear: a block REFUSES an identity
 when it is full or its union holds the identity, and a refusal is for
 good — a union only gains identities and a full block stays full.  So

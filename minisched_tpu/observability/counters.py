@@ -146,12 +146,17 @@ The blocked scan lane (engine/scan_groups + ops/sequential) says how
 full its blocks are, once a grouping (a flush of the scan backlog, and
 each retry round of it):
 
-    scan.rows_live / scan.rows_total
-        — pods handed to the blocked kernel, and the rows they were laid
-          out in (blocks × SCAN_BLOCK_SIZE, ``None`` padding included).
-          live ÷ total is the block fill: 1 ÷ 32 where every pod shares
-          one interaction group, near 1 where groups are many and
-          small.  No benchmark metric reads them (PERF.md §7 row 11)
+    scan.rows_live / scan.rows_total / scan.rows_narrow
+        — pods handed to the blocked kernel, the rows they were laid
+          out in (``None`` padding included: SCAN_BLOCK_SIZE a block in
+          the wide layout, SCAN_NARROW_WIDTH a pod in the narrow one),
+          and how many of the pods went down the narrow layout (the
+          grouping's trailing blocks of exactly one live pod).
+          live ÷ total is the fill: near 1 where groups are many and
+          small (wide, full blocks) and where every pod shares one
+          interaction group (narrow took them all: narrow = live);
+          1 ÷ 32 would be a one-group backlog that the narrow layout
+          missed.  No benchmark metric reads them (PERF.md §7 row 11)
 
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:

@@ -265,6 +265,147 @@ def test_blocked_kernel_matches_exact_scan_on_disjoint_groups():
     assert [got[p.metadata.name] for p in pods] == want
 
 
+def _one_group_backlog(case):
+    """(nodes, pods, plugin chains, volume objects) of a backlog whose pods
+    all interact with each other: one group, so one live pod a block."""
+    from minisched_tpu.api.objects import (
+        ObjectMeta,
+        PersistentVolume,
+        PersistentVolumeClaim,
+        PodAntiAffinity,
+        PVCSpec,
+        PVSpec,
+    )
+    from minisched_tpu.plugins.interpodaffinity import InterPodAffinity
+    from minisched_tpu.plugins.volumerestrictions import VolumeRestrictions
+
+    base = (NodeUnschedulable(), NodeResourcesFit())
+    volumes = {}
+    if case in ("spread", "capacity_tight"):
+        # capacity_tight: 6 nodes of 2 such pods for 16 pods, so the node a
+        # pod may take depends on which nodes earlier pods filled, and the
+        # last four find none
+        tight = case == "capacity_tight"
+        nodes = [
+            make_node(
+                f"n{i:03d}", labels={"zone": "zabc"[1 + i % 3]},
+                capacity={"cpu": "1" if tight else "16", "pods": 64},
+            )
+            for i in range(6 if tight else 24)
+        ]
+        pods = [_spread_pod(f"p{i:03d}", "one") for i in range(16 if tight else 40)]
+        for p in pods:
+            p.spec.containers[0].requests.milli_cpu = 500 if tight else 100
+        ts = PodTopologySpread()
+        chains = ((*base, ts), (ts,), (ts,))
+    elif case == "antiaffinity_hostname":
+        # one pod a node: 24 pods for 20 nodes, the last four find none
+        nodes = [
+            make_node(f"n{i:03d}", labels={"kubernetes.io/hostname": f"n{i:03d}"})
+            for i in range(20)
+        ]
+        pods = [make_pod(f"p{i:03d}", labels={"color": "green"}) for i in range(24)]
+        for p in pods:
+            p.spec.affinity = Affinity(
+                pod_anti_affinity=PodAntiAffinity(
+                    required=[
+                        PodAffinityTerm(
+                            label_selector=LabelSelector(
+                                match_labels={"color": "green"}
+                            ),
+                            topology_key="kubernetes.io/hostname",
+                        )
+                    ]
+                )
+            )
+        ipa = InterPodAffinity()
+        chains = ((*base, ipa), (ipa,), (ipa,))
+    else:
+        assert case == "shared_volume"
+        # every pod mounts the one writable claim: single attach, one a node
+        nodes = _zone_cluster(12)
+        pods = [make_pod(f"p{i:03d}", volumes=["shared"]) for i in range(16)]
+        volumes = dict(
+            pvcs=[
+                PersistentVolumeClaim(
+                    metadata=ObjectMeta(name="shared"),
+                    spec=PVCSpec(request=1 << 30, volume_name="pv-shared"),
+                )
+            ],
+            pvs=[
+                PersistentVolume(
+                    metadata=ObjectMeta(name="pv-shared", namespace=""),
+                    spec=PVSpec(capacity=1 << 30, claim_ref="default/shared"),
+                )
+            ],
+        )
+        chains = ((*base, VolumeRestrictions()), (), ())
+    return sorted(nodes, key=lambda n: n.metadata.name), pods, chains, volumes
+
+
+def _blocked_choices(nodes, rows, chains, volumes, block_size):
+    """Node name ('' for none) of every live row of ``rows`` (None = a
+    padding row), in row order, through the blocked kernel."""
+    dummy = make_pod("scan-pad")
+    row_pods = [m if m is not None else dummy for m in rows]
+    node_table, names = build_node_table(nodes)
+    pod_table, _ = build_pod_table(
+        row_pods,
+        invalid_rows=[i for i, m in enumerate(rows) if m is None],
+        capacity=-(-len(rows) // 128) * 128,
+    )
+    extra = build_constraint_tables(
+        row_pods, nodes, [],
+        pod_capacity=pod_table.capacity, node_capacity=node_table.capacity,
+        **volumes,
+    )
+    blk = BlockedSequentialScheduler(*chains, block_size=block_size)
+    _, choice, _, accepted = blk(pod_table, node_table, extra)
+    choice, accepted = choice.tolist(), accepted.tolist()
+    out = []
+    for i, m in enumerate(rows):
+        if m is not None:
+            assert choice[i] < 0 or accepted[i], m.metadata.name  # no races
+            out.append(names[choice[i]] if choice[i] >= 0 else "")
+    return out
+
+
+@pytest.mark.parametrize("width", [1, 8])
+@pytest.mark.parametrize(
+    "case",
+    ["spread", "capacity_tight", "antiaffinity_hostname", "shared_volume"],
+)
+def test_narrow_layout_places_a_one_group_backlog_like_the_wide_one(case, width):
+    """The kernel at a narrow block size, one live pod every ``width``
+    rows, gives pod for pod the choices of the 32-wide layout (one live
+    pod a block of 32) and of the exact per-pod scan: a padding row never
+    changed a live row's answer, and every pod still sees the state every
+    earlier pod of its group left."""
+    nodes, pods, chains, volumes = _one_group_backlog(case)
+    sets = interaction_sets(pods)
+    wide = [m for blk in order_into_blocks(pods, sets, 32) for m in blk]
+    assert len(wide) == 32 * len(pods)  # one group: a block each
+    node_table, names = build_node_table(nodes)
+    pod_table, _ = build_pod_table(pods)
+    extra = build_constraint_tables(
+        pods, nodes, [], pod_capacity=pod_table.capacity,
+        node_capacity=node_table.capacity, **volumes,
+    )
+    _, exact, _ = SequentialScheduler(*chains)(pod_table, node_table, extra)
+    exact = [names[c] if c >= 0 else "" for c in exact.tolist()[: len(pods)]]
+    narrow = [m for p in pods for m in (p, *[None] * (width - 1))]
+    assert (
+        _blocked_choices(nodes, narrow, chains, volumes, width)
+        == _blocked_choices(nodes, wide, chains, volumes, 32)
+        == exact
+    )
+    # the backlog bites: pods land on several nodes, and where the case
+    # runs out of room the late pods find none
+    assert len(set(exact) - {""}) > 3
+    if case != "spread":
+        assert exact[-1] == "" and exact[0] != ""
+
+
 def test_blocked_kernel_capacity_race_is_flagged_not_lost():
     """Two independent pods racing for the LAST slot of the only feasible
     node: acceptance commits one; the other comes back feasible-but-
@@ -359,6 +500,146 @@ def test_live_engine_blocked_lane_places_spread_burst(monkeypatch):
             zone_of[p.spec.node_name]
             for p in pods
             if p.metadata.labels["app"] == app
+        )
+        counts = [c.get(z, 0) for z in zones]
+        assert max(counts) - min(counts) <= 1, (app, counts)
+
+
+def _burst(case):
+    """Pods of a burst, in the order they are created, by (service, how
+    many): each service's pods carry its one selector."""
+    services = {
+        # one selector shared by all: a block each, all of them narrow
+        "one_selector": [("big", 40)],
+        # 40 services of one pod: a full block and one of 8, nothing narrow
+        "disjoint_services": [(f"svc{i:02d}", 1) for i in range(40)],
+        # three small services ahead of one large: the small ones share
+        # the first blocks with it, the rest of the large one stands alone
+        "mixed": [("a", 2), ("b", 3), ("c", 2), ("big", 60)],
+    }[case]
+    pods, left = [], dict(services)
+    while left:  # round robin, as replicas of several services arrive
+        for app in list(left):
+            n = sum(1 for p in pods if p.metadata.labels["app"] == app)
+            pods.append(_spread_pod(f"{app}-{n:03d}", app))
+            left[app] -= 1
+            if not left[app]:
+                del left[app]
+    return pods
+
+
+@pytest.mark.parametrize("case", ["one_selector", "disjoint_services", "mixed"])
+def test_live_engine_lays_rows_out_by_the_fill_it_found(case, monkeypatch):
+    """The engine's half: blocks of one live pod at the end of a grouping
+    go down the narrow layout and are counted by ``scan.rows_narrow``,
+    every other block keeps 32 rows, wide calls run first; every pod
+    binds, a group's pods are handed to the kernel in the order the
+    grouping got them, and ``maxSkew`` holds."""
+    from minisched_tpu.controlplane.client import Client
+    from minisched_tpu.engine.device_scheduler import DeviceScheduler
+    from minisched_tpu.observability import counters, hist
+    from minisched_tpu.service.config import default_full_roster_config
+    from minisched_tpu.service.service import SchedulerService
+
+    groupings = []  # [pods in, in order], then (narrow, cap, rows) a call
+    plan = DeviceScheduler._plan_blocked_calls.__func__
+
+    def recording_plan(cls, blocks):
+        calls = plan(cls, blocks)
+        groupings.append(
+            (
+                [m.pod.metadata.name for blk in blocks for m in blk if m],
+                [
+                    (narrow, cap, [m and m.pod.metadata.name for m in part])
+                    for narrow, part, cap in calls
+                ],
+            )
+        )
+        return calls
+
+    monkeypatch.setattr(
+        DeviceScheduler, "_plan_blocked_calls", classmethod(recording_plan)
+    )
+    before = counters.snapshot()
+    client = Client()
+    zones = ["za", "zb", "zc", "zd"]
+    for i in range(32):
+        client.nodes().create(
+            make_node(
+                f"node{i:03d}", labels={"zone": zones[i % 4]},
+                capacity={"cpu": "16", "memory": "32Gi", "pods": 64},
+            )
+        )
+    burst = _burst(case)
+    for pod in burst:
+        client.pods().create(pod)
+    svc = SchedulerService(client)
+    sched = svc.start_scheduler(
+        default_full_roster_config(), device_mode=True, max_wave=256
+    )
+    deadline = time.monotonic() + 180
+    while time.monotonic() < deadline:
+        if all(p.spec.node_name for p in client.pods().list()):
+            break
+        time.sleep(0.2)
+    svc.shutdown_scheduler()
+    pods = client.pods().list()
+    assert all(p.spec.node_name for p in pods), [
+        p.metadata.name for p in pods if not p.spec.node_name
+    ]
+
+    def moved(name):
+        return counters.get(name) - before.get(name, 0)
+
+    B, W = sched.SCAN_BLOCK_SIZE, sched.SCAN_NARROW_WIDTH
+    calls = [c for _, cs in groupings for c in cs]
+    narrow_pods = sum(
+        1 for narrow, _, rows in calls if narrow for m in rows if m
+    )
+    wide_rows = sum(len(rows) for narrow, _, rows in calls if not narrow)
+    assert moved("scan.rows_live") == sum(len(g) for g, _ in groupings)
+    assert moved("scan.rows_narrow") == narrow_pods
+    assert moved("scan.rows_total") == wide_rows + W * narrow_pods
+    assert wide_rows % B == 0
+    # one capacity for every narrow call, and the narrow program is the
+    # lane's own: the wide scheduler never saw those rows
+    assert {cap for narrow, cap, _ in calls if narrow} <= {
+        sched.SCAN_MAX_CHUNK * W
+    }
+    programs = sched.dispatched_programs()
+    assert bool(programs["narrow_scan"]) == bool(narrow_pods)
+    assert bool(programs["blocked_scan"]) == bool(wide_rows)
+    for text in programs["narrow_scan"]:
+        assert "module @jit_scan_blocked " in text
+    if case == "one_selector":
+        assert moved("scan.rows_narrow") == len(burst) and not wide_rows
+        assert moved("scan.rows_total") <= W * len(burst)
+    elif case == "disjoint_services":
+        assert moved("scan.rows_narrow") == 0
+        assert moved("scan.rows_total") == B * 2  # 32 + 8 pods: two blocks
+    else:
+        assert narrow_pods and wide_rows
+        assert moved("scan.rows_narrow") < len(burst)
+    assert f"scan_rows_narrow {counters.get('scan.rows_narrow')}\n" in (
+        hist.render_prometheus()
+    )
+    app_of = {p.metadata.name: p.metadata.labels["app"] for p in pods}
+    for handed, its_calls in groupings:
+        # the head runs before the suffix, and a group keeps its order
+        kinds = [narrow for narrow, _, _ in its_calls]
+        assert kinds == sorted(kinds), kinds
+        ran = [m for _, _, rows in its_calls for m in rows if m]
+        assert sorted(ran) == sorted(handed)
+        for app in set(app_of.values()):
+            assert [m for m in ran if app_of[m] == app] == [
+                m for m in handed if app_of[m] == app
+            ], app
+    zone_of = {
+        n.metadata.name: n.metadata.labels["zone"] for n in client.nodes().list()
+    }
+    for app in set(app_of.values()):
+        c = Counter(
+            zone_of[p.spec.node_name] for p in pods if app_of[p.metadata.name] == app
         )
         counts = [c.get(z, 0) for z in zones]
         assert max(counts) - min(counts) <= 1, (app, counts)

@@ -35,7 +35,7 @@ def _run(code: str, env_extra: dict, cwd: str = REPO, drop=()):
 def test_drive_and_audit_tiny_on_cpu():
     """The function chip_smoke.py runs at 5,000 nodes x 10,000 pods, at 64
     nodes x 348 pods: booted through __main__.start, driven over HTTP, all
-    three lanes dispatched, REST/metrics/trace audits clean."""
+    four lanes dispatched, REST/metrics/trace audits clean."""
     import chip_smoke
 
     sizes = chip_smoke.Sizes(nodes=64, plain=300, burst=40, tail=8)

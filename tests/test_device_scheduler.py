@@ -10,6 +10,8 @@ from __future__ import annotations
 import os
 import time
 
+import pytest
+
 from minisched_tpu.api.objects import make_node, make_pod
 from minisched_tpu.controlplane.client import Client
 from minisched_tpu.service.config import (
@@ -365,12 +367,15 @@ def test_cross_pod_wave_partition_is_bind_exact():
     ][:5]
 
 
-def test_blocked_scan_lane_under_mesh():
+@pytest.mark.parametrize("n_apps", [6, 1])
+def test_blocked_scan_lane_under_mesh(n_apps):
     """A cross-pod burst bigger than SCAN_BLOCK_SIZE on a live MESH
     engine: the blocked scan lane must compose with sharded waves —
     every pod binds, DoNotSchedule skew holds, no node over capacity.
-    (The sharded dryrun covers the exact per-pod scan; this covers the
-    blocked lane, which runs unsharded inside the mesh engine.)"""
+    Six services fill their blocks (the wide layout); one service is a
+    block a pod (the narrow layout).  (The sharded dryrun covers the
+    exact per-pod scan; this covers the blocked lane, which runs
+    node-sharded inside the mesh engine.)"""
     import time
 
     from minisched_tpu.api.objects import LabelSelector, TopologySpreadConstraint
@@ -386,7 +391,7 @@ def test_blocked_scan_lane_under_mesh():
                 capacity={"cpu": "8", "memory": "16Gi", "pods": 110},
             )
         )
-    n_spread, n_plain, n_apps = 48, 40, 6
+    n_spread, n_plain = 48, 40
     for i in range(n_plain):
         client.pods().create(
             make_pod(f"plain{i:03d}", requests={"cpu": "250m"})
@@ -442,6 +447,9 @@ def test_blocked_scan_lane_under_mesh():
             counts = list(zones.values())
             assert max(counts) - min(counts) <= 1, (app, zones)
         assert all(v <= 8000 for v in cpu.values())
+        programs = sched.dispatched_programs()
+        lane = "blocked_scan" if n_apps > 1 else "narrow_scan"
+        assert programs[lane], {k: len(v) for k, v in programs.items()}
     finally:
         svc.shutdown_scheduler()
 

@@ -7,7 +7,8 @@ inside a wave).  These tests pin the quantization invariants so a
 * node label/taint profiles (Dp) quantize to 64,
 * combo/ex-term/claim/volume axes quantize to 32 and the topology-key
   axis to 4,
-* scan chunks use exactly two capacities,
+* scan chunks use exactly two capacities, the blocked lane's wide layout
+  one more, its narrow layout exactly one whatever it carries,
 * pod tables have exactly TWO packed schemas per capacity (fast/slow),
   and the slow one can be force-packed below the size threshold (the
   prewarm relies on it).
@@ -16,6 +17,7 @@ inside a wave).  These tests pin the quantization invariants so a
 from __future__ import annotations
 
 import numpy as np
+import pytest
 
 from minisched_tpu.api.objects import (
     LabelSelector,
@@ -95,6 +97,38 @@ def test_scan_chunks_use_exactly_two_capacities():
     assert DeviceScheduler._blocked_cap(
         DeviceScheduler.BLOCKED_MAX_CHUNK
     ) == DeviceScheduler.BLOCKED_MAX_CHUNK
+
+
+@pytest.mark.parametrize("head_blocks", [0, 3])
+@pytest.mark.parametrize("suffix", [1, 33, 1024, 1025, 8192])
+def test_narrow_layout_runs_at_exactly_one_capacity(suffix, head_blocks):
+    """Whatever the length of the run of one-pod blocks a grouping ends
+    in, its calls share ONE pod capacity (a short last call pads up to
+    it), carry at most SCAN_MAX_CHUNK live pods each and keep block order;
+    the blocks before it keep the wide layout's tiers and run first."""
+    S = DeviceScheduler
+    B, W = S.SCAN_BLOCK_SIZE, S.SCAN_NARROW_WIDTH
+    head = [
+        [f"h{b}.{i}" for i in range(B - b)] + [None] * b
+        for b in range(head_blocks)
+    ]
+    tail = [[f"s{i}"] + [None] * (B - 1) for i in range(suffix)]
+    calls = S._plan_blocked_calls(head + tail)
+    narrow = [(part, cap) for is_narrow, part, cap in calls if is_narrow]
+    wide = [(part, cap) for is_narrow, part, cap in calls if not is_narrow]
+    assert [is_narrow for is_narrow, _, _ in calls] == (
+        [False] * len(wide) + [True] * len(narrow)
+    )
+    assert {cap for _, cap in narrow} == {S.SCAN_MAX_CHUNK * W}
+    assert len(narrow) == -(-suffix // S.SCAN_MAX_CHUNK)
+    for part, cap in narrow:
+        assert len(part) <= cap and len(part) % W == 0
+        assert all((m is not None) == (i % W == 0) for i, m in enumerate(part))
+    assert [m for part, _ in narrow for m in part if m] == [
+        f"s{i}" for i in range(suffix)
+    ]
+    assert [m for part, _ in wide for m in part] == [m for b in head for m in b]
+    assert {cap for _, cap in wide} <= {S._blocked_cap(B * head_blocks)}
 
 
 def test_pod_table_has_two_schemas_per_capacity():

@@ -223,19 +223,19 @@ def test_the_two_plain_histograms_are_on_metrics_from_boot(boot_probe, series):
 # -- a tiny served wave under the profiler ------------------------------------
 
 
-def _spread_pod(name):
+def _spread_pod(name, app="s"):
     from minisched_tpu.api.objects import (
         LabelSelector,
         TopologySpreadConstraint,
         make_pod,
     )
 
-    pod = make_pod(name, requests={"cpu": "100m"}, labels={"app": "s"})
+    pod = make_pod(name, requests={"cpu": "100m"}, labels={"app": app})
     pod.spec.topology_spread_constraints = [
         TopologySpreadConstraint(
             max_skew=1, topology_key="zone",
             when_unsatisfiable="DoNotSchedule",
-            label_selector=LabelSelector(match_labels={"app": "s"}),
+            label_selector=LabelSelector(match_labels={"app": app}),
         )
     ]
     return pod
@@ -282,9 +282,13 @@ def traced_wave(tmp_path_factory):
         plain = [make_pod(f"a{i}", requests={"cpu": "100m"}) for i in range(24)]
         client.pods().create_many(plain, return_objects=False)
         _wait_bound(client, 24)
-        # 40 > SCAN_BLOCK_SIZE: the blocked lane; 8: the exact lane
+        # 40 > SCAN_BLOCK_SIZE: the blocked lane, whose first two blocks
+        # hold two pods (wide) and whose other 36 hold one (narrow); 8: the
+        # exact lane
         client.pods().create_many(
-            [_spread_pod(f"s{i}") for i in range(40)], return_objects=False
+            [_spread_pod(f"r{i}", "r") for i in range(2)]
+            + [_spread_pod(f"s{i}") for i in range(38)],
+            return_objects=False,
         )
         _wait_bound(client, 64)
         client.pods().create_many(
@@ -310,6 +314,9 @@ def traced_wave(tmp_path_factory):
         with_locations = {
             "wave": sched._evaluator._packed_caller.lowered_texts(debug_info=True),
             "blocked_scan": sched._blocked_scheduler._packed_caller.lowered_texts(
+                debug_info=True
+            ),
+            "narrow_scan": sched._narrow_scheduler._packed_caller.lowered_texts(
                 debug_info=True
             ),
         }
@@ -399,7 +406,7 @@ def test_the_create_span_carries_its_item_count(traced_wave):
 @pytest.mark.parametrize(
     "lane,module",
     [("wave", "jit_wave"), ("blocked_scan", "jit_scan_blocked"),
-     ("exact_scan", "jit_scan_exact")],
+     ("narrow_scan", "jit_scan_blocked"), ("exact_scan", "jit_scan_exact")],
 )
 def test_the_device_programs_carry_their_lane(traced_wave, lane, module):
     texts = traced_wave["programs"][lane]
@@ -409,7 +416,7 @@ def test_the_device_programs_carry_their_lane(traced_wave, lane, module):
         assert "jit_run" not in text
 
 
-@pytest.mark.parametrize("lane", ["wave", "blocked_scan"])
+@pytest.mark.parametrize("lane", ["wave", "blocked_scan", "narrow_scan"])
 def test_the_selection_tail_has_a_name_in_the_program(traced_wave, lane):
     """Off the TPU the XLA tail runs, under its named scope (on the chip
     the Mosaic kernel's ``name=`` says ``select_hosts`` there instead)."""
