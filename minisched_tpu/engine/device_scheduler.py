@@ -37,6 +37,7 @@ from minisched_tpu.framework.types import (
 from minisched_tpu.models.constraints import (
     SCAN_ELIDE_GROUPS,
     build_constraint_tables,
+    combo_rows,
 )
 from minisched_tpu.models.tables import (
     CachedNodeTableBuilder,
@@ -213,6 +214,12 @@ class DeviceScheduler(Scheduler):
         # (the lane's exactness contract) is unchanged.
         self._scan_backlog: List[QueuedPodInfo] = []
         self._scan_backlog_waves = 0  # full waves survived since first defer
+        #: the scan lanes' combo capacity so far (_build_constraints)
+        self._scan_combo_cap = 0
+        from minisched_tpu.observability import counters
+
+        for name in counters.LANE_COUNTERS:
+            counters.inc(name, 0)
         # assume-pod cache (upstream's scheduler cache AssumePod): a placed
         # pod counts against its node IMMEDIATELY, before the async bind
         # lands in the informer cache — without it, the next wave snapshots
@@ -288,7 +295,17 @@ class DeviceScheduler(Scheduler):
         assumed-pod membership check and the aggregate reads happen under
         ONE index lock hold — otherwise a bind event landing in between
         would count its pod both as "assumed" and in the index planes
-        (TOCTOU double-count)."""
+        (TOCTOU double-count).
+
+        The scan lanes' builds (``scan_planes``) share one combo
+        capacity that only grows (``_scan_combo_cap``): the combos of a
+        build are the selectors among ITS pods, many in the call that
+        holds a flush's head and one in the narrow tail, and each
+        capacity is a program of its own at every pod tier.  Holding the
+        largest tier reached, the lanes run one program a pod tier
+        whatever a call holds, all reached once the first big flush has
+        been (spare combo rows are all-zero: they never match or count).
+        """
         import contextlib
 
         index = self.constraint_index
@@ -309,7 +326,10 @@ class DeviceScheduler(Scheduler):
             with self.metrics.timed("constraints_store_list"):
                 pvcs = self.client.store.list("PersistentVolumeClaim")
                 pvs = self.client.store.list("PersistentVolume")
-            return build_constraint_tables(
+            scan = kw.get("scan_planes")
+            if scan:
+                kw["combo_capacity"] = self._scan_combo_cap
+            tables = build_constraint_tables(
                 pods_, nodes, assigned,
                 pvcs=pvcs,
                 pvs=pvs,
@@ -317,6 +337,9 @@ class DeviceScheduler(Scheduler):
                 extra_assigned=extra,
                 **kw,
             )
+            if scan:
+                self._scan_combo_cap = combo_rows(tables)
+            return tables
         finally:
             lock_cm.__exit__(None, None, None)
 
@@ -794,7 +817,10 @@ class DeviceScheduler(Scheduler):
 
         Shapes must match the live waves exactly or the warm executable is
         wasted: pod capacity is the wave capacity (``_wave_cap``), node
-        capacity is the table builder's for the current node count.
+        capacity is the table builder's for the current node count.  The
+        scan lanes are warmed at the lowest combo capacity (up to 32
+        selectors a call); a workload that holds more compiles them once
+        more, at its first big flush (``_build_constraints``).
         A throwaway table builder keeps the real one's static-column cache
         out of it.
         """
@@ -1170,13 +1196,8 @@ class DeviceScheduler(Scheduler):
                 for narrow, part, cap in calls:
                     if fresh is None:
                         fresh = self._snapshot_for_wave()
-                    scheduler = (
-                        self._get_narrow_scheduler()
-                        if narrow
-                        else self._get_blocked_scheduler()
-                    )
                     retry += self._run_blocked_chunk(
-                        part, cap, scheduler, *fresh
+                        part, cap, narrow, *fresh
                     )
                     fresh = None
                 if not retry:
@@ -1195,18 +1216,27 @@ class DeviceScheduler(Scheduler):
         self,
         part: List[Optional[QueuedPodInfo]],
         cap: int,
-        scheduler: Any,
+        narrow: bool,
         node_infos: List[Any],
         agg_delta: Any,
         assumed_pods: Any,
     ) -> List[QueuedPodInfo]:
         """One blocked-kernel call over ``part`` (None = block padding)
-        at pod capacity ``cap`` through ``scheduler``, whose block size
-        the rows were laid out for.  Commits winners, parks infeasible
-        pods, returns the capacity-race retries."""
+        at pod capacity ``cap`` through the scheduler whose block size
+        the rows were laid out for (``narrow``: SCAN_NARROW_WIDTH, else
+        SCAN_BLOCK_SIZE).  Commits winners, parks infeasible pods,
+        returns the capacity-race retries."""
         import jax
 
         from minisched_tpu.api.objects import make_pod
+        from minisched_tpu.observability import counters
+
+        counters.inc("scan.calls_narrow" if narrow else "scan.calls_wide")
+        scheduler = (
+            self._get_narrow_scheduler()
+            if narrow
+            else self._get_blocked_scheduler()
+        )
 
         nodes = [ni.node for ni in node_infos]
         assigned = (
@@ -1296,7 +1326,7 @@ class DeviceScheduler(Scheduler):
                 retry.append(qpi)  # feasible; lost a same-node race
             else:
                 losers.append((qpi, qpi.pod, set()))
-        self._commit_winners(winners)
+        self._commit_winners(winners, "narrow" if narrow else "wide")
         # keep the next chunk's grouping/build gated: _bind_batch closes
         # the gate when it runs, but a chunk whose winners all parked in
         # permit-wait (or that had none) never reaches it — re-close
@@ -1391,7 +1421,7 @@ class DeviceScheduler(Scheduler):
                     continue
                 self._assume(qpi.pod, node_names[c])
                 winners.append((qpi, qpi.pod, node_names[c]))
-            self._commit_winners(winners)
+            self._commit_winners(winners, "exact")
             # _bind_batch re-closed the gate; this lane stays ungated (the
             # next chunk's re-snapshot needs the bind events applied)
             self.informer_factory.resume_dispatch()
@@ -1714,7 +1744,7 @@ class DeviceScheduler(Scheduler):
                     cause="capacity_raced",
                 )
                 self.queue.add(pod, requeue=True)
-        self._commit_winners(winners)
+        self._commit_winners(winners, "wave")
         if losers:
             self._handle_wave_losers(
                 losers, prepared.node_infos, len(prepared.node_infos)
@@ -2228,7 +2258,7 @@ class DeviceScheduler(Scheduler):
             reasons=canonical_filter_reasons(),
         )
 
-    def _commit_winners(self, winners: List[Any]) -> None:
+    def _commit_winners(self, winners: List[Any], lane: str) -> None:
         """Host-side tail of the wave for every placed pod: reserve →
         permit per pod (host plugin chains, minisched.go:89-112), then ONE
         batched bind transaction for all immediately-bindable pods — a
@@ -2236,8 +2266,14 @@ class DeviceScheduler(Scheduler):
         bind dominated the e2e profile.  Pods a permit plugin parked in
         Wait still get a detached binding cycle (the wait can be seconds).
 
-        ``winners``: (qpi, pod, node_name) triples, already assumed.
+        ``winners``: (qpi, pod, node_name) triples, already assumed;
+        ``lane`` is the program that placed them: ``wave``, the blocked
+        scan's ``wide`` or ``narrow`` layout, or ``exact`` (counted by
+        ``sched.lane_pods.<lane>``, counters.LANE_COUNTERS).
         """
+        from minisched_tpu.observability import counters
+
+        counters.inc("sched.lane_pods." + lane, len(winners))
         with self.metrics.timed("commit", wave=self._wave_seq, n=len(winners)):
             self._commit_winners_inner(winners)
 

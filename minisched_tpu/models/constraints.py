@@ -36,6 +36,7 @@ import numpy as np
 
 from minisched_tpu.api.objects import LabelSelector, PodAffinityTerm
 from minisched_tpu.models.tables import _register_table, pad_to
+from minisched_tpu.observability import counters
 
 MAX_VOLUMES = 4  # PVC references per pod
 MAX_TSC = 4  # topology spread constraints per pod
@@ -73,10 +74,28 @@ SCAN_ELIDE_GROUPS = (
     ("ts_combo", "ts_skew", "ts_mode", "ts_n"),
 )
 
-#: capacity quantum for the combo/ex-term/claim/volume axes — every
+#: smallest capacity of the combo/ex-term/claim/volume axes — every
 #: distinct padded size is a separate compiled executable (see the combo
 #: matrices comment in build_constraint_tables)
 CAP_QUANTUM = 32
+#: each further capacity of those axes is this many times the one below
+CAP_TIER_FACTOR = 8
+
+
+def cap_tier(n: int) -> int:
+    """Capacity of a content-driven axis that holds ``n`` live rows: the
+    smallest of 32, 256, 2048, ... that does.  The pod axis' discipline
+    (_scan_cap: two capacities, _blocked_cap: three tiers) for the axes
+    whose length follows what a build holds: padded to the next multiple
+    of 32, 255 pending services met eight sizes of the combo axis between
+    1 and 255, each a program of its own times the lanes' pod tiers and
+    the later ones first met mid-run; a tier is left only by growing
+    eightfold, so a run meets one or two, early.  Padded rows are all
+    zero: they never match and never count."""
+    cap = CAP_QUANTUM
+    while cap < n:
+        cap *= CAP_TIER_FACTOR
+    return cap
 
 
 @_register_table
@@ -154,6 +173,17 @@ class ConstraintTables:
     pod_missing: Any  # i32[P] mounts whose PVC doesn't exist (generic)
     vol_any: Any  # bool[Vd, N] some assigned pod on n mounts volume v
     vol_rw: Any  # bool[Vd, N] ... with a writable mount
+
+
+def combo_rows(tables: Any) -> int:
+    """Rows of the combo axis ``C`` of built tables, packed or on device."""
+    if isinstance(tables, ConstraintTables):
+        return tables.combo_global.shape[0]
+    return next(
+        shape[0]
+        for name, _kind, shape in tables.metas + tables.zero_metas
+        if name == "combo_global"
+    )
 
 
 #: field → (kind, axis-role) — the ONE authority on how each plane is laid
@@ -392,6 +422,7 @@ def build_constraint_tables(
     device: bool = True,
     elide_zeros: bool = True,
     elide_groups: Tuple[Tuple[str, ...], ...] = (),
+    combo_capacity: int = 0,
 ):
     """Build the wave's coupling tables.
 
@@ -405,6 +436,11 @@ def build_constraint_tables(
     default — all-False would silently break scan parity — wave-only
     callers (DeviceScheduler, bench wave paths) pass False to skip the
     host-side matching cost.
+
+    ``combo_capacity``: the fewest rows the combo axis may have, for a
+    caller that keeps the largest capacity its builds have reached (the
+    engine's scan lanes: a build that holds few combos after one that held
+    many runs the program that is there, see ``combo_rows``).
 
     ``index``: a ``constraint_index.ConstraintIndex`` — the assigned-pod
     planes then come from its event-maintained aggregates in
@@ -503,11 +539,15 @@ def build_constraint_tables(
             _collect_rev(p)
 
     # --- combo matrices ----------------------------------------------------
-    # capacity quantum 32 (not 8): C/T/C2/Vd are EXECUTABLE shapes — a
-    # wave whose combo count steps over a small quantum recompiles the
-    # whole evaluator mid-run.  32 keeps one shape
-    # for realistic rosters at the cost of a few spare 1-MB planes.
-    C = pad_to(max(len(reg.combos), 1), CAP_QUANTUM)
+    # C/T/C2/Vd are EXECUTABLE shapes — a build whose combo count steps
+    # over a capacity recompiles the whole evaluator mid-run.  cap_tier
+    # keeps one shape up to 32 combos and one more for every eightfold
+    # growth, at the cost of spare (all-zero) planes.
+    C = max(cap_tier(len(reg.combos)), combo_capacity)
+    if scan_planes:
+        # the scan lanes' builds say how full the combo axis is
+        counters.inc("scan.combos_live", len(reg.combos))
+        counters.inc("scan.combos_total", C)
     combo_dsum = np.zeros((C, N), np.int32)
     combo_haskey = np.zeros((C, N), bool)
     combo_global = np.zeros(C, np.int32)
@@ -661,7 +701,7 @@ def build_constraint_tables(
     else:
         for p in assigned:
             _add_ex_terms_of(p)
-    T = pad_to(max(len(ex_terms), 1), CAP_QUANTUM)
+    T = cap_tier(len(ex_terms))
     ex_domain = np.zeros((T, N), bool)
     pod_matches_ex = np.zeros((P, T), bool)
     for t, (nss, sel, topo, owner_val) in enumerate(ex_terms):
@@ -756,7 +796,7 @@ def build_constraint_tables(
             pod_claims[i, j] = claim_ids[key]
             pod_claim_valid[i, j] = True
         vol_ok[i] = ok
-    C2 = pad_to(max(len(claim_rows), 1), CAP_QUANTUM)
+    C2 = cap_tier(len(claim_rows))
     claim_mask = np.zeros((C2, N), bool)
     claim_zone_ok = np.zeros((C2, N), bool)
     claim_vol = np.full(C2, -1, np.int32)
@@ -773,7 +813,7 @@ def build_constraint_tables(
     # per-volume mount state from assigned pods: one pre-pass over node
     # claims (O(assigned mounts)), rows only for volumes the wave's claims
     # reference; last row stays a dummy scatter target
-    Vd = pad_to(len(vol_ids) + 1, CAP_QUANTUM)
+    Vd = cap_tier(len(vol_ids) + 1)
     vol_any = np.zeros((Vd, N), bool)
     vol_rw = np.zeros((Vd, N), bool)
     node_vols_fam = np.zeros((F, N), np.int32)

@@ -118,25 +118,43 @@ _SCHEMA_SEEN: Dict[Tuple, int] = {}
 
 _ZERO_DT = {"bool": jnp.bool_, "uint32": jnp.uint32, "int32": jnp.int32}
 
+#: a column whose last axis is shorter than this does not fill one row of
+#: the chip's 8 x 128 tiles (unpack_columns ``fence_narrow``)
+NARROW_LAST_AXIS = 128
+
 
 def unpack_columns(
     flat,
     metas: Tuple[Tuple[str, str, Tuple[int, ...]], ...],
     zero_metas: Tuple[Tuple[str, str, Tuple[int, ...]], ...] = (),
+    fence_narrow: bool = False,
 ) -> Dict[str, Any]:
     """TRACEABLE inverse of ``pack_columns``: slice the flat int32 buffer
     back into named, dtyped columns (+ all-zero columns materialized in
     place).  Usable inside a larger jit — the wave evaluator unpacks its
     tables inside its OWN program so a wave costs one executable and one
     dispatch, not an alternation of splitter programs with the
-    evaluator."""
+    evaluator.
+
+    ``fence_narrow``: keep the slice of every column whose last axis is
+    narrower than a lane row (``NARROW_LAST_AXIS``) apart from its reshape.
+    The TPU compiler turns ``reshape(slice(flat))`` of such a column into
+    ``slice(reshape(flat))``: the WHOLE buffer laid out ``[len/4, 4]``,
+    copied a few words at a time by straight-line code.  For a constraint
+    buffer of 2.7-8.4 M words that one reshape was 139 MB of a scan
+    program's 170 MB of code and 150 of its 180 s of compile, and whether
+    it happened hung on the offsets the elided columns left (PERF.md
+    section 6, PR 33).  Behind the fence the reshape sees 4 K words."""
     out = {}
     off = 0
     for name, kind, shape in metas:
         size = 1
         for d in shape:
             size *= d
-        seg = flat[off : off + size].reshape(shape)
+        seg = flat[off : off + size]
+        if fence_narrow and len(shape) > 1 and shape[-1] < NARROW_LAST_AXIS:
+            seg = jax.lax.optimization_barrier(seg)
+        seg = seg.reshape(shape)
         off += size
         if kind == "bool":
             out[name] = seg != 0
@@ -311,7 +329,7 @@ class PackedCaller:
             )
             extra = (
                 ConstraintTables(
-                    **unpack_columns(ex_flat, *ex_schema)
+                    **unpack_columns(ex_flat, *ex_schema, fence_narrow=True)
                 )
                 if ex_schema is not None
                 else None

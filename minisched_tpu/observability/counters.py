@@ -156,7 +156,28 @@ each retry round of it):
           small (wide, full blocks) and where every pod shares one
           interaction group (narrow took them all: narrow = live);
           1 ÷ 32 would be a one-group backlog that the narrow layout
-          missed.  No benchmark metric reads them (PERF.md §7 row 11)
+          missed (benchmark metrics ``scan.fill_share``,
+          ``scan.narrow_share``)
+    scan.calls_wide / scan.calls_narrow
+        — kernel calls of the blocked lane by the layout of their rows
+          (one grouping is cut into the calls its fill asks for: the
+          head wide, the trailing one-pod blocks narrow)
+    scan.combos_live / scan.combos_total
+        — once a constraint build of the scan lanes (blocked and
+          exact): the (namespaces, selector, topology key) combos among
+          the build's pods, and the rows the combo axis was padded to
+          (models/constraints.cap_tier: 32, 256, 2048, ...); live ÷
+          total is how full the combo planes a step reads are
+          (benchmark metric ``scan.combo_fill_share``)
+
+The device engine counts the pods each of its programs placed, as they
+are handed to the commit (engine/device_scheduler ``_commit_winners``):
+
+    sched.lane_pods.wave / .wide / .narrow / .exact
+        — the packed repair wave, the blocked scan's wide (32 rows a
+          step) and narrow (a pod a step) layouts, the exact per-pod
+          scan.  All of these and the ``scan.*`` counters above are
+          registered at 0 when a device engine is constructed
 
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
@@ -561,6 +582,18 @@ from __future__ import annotations
 
 import threading
 from typing import Dict, Set
+
+
+#: what the device engine's lanes count (see the module doc), registered
+#: at 0 when a device engine is constructed: a reader of /metrics tells a
+#: lane that did not run from a program that has no such counter
+LANE_COUNTERS = (
+    "scan.rows_live", "scan.rows_total", "scan.rows_narrow",
+    "scan.calls_wide", "scan.calls_narrow",
+    "scan.combos_live", "scan.combos_total",
+    "sched.lane_pods.wave", "sched.lane_pods.wide",
+    "sched.lane_pods.narrow", "sched.lane_pods.exact",
+)
 
 
 class Counters:

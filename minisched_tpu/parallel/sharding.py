@@ -609,7 +609,9 @@ class MeshPackedCaller:
                 **unpack_columns(agg_flat, agg_metas, agg_zeros),
             )
             extra = (
-                ConstraintTables(**unpack_columns(ex_flat, *ex_schema))
+                ConstraintTables(
+                    **unpack_columns(ex_flat, *ex_schema, fence_narrow=True)
+                )
                 if ex_schema is not None
                 else None
             )

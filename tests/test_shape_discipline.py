@@ -5,8 +5,9 @@ inside a wave).  These tests pin the quantization invariants so a
 "small" capacity tweak can't silently reintroduce that class:
 
 * node label/taint profiles (Dp) quantize to 64,
-* combo/ex-term/claim/volume axes quantize to 32 and the topology-key
-  axis to 4,
+* combo/ex-term/claim/volume axes hold tiers (32, 256, 2048, ...:
+  ``constraints.cap_tier``; tests/test_mixed_deployment.py steps over
+  them) and the topology-key axis quantizes to 4,
 * scan chunks use exactly two capacities, the blocked lane's wide layout
   one more, its narrow layout exactly one whatever it carries,
 * pod tables have exactly TWO packed schemas per capacity (fast/slow),
