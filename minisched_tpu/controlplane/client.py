@@ -10,12 +10,32 @@ client-side QPS/Burst rate limiter the reference configures at 5000/5000
 
 from __future__ import annotations
 
+import queue
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from minisched_tpu.api.objects import Binding, Node, Pod, PodStatus
-from minisched_tpu.controlplane.store import Conflict, ObjectStore
+from minisched_tpu.api.objects import (
+    Binding,
+    Event,
+    Node,
+    ObjectMeta,
+    Pod,
+    PodStatus,
+)
+from minisched_tpu.controlplane.store import (
+    Conflict,
+    ObjectStore,
+    StorageDegraded,
+)
+from minisched_tpu.observability import counters, profiling
+
+profiling.register_spans("events.write")
+
+#: what the event writer counts (observability/counters), registered at 0
+#: when a recorder is given a store
+_EVENT_COUNTERS = ("events.written", "events.trimmed", "events.batches")
 
 #: the reference's client limits (k8sapiserver.go:60-61)
 DEFAULT_QPS = 5000.0
@@ -60,10 +80,10 @@ class _ThrottledStore:
     limiter covers requests, not watch deliveries)."""
 
     _THROTTLED = frozenset(
-        # mutate_many / create_many are ONE API request each (batch
-        # bind / batch create), so one token
+        # mutate_many / create_many / delete_many are ONE API request
+        # each (batch bind / batch create / batch delete), so one token
         ("create", "create_many", "get", "list", "list_with_rv", "update",
-         "delete", "mutate", "mutate_many", "watch")
+         "delete", "delete_many", "mutate", "mutate_many", "watch")
     )
 
     def __init__(self, store: ObjectStore, limiter: TokenBucket):
@@ -479,15 +499,27 @@ class EventRecorder:
     records ``eventsv1`` objects a client can list) — list/watch-able over
     the store and the REST façade; the kind is volatile (no WAL).  Writes
     happen on a dedicated writer thread, like upstream's broadcaster
-    goroutines: ``eventf`` on the scheduling hot path only enqueues (a
-    device wave emits thousands of decisions — synchronous store writes
-    there would eat the batched-bind win).  ``flush()`` waits for the
-    queue to drain (call before asserting/reading in tests or shutdown).
+    goroutines: ``eventf`` on the scheduling hot path only enqueues the
+    decision's plain fields (a device wave emits thousands of decisions —
+    synchronous store writes there would eat the batched-bind win).
+
+    The writer lands what has piled up as ONE store transaction: it
+    blocks for the first decision, takes everything else that is queued
+    with it (no size knob, no timer: 1 in a scenario, a score while it
+    keeps pace with a wave's commit loop, hundreds to thousands once
+    it falls behind), builds the ``Event`` objects and writes them
+    with one ``create_many`` — one lock hold, one fanout, one swap of
+    the store's read plane a batch, where a ``create`` and a ``delete``
+    an event each copied the kind's whole map.  ``flush()`` waits until
+    everything enqueued before the call is in the store (call before
+    asserting/reading in tests or shutdown).
 
     ``max_events`` bounds growth on BOTH sides (kube events expire by
     TTL; a 100k-pod run would otherwise accrete 100k objects): the
     in-process ``events`` deque drops its oldest dicts, and the oldest
-    Event object is deleted from the store as the cap is passed.
+    Event objects are deleted from the store, one ``delete_many`` BEFORE
+    a batch's create, so the store never shows more than the cap (a
+    batch longer than the cap is written in pieces of at most the cap).
     """
 
     def __init__(self, store: Any = None, max_events: int = 2048) -> None:
@@ -495,15 +527,20 @@ class EventRecorder:
 
         self._events: Any = deque(maxlen=max_events)
         self._store = store
-        self._max_events = max_events
-        self._seq = 0
-        self._mu = threading.Lock()
+        self._max_events = max(int(max_events), 1)
+        self._mu = threading.Lock()  # the record, the numbering, the enqueue
+        self._seq = 0  # decisions enqueued for the store so far
+        self._closing = store is None  # eventf enqueues nothing from now on
         self._writer = None
         if store is not None:
-            import queue as _queue
-
+            for name in _EVENT_COUNTERS:
+                counters.inc(name, 0)  # on /metrics from now on
             self._live: Any = deque()  # (namespace, name) in emit order
-            self._q: Any = _queue.Queue()
+            # a C queue: put takes no lock the writer ever holds, so no
+            # emitter waits for the writer
+            self._q: Any = queue.SimpleQueue()
+            self._landed = 0  # the last decision the writer is done with
+            self._progress = threading.Condition()  # flush() waits on it
             self._writer = threading.Thread(
                 target=self._drain, name="event-writer", daemon=True
             )
@@ -520,87 +557,145 @@ class EventRecorder:
 
     def eventf(self, obj: Any, event_type: str, reason: str, message: str) -> None:
         meta = getattr(obj, "metadata", None)
-        regarding = getattr(meta, "key", "") if meta is not None else ""
+        if meta is not None:
+            regarding = getattr(meta, "key", "")
+            subject = getattr(meta, "name", "")
+            namespace = getattr(meta, "namespace", "") or "default"
+        else:
+            regarding, subject, namespace = "", "", "default"
+        record = {
+            "object": regarding or str(obj),
+            "type": event_type,
+            "reason": reason,
+            "message": message,
+        }
+        # ONE lock hold a decision, and plain fields: the Event and its
+        # ObjectMeta are the writer's to build, a batch at a time
         with self._mu:
-            self._events.append(
-                {
-                    "object": regarding or str(obj),
-                    "type": event_type,
-                    "reason": reason,
-                    "message": message,
-                }
-            )
-        if self._store is None:
-            return
-        from minisched_tpu.api.objects import Event, ObjectMeta
-
-        with self._mu:
+            self._events.append(record)
+            if self._closing:  # no store, or closed: the dict alone
+                return
             self._seq += 1
-            seq = self._seq
-        subject = getattr(meta, "name", "") if meta is not None else ""
-        namespace = (
-            getattr(meta, "namespace", "") if meta is not None else ""
-        ) or "default"
-        self._q.put(
-            Event(
-                metadata=ObjectMeta(
-                    name=f"{subject or 'scheduler'}.{seq:x}",
-                    namespace=namespace,
-                ),
-                type=event_type,
-                reason=reason,
-                message=message,
-                regarding=regarding,
+            self._q.put(
+                (self._seq, subject, namespace, event_type, reason,
+                 message, regarding)
             )
-        )
 
     def _drain(self) -> None:
+        q, cap = self._q, self._max_events
         while True:
-            evt = self._q.get()
-            if evt is None:  # close() sentinel
-                self._q.task_done()
-                return
+            # block for the first decision, then take whatever else has
+            # queued without waiting: the batch is what piled up
+            batch = [q.get()]
             try:
-                self._store.create(KIND_EVENT, evt)
-                ns, name = evt.metadata.namespace, evt.metadata.name
-                self._live.append((ns, name))
-                if len(self._live) > self._max_events:
-                    drop = self._live.popleft()
-                    try:
-                        self._store.delete(KIND_EVENT, drop[0], drop[1])
-                    except KeyError:
-                        pass  # already gone (store swapped/cleared)
-            except Exception as err:
-                # a full/closed store must not kill the writer; an event
-                # shed to a degraded DISK is counted so an ENOSPC episode
-                # shows up in the recovery ledger, not just as silence
-                from minisched_tpu.controlplane.store import StorageDegraded
+                while True:
+                    batch.append(q.get_nowait())
+            except queue.Empty:
+                pass
+            closing = batch[-1] is None  # close()'s sentinel: always last
+            if closing:
+                batch.pop()
+            for i in range(0, len(batch), cap):
+                piece = batch[i:i + cap]
+                try:
+                    self._write(piece)
+                except Exception as err:  # noqa: BLE001 — the writer never dies
+                    print(
+                        f"[events] a batch of {len(piece)} was lost: {err!r}",
+                        file=sys.stderr, flush=True,
+                    )
+                with self._progress:
+                    self._landed = piece[-1][0]
+                    self._progress.notify_all()
+            if closing:
+                return
 
-                if isinstance(err, StorageDegraded):
-                    from minisched_tpu.observability import counters
+    def _write(self, batch: List[tuple]) -> None:
+        """One batch (at most ``max_events`` decisions) as one store
+        transaction: trim, then create.  An item the store refuses is
+        that item's loss alone."""
+        with profiling.span("events.write", n=len(batch)):
+            events = [
+                Event(
+                    metadata=ObjectMeta(
+                        name=f"{subject or 'scheduler'}.{seq:x}",
+                        namespace=namespace,
+                    ),
+                    type=event_type,
+                    reason=reason,
+                    message=message,
+                    regarding=regarding,
+                )
+                for seq, subject, namespace, event_type, reason, message,
+                regarding in batch
+            ]
+            live = self._live
+            over = len(live) + len(events) - self._max_events  # <= len(live)
+            if over > 0:
+                counters.inc("events.trimmed", self._trim(over))
+            try:
+                results = self._store.create_many(
+                    KIND_EVENT, events, return_objects=False
+                )
+            except Exception as err:  # noqa: BLE001 — full/closed store
+                results = [err] * len(events)
+            written = 0
+            degraded = 0
+            for evt, res in zip(events, results):
+                if not isinstance(res, BaseException):
+                    written += 1
+                    live.append((evt.metadata.namespace, evt.metadata.name))
+                elif isinstance(res, StorageDegraded):
+                    degraded += 1
+            counters.inc("events.batches")
+            counters.inc("events.written", written)
+            if degraded:
+                # an event shed to a degraded DISK is counted so an ENOSPC
+                # episode shows up in the recovery ledger, not as silence
+                counters.inc("storage.event_dropped_degraded", degraded)
 
-                    counters.inc("storage.event_dropped_degraded")
-            finally:
-                self._q.task_done()
+    def _trim(self, n: int) -> int:
+        """Delete the ``n`` oldest Event objects with one ``delete_many``;
+        returns how many went.  One already gone (store swapped/cleared)
+        is forgotten; one the store would not delete now stays first in
+        line for the next batch's trim."""
+        live = self._live
+        drop = [live.popleft() for _ in range(n)]
+        try:
+            results = self._store.delete_many(KIND_EVENT, drop)
+        except Exception as err:  # noqa: BLE001 — full/closed store
+            results = [err] * len(drop)
+        kept = [
+            key for key, res in zip(drop, results)
+            if isinstance(res, BaseException) and not isinstance(res, KeyError)
+        ]
+        live.extendleft(reversed(kept))
+        return results.count(None)
 
     def flush(self, timeout: float = 5.0) -> None:
-        """Block until every enqueued event has been written (bounded)."""
+        """Block until every event enqueued before the call has been
+        written (bounded)."""
         if self._store is None:
             return
         deadline = time.monotonic() + timeout
-        while self._q.unfinished_tasks:
-            if time.monotonic() > deadline:
-                return
-            time.sleep(0.01)
+        target = self._seq
+        with self._progress:
+            while self._landed < target:
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return
+                self._progress.wait(left)
 
     def close(self, timeout: float = 5.0) -> None:
         """Drain and terminate the writer thread.  Idempotent; eventf
         after close still records the in-process dict but its store write
         is silently dropped (the writer is gone) — callers close only on
         service teardown."""
-        if self._writer is None:
+        writer = self._writer
+        if writer is None:
             return
-        self.flush(timeout)
-        self._q.put(None)
-        self._writer.join(timeout=timeout)
+        with self._mu:
+            self._closing = True
+            self._q.put(None)  # behind everything enqueued: the writer ends there
+        writer.join(timeout=timeout)
         self._writer = None

@@ -1117,6 +1117,69 @@ class DurableObjectStore(ObjectStore):
 
         return self._gc_run(kind, build)
 
+    def delete_many(self, kind: str, keys: list) -> list:
+        """Batch delete, with ``create_many``'s discipline: one staged
+        entry through the group barrier (record before visibility, one
+        buffered write + one fsync, one batched fanout), the tombstones
+        reserved under the one short lock hold so a key named twice is
+        missing the second time.  Kill-switch mode is the deferred-fsync
+        contract (a record an item via _commit_record, the barrier lands
+        before the batched fanout)."""
+        if not self._gc_enabled:
+            with self._lock:
+                self._check_open()
+                self._check_wal_writable(kind)
+                self._defer_flush = True
+                try:
+                    return super().delete_many(kind, keys)
+                finally:
+                    self._defer_flush = False
+
+        def build():
+            out: list = []
+            frames: list = []
+            events: list = []
+            staged: list = []  # (key, token, old)
+            for namespace, name in keys:
+                key = f"{namespace}/{name}"
+                try:
+                    self._maybe_fault("delete", kind, key)
+                    old = self._gc_current(kind, key)
+                    if old is None:
+                        raise KeyError(f"{kind} {key!r} not found")
+                    rv = self._bump()
+                    frames.append(self._gc_frame_del(kind, old, rv))
+                    token = self._gc_reserve(kind, key, _GC_TOMB)
+                    self._node_agg_track(kind, old, None)
+                    staged.append((key, token, old))
+                    out.append(None)
+                    events.append(WatchEvent(EventType.DELETED, old, rv=rv))
+                except Exception as err:  # noqa: BLE001 — returned, not lost
+                    out.append(err)
+
+            def publish():
+                objs_map = self._objects.get(kind, {})
+                for key, token, _old in staged:
+                    objs_map.pop(key, None)
+                    self._gc_release(kind, key, token)
+                if events:
+                    self._gc_visible_rv = max(
+                        self._gc_visible_rv, events[-1].rv
+                    )
+                self._fanout_many(kind, events)
+
+            def undo():
+                for key, token, old in reversed(staged):
+                    self._gc_release(kind, key, token)
+                    self._node_agg_track(kind, None, old)
+
+            return _GroupEntry(
+                frames, publish, undo, out,
+                staged[0][0] if staged else "", kind,
+            )
+
+        return self._gc_run(kind, build)
+
     def restore_object(self, kind: str, obj: Any) -> None:
         # rare recovery/restore path with no concurrent traffic by
         # contract: a direct append under the IO lock (order io → store)

@@ -246,6 +246,20 @@ _TRANSIENT_ERRORS = (
 )
 
 
+def delete_each(store: Any, kind: str, keys: List[Any]) -> List[Any]:
+    """``delete_many`` for a store that deletes a key a request: a loop
+    over ``store.delete``, results aligned with ``keys`` as the
+    in-process stores answer — None, or that item's exception (404 is
+    KeyError) — so a caller has one call whatever store it was handed."""
+    out: List[Any] = []
+    for namespace, name in keys:
+        try:
+            out.append(store.delete(kind, namespace, name))
+        except Exception as err:  # noqa: BLE001 — returned, not lost
+            out.append(err)
+    return out
+
+
 class RemoteStore:
     """The ObjectStore surface the informers + engine consume, over REST.
 
@@ -825,6 +839,11 @@ class RemoteStore:
 
     def delete(self, kind: str, namespace: str, name: str) -> None:
         self._req("DELETE", self._path(kind, namespace, name))
+
+    def delete_many(self, kind: str, keys: List[Any]) -> List[Any]:
+        """The store's batch delete over the wire: a ``DELETE`` a key
+        (the façade has no batch DELETE); see ``delete_each``."""
+        return delete_each(self, kind, keys)
 
     def close(self) -> None:
         """Drop the pools' idle keep-alive sockets (open watch streams

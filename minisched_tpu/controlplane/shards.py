@@ -1157,6 +1157,13 @@ class ShardedStore:
             )
         )
 
+    def delete_many(self, kind: str, keys: List[Any]) -> List[Any]:
+        """Batch delete, routed a key at a time: each ``delete`` chases
+        its namespace's owner."""
+        from minisched_tpu.controlplane.remote import delete_each
+
+        return delete_each(self, kind, keys)
+
     def mutate(
         self,
         kind: str,

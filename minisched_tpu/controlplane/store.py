@@ -945,6 +945,45 @@ class ObjectStore:
             self._fanout(kind, WatchEvent(EventType.DELETED, old, rv=rv))
             self._cow_publish((kind,))
 
+    def delete_many(
+        self, kind: str, keys: List[Tuple[str, str]]
+    ) -> List[Any]:
+        """Batch delete under ONE lock hold — ``delete``'s twin as
+        ``create_many`` is ``create``'s.  ``keys``: (namespace, name)
+        pairs.  Returns a list aligned with ``keys``: None, or the
+        exception that item raised (KeyError for a missing key — a key
+        named twice is missing the second time) — one failed item never
+        aborts the rest.  The DELETED events carry rising rvs in the
+        order given; durability before visibility holds batch-wide
+        (every record lands, one flush, then ONE batched fanout and ONE
+        swap of the read plane — a ``delete`` a key copied the kind's
+        whole map a key)."""
+        out: List[Any] = []
+        events: List[WatchEvent] = []
+        with self._lock:
+            objs = self._objects.get(kind, {})
+            for namespace, name in keys:
+                key = f"{namespace}/{name}"
+                try:
+                    self._maybe_fault("delete", kind, key)
+                    old = objs.get(key)
+                    if old is None:
+                        raise KeyError(f"{kind} {key!r} not found")
+                    rv = self._bump()
+                    # durability before commit (see create): a refused
+                    # append fails THIS item only, leaving memory clean
+                    self._commit_record(kind, "del", old, rv)
+                    del objs[key]
+                    self._node_agg_track(kind, old, None)
+                    out.append(None)
+                    events.append(WatchEvent(EventType.DELETED, old, rv=rv))
+                except Exception as err:  # noqa: BLE001 — returned, not lost
+                    out.append(err)
+            self._flush_log()
+            self._fanout_many(kind, events)
+            self._cow_publish((kind,))
+        return out
+
     def mutate(
         self, kind: str, namespace: str, name: str, fn: Callable[[Any], Any]
     ) -> Any:

@@ -242,6 +242,15 @@ storage-integrity story under ``storage.`` — surfaced in the bench
         — HTTP 507 answers the remote client retried with backoff
     storage.event_dropped_degraded
         — volatile Events shed while the disk was full (best-effort)
+    events.written / events.trimmed / events.batches
+        — the events broadcaster's writer thread (controlplane/client
+          .EventRecorder): Event objects created, Event objects deleted
+          as the cap was passed, and batches landed (one ``delete_many``
+          + one ``create_many`` each, whatever the batch holds).
+          written ÷ batches is how many decisions share one store
+          transaction: 1 in a scenario, a score while the writer keeps
+          pace with a wave's commit, hundreds when it falls behind.
+          Registered at 0 when a recorder is given a store.
     storage.wal_corrupt_detected / storage.wal_salvaged
         — replay found a bad frame (bit-flip / torn mid-file write);
           salvage truncated at it because the checkpoint covered the

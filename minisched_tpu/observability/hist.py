@@ -157,6 +157,13 @@ a pod.  The lint in tests/test_telemetry.py holds every ``span(<name>)`` and
           children ``sched.permit`` (one span over a wave's reserve and
           permit chains; the default roster has neither) and
           ``sched.bind`` (the batched bind transaction, id ``n``)
+    events.write
+        — ``event-writer`` thread (``controlplane/client.EventRecorder``),
+          one batch of decisions landed as ``Event`` objects: the objects
+          built, one ``delete_many`` of what passes the cap, one
+          ``create_many``; id ``n`` (the batch's length).  On no layer's
+          path: its CPU is what the writer takes from the interpreter's
+          lock the engine's threads run under
     sched.scan_flush
         — loop thread, one flush of the deferred cross-pod lane, ids
           ``call``, ``n``; children ``sched.scan_grouping``,
