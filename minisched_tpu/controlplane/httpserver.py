@@ -32,7 +32,7 @@ import threading
 import time
 import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs
 
 from minisched_tpu.api.objects import Binding, Node, Pod
@@ -62,6 +62,7 @@ from minisched_tpu.observability import profiling
 profiling.register_spans(
     "http.create", "http.create_read", "http.create_decode",
     "http.create_store", "http.create_respond",
+    "http.delete", "http.delete_store", "http.delete_respond",
 )
 
 
@@ -226,8 +227,90 @@ class _WatchHTTPServer(ThreadingHTTPServer):
         super().shutdown_request(request)
 
 
+class _PendingDelete:
+    """One ``DELETE`` in the combiner's queue."""
+
+    __slots__ = ("kind", "key", "outcome", "done", "leads")
+
+    def __init__(self, kind: str, key: Tuple[str, str]) -> None:
+        self.kind, self.key = kind, key
+        self.outcome: Optional[BaseException] = None
+        #: set when a leader has landed it (None: it led from the start)
+        self.done: Optional[threading.Event] = None
+        self.leads = False
+
+
+class _DeleteCombiner:
+    """Single-object ``DELETE``s that arrive together land as ONE
+    ``store.delete_many`` a kind.
+
+    The API deletes one object a request, and every handler thread used
+    to take the store's lock for its own: beside a scheduler that shares
+    the interpreter, each hand-over of that lock waits for the
+    interpreter too, so deletes went through one at a time however many
+    connections sent them (27 ms each in ``store.delete`` for 1.2 ms of
+    CPU, PERF.md section 6, PR 35).  Here the first request to arrive
+    leads: it lands whatever has queued behind it (its own first) in one
+    transaction, wakes the requests it served, and hands the lead to the
+    next one waiting.  A request is answered only after its delete is
+    applied, with the outcome ``store.delete`` would have given it."""
+
+    def __init__(self, store: ObjectStore) -> None:
+        self._store = store
+        self._mu = threading.Lock()
+        self._queued: List[_PendingDelete] = []
+        self._led = False  # some request is leading
+
+    def delete(self, kind: str, namespace: str, name: str) -> None:
+        mine = _PendingDelete(kind, (namespace, name))
+        with self._mu:
+            self._queued.append(mine)
+            if self._led:
+                mine.done = threading.Event()
+            else:
+                self._led = mine.leads = True
+        if mine.done is not None:
+            mine.done.wait()
+        if mine.leads:
+            self._land()
+        if mine.outcome is not None:
+            raise mine.outcome
+
+    def _land(self) -> None:
+        """The leader's turn: one transaction a kind over everything
+        queued (the leader's own request is among it), then the lead
+        goes to whoever queued meanwhile, or to nobody."""
+        with self._mu:
+            batch, self._queued = self._queued, []
+        by_kind: Dict[str, List[_PendingDelete]] = {}
+        for item in batch:
+            by_kind.setdefault(item.kind, []).append(item)
+        for kind, items in by_kind.items():
+            try:
+                outcomes = self._store.delete_many(
+                    kind, [item.key for item in items]
+                )
+            except Exception as err:  # noqa: BLE001 — every request of
+                # the transaction answers it (NotLeader, StorageDegraded)
+                outcomes = [err] * len(items)
+            for item, outcome in zip(items, outcomes):
+                item.outcome = outcome
+        with self._mu:
+            heir = self._queued[0] if self._queued else None
+            if heir is None:
+                self._led = False
+            else:
+                heir.leads = True
+        for item in batch:
+            if item.done is not None:
+                item.done.set()
+        if heir is not None:
+            heir.done.set()
+
+
 class _Handler(BaseHTTPRequestHandler):
     store: ObjectStore = None  # set by start_api_server
+    deletes: _DeleteCombiner = None  # set by start_api_server
     active_watches = None  # set by start_api_server (set + lock)
     watch_lock = None
     faults = None  # optional faults.FaultFabric, set by start_api_server
@@ -294,8 +377,13 @@ class _Handler(BaseHTTPRequestHandler):
             # and bounds later reads with ?min_rv= so reads never go
             # backwards across an endpoint switch
             self.send_header("X-Minisched-RV", str(rv))
-        self.end_headers()
-        self.wfile.write(body)
+        # headers and body in ONE write: end_headers() puts the headers on
+        # the wire by themselves, and with Nagle on the body then waits
+        # for the client's delayed ACK — 40 ms a small answer on a
+        # keep-alive connection, which is every DELETE and every create
+        self._headers_buffer.append(b"\r\n")
+        self._headers_buffer.append(body)
+        self.flush_headers()
 
     def _body(self) -> Any:
         n = int(self.headers.get("Content-Length", 0))
@@ -1207,8 +1295,15 @@ class _Handler(BaseHTTPRequestHandler):
             kind, ns, name, _ = _route(self.path)
             if not self._shard_guard(kind, ns):
                 return
-            self.store.delete(kind, ns, name)
-            self._send(200, {})
+            # a pod's delete is a span as its create is: the store's
+            # share (the lock, the delete, the publish) apart from the
+            # answer's
+            span = profiling.span if kind == "Pod" else profiling.no_span
+            with span("http.delete", n=1):
+                with span("http.delete_store", n=1):
+                    self.deletes.delete(kind, ns, name)
+                with span("http.delete_respond", n=1):
+                    self._send(200, {})
         except NotLeader as e:
             self._error(503, str(e))
         except StorageDegraded as e:
@@ -1262,7 +1357,8 @@ def start_api_server(
     handler = type(
         "BoundHandler",
         (_Handler,),
-        {"store": store, "active_watches": set(),
+        {"store": store, "deletes": _DeleteCombiner(store),
+         "active_watches": set(),
          "watch_lock": threading.Lock(), "faults": faults,
          "ack_registry": acks, "ack_order": _deque(acks),
          "ack_lock": threading.Lock(), "stream_loop": stream_loop,

@@ -1326,6 +1326,7 @@ class DeviceScheduler(Scheduler):
                 retry.append(qpi)  # feasible; lost a same-node race
             else:
                 losers.append((qpi, qpi.pod, set()))
+        self._count_evaluated(len(live), len(losers))
         self._commit_winners(winners, "narrow" if narrow else "wide")
         # keep the next chunk's grouping/build gated: _bind_batch closes
         # the gate when it runs, but a chunk whose winners all parked in
@@ -1421,6 +1422,7 @@ class DeviceScheduler(Scheduler):
                     continue
                 self._assume(qpi.pod, node_names[c])
                 winners.append((qpi, qpi.pod, node_names[c]))
+            self._count_evaluated(len(part), len(losers))
             self._commit_winners(winners, "exact")
             # _bind_batch re-closed the gate; this lane stays ungated (the
             # next chunk's re-snapshot needs the bind events applied)
@@ -1744,6 +1746,7 @@ class DeviceScheduler(Scheduler):
                     cause="capacity_raced",
                 )
                 self.queue.add(pod, requeue=True)
+        self._count_evaluated(len(qpis), len(losers))
         self._commit_winners(winners, "wave")
         if losers:
             self._handle_wave_losers(
@@ -2257,6 +2260,16 @@ class DeviceScheduler(Scheduler):
             [unwrap(pl) for pl in self.score_plugins],
             reasons=canonical_filter_reasons(),
         )
+
+    @staticmethod
+    def _count_evaluated(evaluated: int, unschedulable: int) -> None:
+        """Once an evaluate, whichever lane ran it: the pods it was handed
+        and those it returned without a node (a feasible pod that lost a
+        capacity race inside a blocked call is neither: it retries)."""
+        from minisched_tpu.observability import counters
+
+        counters.inc("sched.evaluated_pods", evaluated)
+        counters.inc("sched.unschedulable_pods", unschedulable)
 
     def _commit_winners(self, winners: List[Any], lane: str) -> None:
         """Host-side tail of the wave for every placed pod: reserve →

@@ -1,7 +1,7 @@
 """Incremental assigned-pod aggregates for the cross-pod constraint planes.
 
 ``build_constraint_tables`` derives every assigned-pod plane (combo
-``here``/``global``/domain sums, the reverse anti-affinity terms, the
+``here``/``global``/domain sums, the reverse anti-affinity bans, the
 volume mount/family state) by walking the FULL assigned-pod population —
 O(cluster) host Python per wave.  That is the reference's own per-cycle
 re-list pattern one layer up (``minisched/minisched.go:40`` — SURVEY.md
@@ -22,7 +22,10 @@ Growth bound: the combo registry keeps every distinct (namespaces,
 selector, topology-key) group ever seen by a wave, and each assigned-pod
 event matches against every GROUP (selector-deduped).  Real rosters
 reuse a handful of selectors, so groups plateau; per-claim volume maps
-are pruned when their last pod leaves.
+are pruned when their last pod leaves, and so are the reverse
+anti-affinity owner values (one entry a (term, occupied domain): under a
+hostname key one for every occupied node, dropped when its last owner is
+deleted).
 
 Consistency model (same as the NodeInfo cache): the index is updated on
 the informer dispatch thread; reads see event-stream state plus the
@@ -48,19 +51,19 @@ import threading
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 from minisched_tpu.api.objects import LabelSelector
+from minisched_tpu.observability import counters
 
 # the ONE definition of combo/term identity — shared with the from-scratch
 # walk so the two paths cannot drift on key shape
 from minisched_tpu.models.constraints import (
     _matches,
     _selector_sig,
-    _term_namespaces,
+    rev_excl_terms_of,
+    rev_pref_terms_of,
 )
 
 #: combo key: (namespaces, selector signature, topology key)
 ComboKey = Tuple[Tuple[str, ...], Tuple, str]
-#: reverse anti-affinity term key: combo key + the owner's topo value
-ExKey = Tuple[Tuple[str, ...], Tuple, str, str]
 #: volume counting key: ("pv", volume_name) | ("pvc", claim_key) |
 #: ("miss", pod_uid, slot)
 VolKey = Tuple
@@ -91,7 +94,7 @@ class _PodRecord:
     without re-matching (labels may have changed since)."""
 
     __slots__ = (
-        "node", "sig", "ex_keys", "vols", "claims", "has_anti", "rev",
+        "node", "sig", "excl", "vols", "claims", "has_anti", "rev",
     )
 
     def __init__(self, node: str):
@@ -102,7 +105,9 @@ class _PodRecord:
         #: matching (per add and per new-combo backfill) runs against
         #: signatures instead of pods
         self.sig: int = -1
-        self.ex_keys: List[ExKey] = []
+        #: reverse required anti-affinity: (ComboKey, owner topo value) per
+        #: term of this assigned pod whose node carries the topology key
+        self.excl: List[Tuple[ComboKey, str]] = []
         #: (VolKey, family, rw) per mount — one entry per spec.volumes slot
         self.vols: List[Tuple[VolKey, int, bool]] = []
         #: referenced claim keys (for PVC/PV re-resolution)
@@ -150,9 +155,12 @@ class ConstraintIndex:
         self._sig_count: List[int] = []  # sig id → live records
         self._sig_key: List[Optional[Tuple]] = []  # sig id → _sig_ids key
         self._sig_free: List[int] = []  # recycled sig ids
-        # reverse anti-affinity: key → per-owner-node count
-        self._ex_terms: Dict[ExKey, Dict[str, int]] = {}
-        self._ex_sel: Dict[ExKey, LabelSelector] = {}
+        # reverse required anti-affinity: combo key → owner topo value →
+        # assigned pods whose term owns that domain (the shape of
+        # _rev_pref below: one entry a distinct TERM, its values dropped
+        # at zero, so nothing here outlives the pods that put it there)
+        self._rev_excl: Dict[ComboKey, Dict[str, int]] = {}
+        self._excl_sel: Dict[ComboKey, LabelSelector] = {}
         # symmetric preferred scoring: combo key → owner topo value →
         # Σ signed weight of assigned pods' terms owning that domain
         self._rev_pref: Dict[ComboKey, Dict[str, int]] = {}
@@ -238,7 +246,7 @@ class ConstraintIndex:
                     if not ev.obj.spec.node_name:
                         continue
                     if ev.type == EventType.DELETED:
-                        self._remove(ev.obj.metadata.uid)
+                        self._delete(ev.obj.metadata.uid)
                     elif ev.type == EventType.ADDED:
                         self._add(ev.obj)
                     else:
@@ -260,7 +268,15 @@ class ConstraintIndex:
 
     def delete_pod(self, pod: Any) -> None:
         with self._mu:
-            self._remove(pod.metadata.uid)
+            self._delete(pod.metadata.uid)
+
+    def _delete(self, uid: str) -> None:
+        """An assigned pod went away (not a re-resolution, which removes
+        and adds again): ``constraint_index.pods_removed`` counts those
+        the index held."""
+        if uid in self._records:
+            counters.inc("constraint_index.pods_removed")
+        self._remove(uid)
 
     def update_node(self, old: Any, new: Any) -> None:
         """A node's labels feed the reverse anti-affinity owner domains —
@@ -318,30 +334,22 @@ class ConstraintIndex:
         from minisched_tpu.plugins.volumelimits import volume_family
 
         rec = _PodRecord(pod.spec.node_name)
-        aff = pod.spec.affinity
-        if (
-            aff is not None
-            and aff.pod_anti_affinity is not None
-            and aff.pod_anti_affinity.required
-        ):
-            rec.has_anti = True
-            # the owner's CURRENT node labels give the term's domain value
-            owner_labels = self._node_labels(pod.spec.node_name)
-            for term in aff.pod_anti_affinity.required:
-                owner_val = owner_labels.get(term.topology_key)
-                if owner_val is None:
-                    continue  # owner's node lacks the key: can't be violated
-                nss = _term_namespaces(term, pod.metadata.namespace)
-                key = (nss, _selector_sig(term.label_selector),
-                       term.topology_key, owner_val)
-                self._ex_sel.setdefault(key, term.label_selector)
-                rec.ex_keys.append(key)
-        # symmetric preferred/hard-affinity contributions (the terms this
-        # ASSIGNED pod scores toward future incoming pods) — ONE term
-        # stream shared with the from-scratch walk
-        from minisched_tpu.models.constraints import rev_pref_terms_of
-
+        # reverse required anti-affinity and symmetric preferred/hard-
+        # affinity contributions (what this ASSIGNED pod bans and scores
+        # toward future incoming pods) — term streams shared with the
+        # from-scratch walk
         owner_labels = None
+        for nss, sel, topo in rev_excl_terms_of(pod):
+            rec.has_anti = True
+            if owner_labels is None:
+                # the owner's CURRENT node labels give the term's domain
+                owner_labels = self._node_labels(pod.spec.node_name)
+            owner_val = owner_labels.get(topo)
+            if owner_val is None:
+                continue  # owner's node lacks the key: can't be violated
+            ck = (nss, _selector_sig(sel), topo)
+            self._excl_sel.setdefault(ck, sel)
+            rec.excl.append((ck, owner_val))
         for nss, sel, topo, w in rev_pref_terms_of(pod):
             # node-label-sensitive either way: a label change can grant or
             # revoke the owner's topology key — re-resolve on node events
@@ -452,9 +460,9 @@ class ConstraintIndex:
         sn = self._sig_nodes[rec.sig]
         sn[node] = sn.get(node, 0) + 1
         self._sig_count[rec.sig] += 1
-        for key in rec.ex_keys:
-            owners = self._ex_terms.setdefault(key, {})
-            owners[node] = owners.get(node, 0) + 1
+        for ck, owner_val in rec.excl:
+            vals = self._rev_excl.setdefault(ck, {})
+            vals[owner_val] = vals.get(owner_val, 0) + 1
         for ck, owner_val, w in rec.rev:
             vals = self._rev_pref.setdefault(ck, {})
             vals[owner_val] = vals.get(owner_val, 0) + w
@@ -495,14 +503,17 @@ class ConstraintIndex:
         else:
             sn[node] = left
         self._sig_release(rec.sig)
-        for key in rec.ex_keys:
-            owners = self._ex_terms.get(key)
-            if owners is not None:
-                n = owners.get(node, 0) - 1
-                if n <= 0:
-                    owners.pop(node, None)
+        for ck, owner_val in rec.excl:
+            vals = self._rev_excl.get(ck)
+            if vals is not None:
+                left = vals.get(owner_val, 0) - 1
+                if left <= 0:
+                    vals.pop(owner_val, None)
+                    if not vals:
+                        self._rev_excl.pop(ck, None)
+                        self._excl_sel.pop(ck, None)
                 else:
-                    owners[node] = n
+                    vals[owner_val] = left
         for ck, owner_val, w in rec.rev:
             vals = self._rev_pref.get(ck)
             if vals is not None:
@@ -545,6 +556,8 @@ class ConstraintIndex:
             anti = self._node_anti.get(node)
             if anti is not None:
                 anti.discard(uid)
+                if not anti:
+                    del self._node_anti[node]
 
     # -- reads (wave assembly) ---------------------------------------------
     def combo_aggregate(
@@ -606,13 +619,14 @@ class ConstraintIndex:
         with self._mu:
             return set(self._records)
 
-    def ex_term_list(self) -> List[Tuple[ExKey, LabelSelector, Set[str]]]:
-        """Live reverse anti-affinity terms: (key, selector, owner nodes)."""
+    def rev_excl_list(self) -> List[Tuple[ComboKey, LabelSelector, Dict[str, int]]]:
+        """Live reverse required anti-affinity: (combo key, selector,
+        owner-topo-value → owners) — one entry a distinct term, whatever
+        the cluster holds."""
         with self._mu:
             return [
-                (key, self._ex_sel[key], set(owners))
-                for key, owners in self._ex_terms.items()
-                if owners
+                (ck, self._excl_sel[ck], dict(vals))
+                for ck, vals in self._rev_excl.items()
             ]
 
     def rev_pref_list(self) -> List[Tuple[ComboKey, LabelSelector, Dict[str, int]]]:

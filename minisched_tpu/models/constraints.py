@@ -9,12 +9,18 @@ TPU-native factoring separates the two halves:
   topology-key) triple appearing in the wave's constraints becomes a
   **combo**; assigned pods are matched against each combo ONCE, and the
   per-node domain sums land in a dense ``combo_dsum[C, N]`` matrix.  The
-  reverse direction (assigned pods' required anti-affinity) becomes a
-  ``pod_matches_ex[P, T] × ex_domain[T, N]`` pair.
+  reverse direction (assigned pods' required anti-affinity) is a combo
+  too: the term's (namespaces, selector, topology key) is the row, and
+  ``combo_excl[C, N]`` is set over the domains its owners occupy — the
+  plane the sequential scan already carries for the pods it commits, so
+  placed owners and owners committed a step ago ban through one matmul
+  (``pod_matches_combo @ combo_excl``) and no axis follows how many
+  nodes are occupied.
 
 * **Device side** (plugins/interpodaffinity.py, podtopologyspread.py):
   kernels only gather combo rows and reduce — no string or object work.
-  The reverse anti-affinity check is one bool matmul (MXU-shaped).
+  The reverse anti-affinity check is one bool matmul over the combo axis
+  (MXU-shaped).
 
 Semantics follow upstream v1.22 ``interpodaffinity`` / ``podtopologyspread``
 (the reference's default roster enables both — scheduler_test.go:307-332),
@@ -74,7 +80,7 @@ SCAN_ELIDE_GROUPS = (
     ("ts_combo", "ts_skew", "ts_mode", "ts_n"),
 )
 
-#: smallest capacity of the combo/ex-term/claim/volume axes — every
+#: smallest capacity of the combo/claim/volume axes — every
 #: distinct padded size is a separate compiled executable (see the combo
 #: matrices comment in build_constraint_tables)
 CAP_QUANTUM = 32
@@ -131,21 +137,20 @@ class ConstraintTables:
     ppa_combo: Any  # i32[P, MAX_PPA]
     ppa_w: Any  # i32[P, MAX_PPA]
     ppa_n: Any  # i32[P]
-    # reverse direction: assigned pods' required anti-affinity terms
-    ex_domain: Any  # bool[T, N] nodes in the owning pod's topo domain
-    pod_matches_ex: Any  # bool[P, T] pending pod matches term selector
     # symmetric preferred scoring (upstream v1.22 interpodaffinity
     # PreScore): assigned pods' preferred affinity (+w) / anti-affinity
     # (−w) terms and required affinity terms (×HARD_POD_AFFINITY_WEIGHT),
     # accumulated as signed weight over the owner's topology domain per
     # combo.  Scored as pod_matches_combo @ rev_weight (one int matmul).
     rev_weight: Any  # i32[C, N] Σ signed term weights whose domain holds n
-    # sequential-scan support (ops/sequential.py): which pending pods match
-    # each combo's selector — commits update the combo aggregates with it —
-    # and the exclusion plane accumulated from committed pods' required
-    # anti-affinity terms (all-False outside the scan)
+    # which pending pods match each combo's selector (the sequential
+    # scan's commits update the combo aggregates with it; a wave matches
+    # only the combos the two reverse planes use), and the reverse
+    # direction of required anti-affinity: the domains that assigned
+    # pods' terms own, to which the scan (ops/sequential.py) adds those
+    # of the pods it commits
     pod_matches_combo: Any  # bool[P, C]
-    combo_excl: Any  # bool[C, N] matching pods banned (committed pod's
+    combo_excl: Any  # bool[C, N] matching pods banned (an owner's
     #                  anti-affinity domain)
     # volume coupling (VolumeBinding / NodeVolumeLimits)
     claim_mask: Any  # bool[C2, N] nodes OK for referenced claim c (bound
@@ -199,8 +204,6 @@ CONSTRAINT_AXES = {
     "topo_domain": ("last", "nodes"),
     "topo_onehot": ("last", "nodes"),
     "topo_unique": ("rep", None),
-    "ex_domain": ("last", "nodes"),
-    "pod_matches_ex": ("first", "pods"),
     "rev_weight": ("last", "nodes"),
     "pod_matches_combo": ("first", "pods"),
     "combo_excl": ("last", "nodes"),
@@ -281,6 +284,19 @@ def rev_pref_terms_of(p: Any):
                 _term_namespaces(wt.term, ns), wt.term.label_selector,
                 wt.term.topology_key, -wt.weight,
             )
+
+
+def rev_excl_terms_of(p: Any):
+    """The (namespaces, selector, topology-key) stream of an ASSIGNED
+    pod's required anti-affinity terms: what it bans from its own
+    topology domains (the reverse direction of the filter).  Shared by
+    the from-scratch walk and the incremental index."""
+    aff = p.spec.affinity
+    if aff is None or aff.pod_anti_affinity is None:
+        return
+    ns = p.metadata.namespace
+    for term in aff.pod_anti_affinity.required:
+        yield _term_namespaces(term, ns), term.label_selector, term.topology_key
 
 
 def _selector_sig(sel: LabelSelector) -> Tuple:
@@ -510,36 +526,54 @@ def build_constraint_tables(
                 )
         pod_rows.append((pi, row))
 
-    # --- symmetric preferred contributions (assigned pods' terms) ----------
-    # cid → topology value → Σ signed weight; combos register here too, so
-    # C covers them before the matrices are allocated
-    rev_vals: Dict[int, Dict[str, int]] = {}
+    # --- reverse contributions (assigned pods' terms) ----------------------
+    # symmetric preferred scoring: term → topology value → Σ signed weight;
+    # reverse required anti-affinity: term → topology value → owners.
+    # Their combos register here too, in the order of their keys (the
+    # from-scratch walk and the index meet them in different orders), so C
+    # covers them before the matrices are allocated: one row a distinct
+    # TERM, whatever the cluster holds
+    rev_by_key: Dict[Tuple, Tuple[LabelSelector, Dict[str, int]]] = {}
+    excl_by_key: Dict[Tuple, Tuple[LabelSelector, Dict[str, int]]] = {}
+
+    def _note(by_key, key: Tuple, sel: LabelSelector, val: str, w: int):
+        ent = by_key.get(key)
+        if ent is None:
+            ent = by_key[key] = (sel, {})
+        ent[1][val] = ent[1].get(val, 0) + w
 
     def _collect_rev(p: Any) -> None:
         labels = nodes[node_idx[p.spec.node_name]].metadata.labels
         for nss, sel, topo, w in rev_pref_terms_of(p):
             val = labels.get(topo)
-            if val is None:
-                continue  # owner's node lacks the key: no domain to score
-            cid = reg.get(nss, sel, topo)
-            vals = rev_vals.setdefault(cid, {})
-            vals[val] = vals.get(val, 0) + w
+            if val is not None:  # else no domain to score
+                _note(rev_by_key, (nss, _selector_sig(sel), topo), sel, val, w)
+        for nss, sel, topo in rev_excl_terms_of(p):
+            val = labels.get(topo)
+            if val is not None:  # else the term can't be violated
+                _note(excl_by_key, (nss, _selector_sig(sel), topo), sel, val, 1)
 
     if index is not None:
-        for key, sel_obj, vals in index.rev_pref_list():
-            nss_k, _sig, topo_k = key
-            cid = reg.get(nss_k, sel_obj, topo_k)
-            dst = rev_vals.setdefault(cid, {})
-            for val, w in vals.items():
-                dst[val] = dst.get(val, 0) + w
+        for src, by_key in (
+            (index.rev_pref_list(), rev_by_key),
+            (index.rev_excl_list(), excl_by_key),
+        ):
+            for key, sel_obj, vals in src:
+                by_key[key] = (sel_obj, vals)  # each key once, a copy
         for p in extra_assigned:
             _collect_rev(p)
     else:
         for p in assigned:
             _collect_rev(p)
+    rev_vals: Dict[int, Dict[str, int]] = {}  # cid → value → Σ weight
+    excl_vals: Dict[int, Dict[str, int]] = {}  # cid → value → owners
+    for by_key, by_cid in ((rev_by_key, rev_vals), (excl_by_key, excl_vals)):
+        for key in sorted(by_key, key=repr):
+            sel, vals = by_key[key]
+            by_cid[reg.get(key[0], sel, key[2])] = vals
 
     # --- combo matrices ----------------------------------------------------
-    # C/T/C2/Vd are EXECUTABLE shapes — a build whose combo count steps
+    # C/C2/Vd are EXECUTABLE shapes — a build whose combo count steps
     # over a capacity recompiles the whole evaluator mid-run.  cap_tier
     # keeps one shape up to 32 combos and one more for every eightfold
     # growth, at the cost of spare (all-zero) planes.
@@ -567,10 +601,12 @@ def build_constraint_tables(
     rev_weight = np.zeros((C, N), np.int32)
     # scan mode matches every combo (commits update aggregates with it);
     # wave mode matches only the rev-active combos — the symmetric score
-    # needs "does this pending pod match the assigned pod's term", and a
-    # wave over a cluster with no such terms pays nothing
+    # and the reverse anti-affinity ban need "does this pending pod match
+    # the assigned pod's term", and a wave over a cluster with no such
+    # terms pays nothing
     match_combos = (
-        range(len(reg.combos)) if scan_planes else sorted(rev_vals)
+        range(len(reg.combos)) if scan_planes
+        else sorted(rev_vals.keys() | excl_vals.keys())
     )
     if match_combos:
         # combos sharing (namespaces, selector) across topology keys match
@@ -641,10 +677,11 @@ def build_constraint_tables(
                     if val is not None:
                         domain_count[val] = domain_count.get(val, 0) + cnt
             combo_global[cid] = total
-        # haskey/dsum/rev rows as gathers through the node→value-id axis
-        # (a per-combo × per-node Python loop here cost ~1s per scan chunk
-        # at 32 combos × 10k nodes)
+        # haskey/dsum/rev/excl rows as gathers through the node→value-id
+        # axis (a per-combo × per-node Python loop here cost ~1s per scan
+        # chunk at 32 combos × 10k nodes)
         rv = rev_vals.get(cid)
+        ev = excl_vals.get(cid)
         vid = val_id_[k, :n_real]  # (n_real,) value id, -1 absent
         has = vid >= 0
         combo_haskey[cid, :n_real] = has
@@ -664,52 +701,18 @@ def build_constraint_tables(
                 if vi is not None:
                     rw_by_vid[vi] = w
             rev_weight[cid, :n_real] = np.where(has, rw_by_vid[safe_vid], 0)
-
-    # --- reverse anti-affinity terms (deduped: replicas sharing one term
-    # and one topology domain collapse to a single row) --------------------
-    ex_ids: Dict[Tuple, int] = {}
-    ex_terms: List[Tuple[Tuple[str, ...], LabelSelector, str, str]] = []
-
-    def _add_ex_terms_of(p: Any) -> None:
-        aff = p.spec.affinity
-        if aff is None or aff.pod_anti_affinity is None:
-            return
-        for term in aff.pod_anti_affinity.required:
-            owner_val = nodes[node_idx[p.spec.node_name]].metadata.labels.get(
-                term.topology_key
-            )
-            if owner_val is None:
-                continue  # owner's node lacks the key: term can't be violated
-            nss = _term_namespaces(term, p.metadata.namespace)
-            key = (nss, _selector_sig(term.label_selector), term.topology_key,
-                   owner_val)
-            if key not in ex_ids:
-                ex_ids[key] = len(ex_terms)
-                ex_terms.append(
-                    (nss, term.label_selector, term.topology_key, owner_val)
-                )
-
-    if index is not None:
-        for key, sel_obj, owner_nodes in index.ex_term_list():
-            if key in ex_ids or not any(n in node_idx for n in owner_nodes):
-                continue
-            nss_k, _sig, topo_k, owner_val = key
-            ex_ids[key] = len(ex_terms)
-            ex_terms.append((nss_k, sel_obj, topo_k, owner_val))
-        for p in extra_assigned:
-            _add_ex_terms_of(p)
-    else:
-        for p in assigned:
-            _add_ex_terms_of(p)
-    T = cap_tier(len(ex_terms))
-    ex_domain = np.zeros((T, N), bool)
-    pod_matches_ex = np.zeros((P, T), bool)
-    for t, (nss, sel, topo, owner_val) in enumerate(ex_terms):
-        for i, node in enumerate(nodes):
-            if node.metadata.labels.get(topo) == owner_val:
-                ex_domain[t, i] = True
-        for i, pod in enumerate(pending_pods):
-            pod_matches_ex[i, t] = _matches(sel, nss, pod)
+        if ev:
+            ban_by_vid = np.zeros(max(len(vals_k), 1), bool)
+            for val in ev:
+                vi = vals_k.get(val)
+                if vi is not None:
+                    ban_by_vid[vi] = True
+            combo_excl[cid, :n_real] = has & ban_by_vid[safe_vid]
+    if scan_planes:
+        # how much of the cluster the reverse direction bans
+        counters.inc("scan.excl_terms", len(excl_vals))
+        counters.inc("scan.excl_nodes", int(combo_excl.sum()))
+        counters.inc("scan.excl_capacity", len(excl_vals) * n_real)
 
     # --- volume coupling ---------------------------------------------------
     # feasibility semantics come from ONE place each — the VolumeBinding /
@@ -913,7 +916,6 @@ def build_constraint_tables(
             pa_combo=pa_combo, pa_self=pa_self, pa_n=pa_n,
             pan_combo=pan_combo, pan_n=pan_n,
             ppa_combo=ppa_combo, ppa_w=ppa_w, ppa_n=ppa_n,
-            ex_domain=ex_domain, pod_matches_ex=pod_matches_ex,
             pod_matches_combo=pod_matches_combo, combo_excl=combo_excl,
             rev_weight=rev_weight,
             claim_mask=claim_mask, pod_claims=pod_claims, vol_ok=vol_ok,

@@ -169,6 +169,18 @@ each retry round of it):
           (models/constraints.cap_tier: 32, 256, 2048, ...); live ÷
           total is how full the combo planes a step reads are
           (benchmark metric ``scan.combo_fill_share``)
+    scan.excl_terms / scan.excl_nodes / scan.excl_capacity
+        — once a constraint build of the scan lanes: the distinct
+          reverse required anti-affinity terms of the pods that are
+          placed (a combo row each, whatever the cluster holds: 1 where
+          every pod carries the one term), the nodes their owners'
+          domains ban (true cells of ``combo_excl``), and terms × real
+          nodes; nodes ÷ capacity is how much of the cluster the reverse
+          direction bans (benchmark metric ``scan.excl_nodes_share``)
+    constraint_index.pods_removed
+        — assigned pods the constraint index dropped on a DELETED event
+          (models/constraint_index: their counts, owner values and
+          volume mounts go with them)
 
 The device engine counts the pods each of its programs placed, as they
 are handed to the commit (engine/device_scheduler ``_commit_winners``):
@@ -176,8 +188,13 @@ are handed to the commit (engine/device_scheduler ``_commit_winners``):
     sched.lane_pods.wave / .wide / .narrow / .exact
         — the packed repair wave, the blocked scan's wide (32 rows a
           step) and narrow (a pod a step) layouts, the exact per-pod
-          scan.  All of these and the ``scan.*`` counters above are
-          registered at 0 when a device engine is constructed
+          scan
+    sched.evaluated_pods / sched.unschedulable_pods
+        — once an evaluate of any of those lanes: the pods it was handed,
+          and those it returned without a node (benchmark metric
+          ``queue.unschedulable_share``: 0 while a cluster has room).
+          All of these and the ``scan.*`` counters above are registered
+          at 0 when a device engine is constructed
 
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
@@ -602,6 +619,9 @@ LANE_COUNTERS = (
     "scan.combos_live", "scan.combos_total",
     "sched.lane_pods.wave", "sched.lane_pods.wide",
     "sched.lane_pods.narrow", "sched.lane_pods.exact",
+    "scan.excl_terms", "scan.excl_nodes", "scan.excl_capacity",
+    "sched.evaluated_pods", "sched.unschedulable_pods",
+    "constraint_index.pods_removed",
 )
 
 

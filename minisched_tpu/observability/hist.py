@@ -115,6 +115,13 @@ a pod.  The lint in tests/test_telemetry.py holds every ``span(<name>)`` and
           ``http.create_decode`` (dicts → objects),
           ``http.create_store`` (the store transaction and its fanout),
           ``http.create_respond`` (encode + socket write)
+    http.delete
+        — façade handler thread, one pod ``DELETE`` (one object a
+          request; other kinds open none), id ``n``; children:
+          ``http.delete_store`` (the wait for a leader's transaction
+          or the lead of one: the store's lock, the deletes that queued
+          together, one publish of the read plane — ``_DeleteCombiner``),
+          ``http.delete_respond`` (the answer's socket write)
     watch.deliver
         — stream-loop thread, one drained batch of watch events for one
           stream: encode into the out-buffer + socket write; id ``n``

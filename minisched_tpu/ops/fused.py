@@ -47,10 +47,6 @@ class BatchContext:
     """
 
     weights: Tuple[Tuple[str, int], ...] = ()
-    #: True only inside the sequential scan (ops/sequential.py): kernels
-    #: whose in-scan terms are statically zero elsewhere (InterPodAffinity's
-    #: combo_excl matmul) compile them only when set
-    in_scan: bool = False
 
     def weight_of(self, name: str) -> int:
         for n, w in self.weights:

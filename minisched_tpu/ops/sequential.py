@@ -19,8 +19,9 @@ the scan:
   for every combo whose selector it matches (``pod_matches_combo``,
   host-precomputed), with the domain mask derived on device from the
   topo-key planes.  Its required anti-affinity terms accumulate into
-  ``combo_excl``, which the affinity filter applies to later pods — the
-  in-scan version of the reverse-direction check.
+  ``combo_excl``, which arrives holding the domains of the owners placed
+  before the build and which the affinity filter applies to later pods —
+  the reverse-direction check, one plane for both.
 * **volume planes** (VolumeRestrictions / limit family / VolumeBinding):
   the committed pod's mounts update ``vol_any`` / ``vol_rw`` /
   ``node_vols_fam`` exactly like the repair loop's commit step.
@@ -651,7 +652,7 @@ class BlockedSequentialScheduler:
 
         validate_batch_chains(filter_plugins, pre_score_plugins, score_plugins)
         ctx = BatchContext(
-            weights=tuple(sorted((weights or {}).items())), in_scan=True
+            weights=tuple(sorted((weights or {}).items()))
         )
         self._chains = (tuple(filter_plugins), tuple(pre_score_plugins),
                         tuple(score_plugins))
@@ -720,7 +721,7 @@ class SequentialScheduler:
 
         validate_batch_chains(filter_plugins, pre_score_plugins, score_plugins)
         ctx = BatchContext(
-            weights=tuple(sorted((weights or {}).items())), in_scan=True
+            weights=tuple(sorted((weights or {}).items()))
         )
         self._chains = (tuple(filter_plugins), tuple(pre_score_plugins),
                         tuple(score_plugins))

@@ -22,9 +22,10 @@ follow upstream v1.22:
   normalizes to [0, 100].
 
 Batch form (models/constraints.py): gathers of ``combo_dsum`` rows, one
-bool matmul for the reverse required-anti direction, and one int matmul
+bool matmul for the reverse required-anti direction
+(``pod_matches_combo @ combo_excl``), and one int matmul
 (``pod_matches_combo @ rev_weight``) for the symmetric scoring — all
-MXU-shaped at scale.
+MXU-shaped at scale, both over the combo axis.
 """
 
 from __future__ import annotations
@@ -215,28 +216,19 @@ class InterPodAffinity(Plugin, BatchEvaluable):
                 "InterPodAffinity batch kernels need the wave's "
                 "ConstraintTables (models/constraints.py) — pass `extra`"
             )
-        # reverse direction: one bool matmul over the existing-term axis
+        # reverse direction: one bool matmul over the combo axis.  A row
+        # of combo_excl is the domains owned by the required anti-affinity
+        # terms of the pods that are placed, and of those committed
+        # EARLIER IN THIS SCAN (the sequential engine adds them as it
+        # goes); all-zero, and folded, where no such term is about
         rev = (
             jnp.einsum(
-                "pt,tn->pn",
-                extra.pod_matches_ex.astype(jnp.int32),
-                extra.ex_domain.astype(jnp.int32),
+                "pc,cn->pn",
+                extra.pod_matches_combo.astype(jnp.int32),
+                extra.combo_excl.astype(jnp.int32),
             )
             > 0
         )  # (P, N)
-        # same check against pods committed EARLIER IN THIS SCAN: the
-        # sequential engine accumulates their anti-affinity domains into
-        # combo_excl.  Statically all-False outside the scan — the matmul
-        # only compiles when the scan context sets in_scan
-        if getattr(ctx, "in_scan", False):
-            rev = rev | (
-                jnp.einsum(
-                    "pc,cn->pn",
-                    extra.pod_matches_combo.astype(jnp.int32),
-                    extra.combo_excl.astype(jnp.int32),
-                )
-                > 0
-            )
 
         # incoming required anti-affinity
         pan_in = (

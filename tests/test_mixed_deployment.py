@@ -294,7 +294,7 @@ def test_the_lane_counters_stand_at_zero_from_the_engines_construction():
     from minisched_tpu.service.config import default_full_roster_config
     from minisched_tpu.service.service import SchedulerService
 
-    assert len(counters.LANE_COUNTERS) == 7 + len(LANES)
+    assert len(counters.LANE_COUNTERS) == 13 + len(LANES)
     assert {"sched.lane_pods." + lane for lane in LANES} <= set(counters.LANE_COUNTERS)
     fresh = counters.Counters()
     real, counters.GLOBAL = counters.GLOBAL, fresh

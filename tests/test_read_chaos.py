@@ -113,7 +113,17 @@ def test_every_replica_serves_bounded_reads(tmp_path):
 
         # watch on a follower BEFORE the next writes: live fanout proof
         frs = RemoteStore(followers[0].base_url, timeout_s=10.0)
-        w, snap = frs.watch("Pod", resume_rv=rv)
+        # a follower that has not applied ``rv`` yet answers the typed,
+        # retryable 504 (the leader's acks no longer wait 44 ms each for
+        # a delayed ACK on this loopback, PR 35: the follower may trail)
+        deadline = time.monotonic() + 10.0
+        while True:
+            try:
+                w, snap = frs.watch("Pod", resume_rv=rv)
+                break
+            except NotYetObserved:
+                assert time.monotonic() < deadline, "follower never caught up"
+                time.sleep(0.05)
         assert snap == []
 
         for r in plane.replicas:

@@ -73,7 +73,7 @@ def test_constraint_capacities_stable_under_growth():
         [_spread_pod(f"p{i}", f"app{i}") for i in range(20)], nodes, [],
         pod_capacity=np.asarray(one.ts_combo).shape[0],
     )
-    for field in ("combo_dsum", "combo_here", "ex_domain", "claim_mask",
+    for field in ("combo_dsum", "combo_here", "combo_excl", "claim_mask",
                   "vol_any", "topo_domain", "topo_onehot"):
         assert (
             np.asarray(getattr(one, field)).shape
