@@ -110,8 +110,13 @@ class Informer:
         # host-side stretch of a wave (snapshot/table build) so handler
         # work for the previous wave's thousands of bind events lands in
         # the GIL-free device-call window instead of contending with the
-        # engine's own Python.  Soft pause: the timed wait bounds how long
-        # a forgotten gate can stall the stream.
+        # engine's own Python.  A bind clears it only where such a stretch
+        # is coming (DeviceScheduler._bind_batch): ready pods in the
+        # queue, a batch the build worker holds, or scan work (a backlog,
+        # or a lane running); with none, the gate stays as it was, since a
+        # closed gate would hold the very pods that bring the next wave.
+        # Soft pause: the timed wait bounds how long a forgotten gate can
+        # stall the stream (``informer.gate_wait_expired`` counts those).
         self._gate = threading.Event()
         self._gate.set()
         #: degraded-mode gauges: how many times the watch died and was
@@ -344,7 +349,8 @@ class Informer:
                 with profiling.span(
                     "informer.gate_wait", kind=self._kind, n=len(batch)
                 ):
-                    self._gate.wait(timeout=2.0)
+                    if not self._gate.wait(timeout=2.0):
+                        counters.inc("informer.gate_wait_expired")
             if not batch:
                 if self._watch.stopped:
                     if self._stop.is_set() or not self._reconnect():

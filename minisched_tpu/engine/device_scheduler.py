@@ -217,11 +217,14 @@ class DeviceScheduler(Scheduler):
         # (the lane's exactness contract) is unchanged.
         self._scan_backlog: List[QueuedPodInfo] = []
         self._scan_backlog_waves = 0  # full waves survived since first defer
+        #: a scan lane is running (_schedule_scan): its calls are the next
+        #: host stretch a bind's dispatch gate protects
+        self._scan_running = False
         #: the scan lanes' combo capacity so far (_build_constraints)
         self._scan_combo_cap = 0
         from minisched_tpu.observability import counters
 
-        for name in counters.LANE_COUNTERS:
+        for name in counters.LANE_COUNTERS + counters.GATE_COUNTERS:
             counters.inc(name, 0)
         # assume-pod cache (upstream's scheduler cache AssumePod): a placed
         # pod counts against its node IMMEDIATELY, before the async bind
@@ -1141,15 +1144,21 @@ class DeviceScheduler(Scheduler):
         """The cross-pod lane: blocked scan for throughput (disjoint
         interaction groups per kernel step), exact per-pod scan for the
         remainder and as the configured fallback."""
-        if (
-            self.SCAN_BLOCK_SIZE > 1
-            and len(qpis) > self.SCAN_BLOCK_SIZE
-        ):
-            self._schedule_scan_blocked(
+        self._scan_running = True
+        try:
+            if (
+                self.SCAN_BLOCK_SIZE > 1
+                and len(qpis) > self.SCAN_BLOCK_SIZE
+            ):
+                self._schedule_scan_blocked(
+                    qpis, node_infos, agg_delta, assumed_pods
+                )
+                return
+            self._schedule_scan_exact(
                 qpis, node_infos, agg_delta, assumed_pods
             )
-            return
-        self._schedule_scan_exact(qpis, node_infos, agg_delta, assumed_pods)
+        finally:
+            self._scan_running = False
 
     def _schedule_scan_blocked(
         self,
@@ -2385,6 +2394,18 @@ class DeviceScheduler(Scheduler):
             ready.append((qpi, pod, node_name, state))
         return ready
 
+    def _next_stretch_pending(self) -> bool:
+        """A next host stretch is there for a closed dispatch gate to
+        protect (see _bind_batch): ready pods in the queue, a batch the
+        build worker holds, or scan work."""
+        pipe = self._pipeline
+        return (
+            self.queue.ready_count() > 0
+            or (pipe is not None and pipe.holds_batch)
+            or bool(self._scan_backlog)
+            or self._scan_running
+        )
+
     def _bind_batch(
         self, ready: List[Any], lane: Optional[str] = None
     ) -> None:
@@ -2393,6 +2414,7 @@ class DeviceScheduler(Scheduler):
         (labeled ``lane``: the program that placed them) and one trace
         record each, all stamped at the instant ``bind_many`` returned."""
         from minisched_tpu.api.objects import Binding
+        from minisched_tpu.observability import counters
 
         # expected_rv: the optimistic-concurrency precondition — bind only
         # if the pod is STILL at the version this wave evaluated (a spec
@@ -2408,15 +2430,26 @@ class DeviceScheduler(Scheduler):
             )
             for _, pod, node_name, _ in ready
         ]
-        # close the dispatch gate BEFORE the events fan out: the informer
-        # threads then hold this wave's thousands of bind events through
-        # the next wave's host stretch (pop/snapshot/build) and process
-        # them inside its GIL-free device call — _finish_wave reopens
-        # the gate, schedule_one reopens it when the queue idles.
-        # The handler work is identical either way (the assume-cache
-        # carries placements until the events land); only WHEN it contends
-        # for the GIL changes.
-        self.informer_factory.pause_dispatch()
+        # the dispatch gate closes BEFORE the events fan out, and only
+        # where a next host stretch is there for the hold to protect:
+        # ready pods in the queue, a batch the build worker holds
+        # (building, or built and waiting in the handoff), or scan work (a
+        # backlog, or a lane running).  The informer threads then hold
+        # this wave's bind events through that stretch (pop/snapshot/
+        # build) and process them inside its GIL-free device call, which
+        # reopens the gate (_finish_wave, the scan lanes).  With none of
+        # the three no device call is coming, and a closed gate would hold
+        # the very pods that would bring one until the build worker's
+        # empty pop (schedule_one's idle path, up to 0.5 s): the bind
+        # leaves the gate as it found it.  It never opens it — a blocked
+        # scan flush keeps it closed for its whole run.  The handler work
+        # is identical either way (the assume-cache carries placements
+        # until the events land); only WHEN it contends for the GIL changes.
+        if self._next_stretch_pending():
+            self.informer_factory.pause_dispatch()
+            counters.inc("sched.gate_closed")
+        else:
+            counters.inc("sched.gate_left_open")
         with self.metrics.timed("bind", n=len(ready)):
             try:
                 if self.faults is not None:
@@ -2439,9 +2472,6 @@ class DeviceScheduler(Scheduler):
                 # bind returns AlreadyBound and the informer's bind event
                 # settles it — converges either way, and the assume-lease
                 # TTL backstops anything this path itself loses.
-                from minisched_tpu.controlplane.store import StorageDegraded
-                from minisched_tpu.observability import counters
-
                 counters.inc("engine.bind_batch_failed")
                 results = [err] * len(ready)
         t_bound = time.monotonic()
@@ -2477,8 +2507,6 @@ class DeviceScheduler(Scheduler):
                     # below forgets the assumption (releasing the
                     # capacity) and requeues, so the pod retries once
                     # the store's recovery probe re-arms appends
-                    from minisched_tpu.observability import counters
-
                     counters.inc("storage.degraded_parks")
                     if not degraded_dumped:
                         degraded_dumped = True

@@ -168,6 +168,11 @@ class WavePipeline:
         #: qpis popped but never handed over (stop raced the put) — the
         #: loop thread's shutdown drain parks them through error_func
         self._leftover: List[Any] = []
+        # batches popped (the worker writes it) and batches the loop
+        # thread took off the handoff (it writes that): one writer each,
+        # so ``holds_batch`` needs no lock
+        self._popped = 0
+        self._taken = 0
 
     # -- loop-thread surface -----------------------------------------------
     def start(self) -> None:
@@ -182,9 +187,21 @@ class WavePipeline:
         """Next item, or None on timeout (the worker emits at least one
         item per pop window, so None means it is stopping or wedged)."""
         try:
-            return self._handoff.get(timeout=timeout)
+            item = self._handoff.get(timeout=timeout)
         except _queue.Empty:
             return None
+        if item[0] != "empty":
+            self._taken += 1
+        return item
+
+    @property
+    def holds_batch(self) -> bool:
+        """The worker holds a popped batch the loop thread has not taken:
+        set from the pop's return until the handoff's get, whether the
+        batch is still building or built and waiting (with a handoff of
+        depth 1 that can be two).  A bind then knows a wave is coming and
+        closes the dispatch gate (DeviceScheduler._bind_batch)."""
+        return self._popped != self._taken
 
     def stop(self, join_timeout: float = 2.0) -> None:
         self._stop.set()
@@ -245,6 +262,7 @@ class WavePipeline:
             if not qpis:
                 self._put(("empty",))
                 continue
+            self._popped += 1
             item = self._build_item(
                 qpis, len(qpis) < sched.max_wave, sched._next_wave_id()
             )

@@ -196,6 +196,20 @@ are handed to the commit (engine/device_scheduler ``_commit_winners``):
           All of these and the ``scan.*`` counters above are registered
           at 0 when a device engine is constructed
 
+The informers' dispatch gate (controlplane/informer ``Informer._gate``),
+registered at 0 when a device engine is constructed (``GATE_COUNTERS``):
+
+    sched.gate_closed / sched.gate_left_open
+        — once a bind batch (engine/device_scheduler ``_bind_batch``):
+          binds that closed the gate because a next host stretch was
+          there to protect (ready pods in the queue, a batch the build
+          worker holds, scan work), and binds that found none and left
+          the gate as it was; left_open ÷ (closed + left_open) is where
+          the rule bites
+    informer.gate_wait_expired
+        — held batches whose timed wait at the gate ran its full 2.0 s
+          with the gate still closed (a gate nobody reopened)
+
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
 
@@ -622,6 +636,12 @@ LANE_COUNTERS = (
     "scan.excl_terms", "scan.excl_nodes", "scan.excl_capacity",
     "sched.evaluated_pods", "sched.unschedulable_pods",
     "constraint_index.pods_removed",
+)
+
+#: the dispatch gate's counters (see the module doc), registered beside
+#: ``LANE_COUNTERS``
+GATE_COUNTERS = (
+    "sched.gate_closed", "sched.gate_left_open", "informer.gate_wait_expired",
 )
 
 

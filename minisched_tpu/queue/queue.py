@@ -892,6 +892,11 @@ class SchedulingQueue:
                 )
             return out
 
+    def ready_count(self) -> int:
+        """Pods in the activeQ, ready for the next pop."""
+        with self._cond:
+            return len(self._active)
+
     def quota_stats(self) -> Dict[str, Dict[str, Any]]:
         """Per-namespace {admitted, held, limit} under one lock hold —
         the churn bench samples this to audit that no tenant ever
