@@ -713,6 +713,14 @@ def build_constraint_tables(
         counters.inc("scan.excl_terms", len(excl_vals))
         counters.inc("scan.excl_nodes", int(combo_excl.sum()))
         counters.inc("scan.excl_capacity", len(excl_vals) * n_real)
+        # how much of the cluster the pending pods' required affinity
+        # terms admit
+        aff_cids = sorted({cid for _pi, row in pod_rows for cid, _self in row["pa"]})
+        counters.inc(
+            "scan.aff_nodes",
+            int((combo_haskey[aff_cids] & (combo_dsum[aff_cids] > 0)).sum()),
+        )
+        counters.inc("scan.aff_capacity", len(aff_cids) * n_real)
 
     # --- volume coupling ---------------------------------------------------
     # feasibility semantics come from ONE place each — the VolumeBinding /

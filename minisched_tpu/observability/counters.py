@@ -177,6 +177,15 @@ each retry round of it):
           domains ban (true cells of ``combo_excl``), and terms × real
           nodes; nodes ÷ capacity is how much of the cluster the reverse
           direction bans (benchmark metric ``scan.excl_nodes_share``)
+    scan.aff_nodes / scan.aff_capacity
+        — once a constraint build of the scan lanes: the nodes on which
+          the distinct required affinity terms among the build's pending
+          pods (a combo row each) already match a pod in the node's
+          domain (true cells of ``combo_haskey & combo_dsum > 0`` over
+          those rows: the nodes the terms admit before the bootstrap
+          rule), and terms × real nodes; nodes ÷ capacity is how much of
+          the cluster the forward direction admits (benchmark metric
+          ``scan.aff_nodes_share``)
     constraint_index.pods_removed
         — assigned pods the constraint index dropped on a DELETED event
           (models/constraint_index: their counts, owner values and
@@ -634,6 +643,7 @@ LANE_COUNTERS = (
     "sched.lane_pods.wave", "sched.lane_pods.wide",
     "sched.lane_pods.narrow", "sched.lane_pods.exact",
     "scan.excl_terms", "scan.excl_nodes", "scan.excl_capacity",
+    "scan.aff_nodes", "scan.aff_capacity",
     "sched.evaluated_pods", "sched.unschedulable_pods",
     "constraint_index.pods_removed",
 )
