@@ -37,6 +37,7 @@ from minisched_tpu.framework.types import (
 )
 from minisched_tpu.models.constraints import (
     SCAN_ELIDE_GROUPS,
+    TopoAxisCache,
     build_constraint_tables,
     combo_rows,
 )
@@ -198,6 +199,9 @@ class DeviceScheduler(Scheduler):
         self._table_builder = CachedNodeTableBuilder(
             device_static=True, mesh=self.mesh
         )
+        #: the constraint build's node side (node index, topology-key
+        #: axis), kept while ``_table_builder``'s roster stands
+        self._topo_axis = TopoAxisCache()
         #: observability.resultstore.Store — set by the service when
         #: record_results is on: each wave then also runs a diagnostics
         #: evaluation and records the same per-plugin artifact scalar
@@ -224,7 +228,10 @@ class DeviceScheduler(Scheduler):
         self._scan_combo_cap = 0
         from minisched_tpu.observability import counters
 
-        for name in counters.LANE_COUNTERS + counters.GATE_COUNTERS:
+        for name in (
+            counters.LANE_COUNTERS + counters.GATE_COUNTERS
+            + counters.AXIS_COUNTERS
+        ):
             counters.inc(name, 0)
         # assume-pod cache (upstream's scheduler cache AssumePod): a placed
         # pod counts against its node IMMEDIATELY, before the async bind
@@ -296,12 +303,18 @@ class DeviceScheduler(Scheduler):
             self.gang_index = GangIndex()
             self.gang_index.wire(informer_factory)
 
-    def _build_constraints(self, pods_, nodes, assigned, **kw) -> Any:
+    def _build_constraints(
+        self, pods_, nodes, assigned, roster=None, **kw
+    ) -> Any:
         """Constraint tables for one wave/chunk.  With a live index the
         assumed-pod membership check and the aggregate reads happen under
         ONE index lock hold — otherwise a bind event landing in between
         would count its pod both as "assumed" and in the index planes
         (TOCTOU double-count).
+
+        ``roster``: ``_table_builder.roster_epoch()`` read on the thread
+        that just built ``nodes``' node table — the node side of the
+        build then comes from ``_topo_axis`` while that roster stands.
 
         The scan lanes' builds (``scan_planes``) share one combo
         capacity that only grows (``_scan_combo_cap``): the combos of a
@@ -335,6 +348,8 @@ class DeviceScheduler(Scheduler):
             scan = kw.get("scan_planes")
             if scan:
                 kw["combo_capacity"] = self._scan_combo_cap
+            if roster is not None:
+                kw.update(axis_cache=self._topo_axis, roster=roster)
             tables = build_constraint_tables(
                 pods_, nodes, assigned,
                 pvcs=pvcs,
@@ -1286,6 +1301,7 @@ class DeviceScheduler(Scheduler):
                 with self.metrics.timed("scan_build_constraints"):
                     extra = self._build_constraints(
                         pods_, nodes, assigned,
+                        roster=self._table_builder.roster_epoch(),
                         pod_capacity=cap,
                         node_capacity=node_agg.capacity,
                         scan_planes=True,
@@ -1399,6 +1415,7 @@ class DeviceScheduler(Scheduler):
                     )
                     extra = self._build_constraints(
                         pods_, nodes, assigned,
+                        roster=self._table_builder.roster_epoch(),
                         pod_capacity=cap,
                         node_capacity=node_agg.capacity,
                         scan_planes=True,  # the scan's commits need it

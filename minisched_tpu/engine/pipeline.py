@@ -129,6 +129,7 @@ def build_wave(sched: Any, qpis: List[Any], snapshot: Any) -> PreparedWave:
         with sched.metrics.timed("wave_build_constraints"):
             prepared.extra = sched._build_constraints(
                 pods_, nodes, assigned,
+                roster=sched._table_builder.roster_epoch(),
                 pod_capacity=pod_capacity,
                 node_capacity=prepared.node_agg.capacity,
                 scan_planes=False,  # wave mode never runs the scan

@@ -34,6 +34,7 @@ plane (one ``pod_matches_combo @ rev_weight`` matmul on device).
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -325,62 +326,138 @@ class _ComboRegistry:
         return self.ids[key]
 
 
-def _topo_key_axis(combos, nodes) -> Tuple[
-    Dict[str, int], Any, Any, Any, Any, List[Dict[str, int]]
-]:
-    """Dense domain encoding per distinct topology key.
+@dataclass(frozen=True)
+class KeyAxis:
+    """One topology key's node side, its rows padded to the node capacity:
+    all the constraint build reads of the nodes' labels under that key.
+    The arrays are read-only (a cached axis is shared by later builds)."""
 
-    Returns (key→index, topo_domain i32[K, N], topo_onehot bool[K, D, N],
-    topo_unique bool[K], val_id i32[K, N], value→id dicts per key).  Keys
-    whose cardinality exceeds MAX_DOMAINS must be unique-per-node
-    (hostname-like) — their one-hot plane is unused (the kernel
+    values: Dict[str, int]  # label value → id, in the order nodes meet them
+    val_id: Any  # i32[N] node i's value id, −1 where it lacks the key
+    domain: Any  # i32[N] the key's topo_domain row (D where keyless)
+    onehot: Any  # bool[D, N] for a key that is not unique, else None
+    unique: bool  # every keyed node holds a value of its own (hostname)
+
+
+def _key_axis(key: str, nodes: Sequence[Any], N: int) -> KeyAxis:
+    """Dense domain encoding of one topology key.  A key whose
+    cardinality exceeds MAX_DOMAINS must be unique-per-node
+    (hostname-like) — its one-hot plane is unused (the kernel
     short-circuits to per-node counts); anything in between raises.
-    ``val_id[k, i]`` is node i's label-VALUE id under key k (−1 when the
-    node lacks the key) — the host-side gather axis that lets the combo
-    planes fill without a per-combo × per-node Python loop.
-    """
-    N = len(nodes)
-    keys = sorted({topo for (_, _, topo) in combos})
-    key_ids = {k: i for i, k in enumerate(keys)}
+    ``val_id`` is the host-side gather axis that lets the combo planes
+    fill without a per-combo × per-node Python loop."""
+    values: Dict[str, int] = {}
+    row = [
+        -1 if v is None else values.setdefault(v, len(values))
+        for v in (node.metadata.labels.get(key) for node in nodes)
+    ]
+    val_id = np.full(N, -1, np.int32)
+    val_id[: len(row)] = row
+    keyed = val_id >= 0
+    n_domains = len(values)
+    unique = n_domains == int(keyed.sum()) and n_domains > 0
+    if n_domains > MAX_DOMAINS and not unique:
+        raise ValueError(
+            f"topology key {key!r}: {n_domains} domains exceed "
+            f"MAX_DOMAINS={MAX_DOMAINS} and the key is not unique-per-node"
+        )
+    D = MAX_DOMAINS
+    domain = np.full(N, D, np.int32)
+    onehot = None
+    if unique:
+        domain[keyed] = 0  # unused by the unique path; != D marks haskey
+    else:
+        domain[keyed] = val_id[keyed]
+        onehot = np.zeros((D, N), bool)
+        onehot[val_id[keyed], np.flatnonzero(keyed)] = True
+        onehot.flags.writeable = False
+    val_id.flags.writeable = False
+    domain.flags.writeable = False
+    return KeyAxis(values, val_id, domain, onehot, unique)
+
+
+def _axis_planes(axes: Sequence[KeyAxis], N: int) -> Tuple[Any, Any, Any]:
+    """(topo_domain i32[K, N], topo_onehot bool[K, D, N], topo_unique
+    bool[K]) of a build's keys, in the order of ``axes``."""
     # K is an executable shape too — quantize to 4 so adding a second
     # topology key doesn't recompile (the onehot plane costs K×D×N bools)
-    K = pad_to(max(len(keys), 1), 4)
-    values: List[Dict[str, int]] = [{} for _ in range(K)]
-    vals_per_node: List[List[Optional[int]]] = [[None] * N for _ in range(K)]
-    for k, key in enumerate(keys):
-        for i, node in enumerate(nodes):
-            v = node.metadata.labels.get(key)
-            if v is None:
-                continue
-            if v not in values[k]:
-                values[k][v] = len(values[k])
-            vals_per_node[k][i] = values[k][v]
-    unique = np.zeros(K, bool)
-    for k, key in enumerate(keys):
-        n_domains = len(values[k])
-        n_keyed = sum(1 for v in vals_per_node[k] if v is not None)
-        unique[k] = n_domains == n_keyed and n_domains > 0
-        if n_domains > MAX_DOMAINS and not unique[k]:
-            raise ValueError(
-                f"topology key {key!r}: {n_domains} domains exceed "
-                f"MAX_DOMAINS={MAX_DOMAINS} and the key is not unique-per-node"
-            )
-    D = MAX_DOMAINS
-    Ncap = N  # caller re-pads below
-    topo_domain = np.full((K, Ncap), D, np.int32)
-    topo_onehot = np.zeros((K, D, Ncap), bool)
-    val_id = np.full((K, Ncap), -1, np.int32)
-    for k in range(len(keys)):
-        for i, dom in enumerate(vals_per_node[k]):
-            if dom is None:
-                continue
-            val_id[k, i] = dom
-            if unique[k]:
-                topo_domain[k, i] = 0  # unused by the unique path; != D marks haskey
-            else:
-                topo_domain[k, i] = dom
-                topo_onehot[k, dom, i] = True
-    return key_ids, topo_domain, topo_onehot, unique, val_id, values
+    K = pad_to(max(len(axes), 1), 4)
+    topo_domain = np.full((K, N), MAX_DOMAINS, np.int32)
+    topo_onehot = np.zeros((K, MAX_DOMAINS, N), bool)
+    topo_unique = np.zeros(K, bool)
+    for k, ax in enumerate(axes):
+        topo_domain[k] = ax.domain
+        topo_unique[k] = ax.unique
+        if ax.onehot is not None:
+            topo_onehot[k] = ax.onehot
+    return topo_domain, topo_onehot, topo_unique
+
+
+class TopoAxisCache:
+    """The node side of the topology-key axis, derived once a node roster.
+
+    What a constraint build reads of the nodes — their name → index map
+    and each topology key's ``KeyAxis`` — depends on the nodes' names,
+    order and labels only, so it is kept while the roster stands: a scan
+    call over 5,000 nodes then reads no label in Python.  ``roster`` is
+    the caller's version of the node list (``CachedNodeTableBuilder
+    .roster_epoch``, which moves whenever a node's ``resource_version``
+    or the membership changes); a new one, or a new node capacity, drops
+    everything.  Axes are kept per KEY, so lanes that build with
+    different keys (the wave with none, a scan with one) share them, and
+    the assembled planes per key set.  An axis is kept only once derived:
+    a key over MAX_DOMAINS raises on every build that meets it.  The wave
+    build worker and the loop thread both build, hence the lock.
+
+    Counters (registered at 0 with the device engine,
+    ``counters.AXIS_COUNTERS``): ``constraints.axis_built`` /
+    ``constraints.axis_reused``, once a key a build."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._roster: Any = None
+        self._node_idx: Optional[Dict[str, int]] = None
+        self._axes: Dict[str, KeyAxis] = {}
+        self._planes: Dict[Tuple[str, ...], Tuple[Any, Any, Any]] = {}
+
+    def _stand(self, roster: Any, N: int) -> None:
+        """Drop what another roster or capacity left (lock held)."""
+        if self._roster != (roster, N):
+            self._roster = (roster, N)
+            self._node_idx = None
+            self._axes = {}
+            self._planes = {}
+
+    def node_index(self, roster: Any, nodes: Sequence[Any], N: int) -> Dict[str, int]:
+        """Node name → index (shared: the caller must not mutate it)."""
+        with self._lock:
+            self._stand(roster, N)
+            if self._node_idx is None:
+                self._node_idx = {n.metadata.name: i for i, n in enumerate(nodes)}
+            return self._node_idx
+
+    def axes(
+        self, roster: Any, nodes: Sequence[Any], keys: Tuple[str, ...], N: int
+    ) -> Tuple[List[KeyAxis], Tuple[Any, Any, Any]]:
+        """The keys' axes and their assembled, read-only planes."""
+        with self._lock:
+            self._stand(roster, N)
+            axes = []
+            for key in keys:
+                ax = self._axes.get(key)
+                if ax is None:
+                    ax = self._axes[key] = _key_axis(key, nodes, N)
+                    counters.inc("constraints.axis_built")
+                else:
+                    counters.inc("constraints.axis_reused")
+                axes.append(ax)
+            planes = self._planes.get(keys)
+            if planes is None:
+                planes = _axis_planes(axes, N)
+                for a in planes:
+                    a.flags.writeable = False
+                self._planes[keys] = planes
+            return axes, planes
 
 
 def _matches(sel: LabelSelector, namespaces: Tuple[str, ...], pod: Any) -> bool:
@@ -439,6 +516,8 @@ def build_constraint_tables(
     elide_zeros: bool = True,
     elide_groups: Tuple[Tuple[str, ...], ...] = (),
     combo_capacity: int = 0,
+    axis_cache: Optional[TopoAxisCache] = None,
+    roster: Any = None,
 ):
     """Build the wave's coupling tables.
 
@@ -464,10 +543,18 @@ def build_constraint_tables(
     ``extra_assigned``: assigned pods the index hasn't seen yet (the
     engine's still-assumed binds), folded through the same per-pod logic
     the from-scratch walk uses.
+
+    ``axis_cache`` / ``roster``: a ``TopoAxisCache`` and the version of
+    ``nodes`` it is read under — the node index and the topology-key axis
+    then come from the cache while the roster stands, instead of from a
+    walk over every node's labels; the tables are the same.
     """
     P = pod_capacity or pad_to(len(pending_pods))
     N = node_capacity or pad_to(len(nodes))
-    node_idx = {n.metadata.name: i for i, n in enumerate(nodes)}
+    if axis_cache is not None:
+        node_idx = axis_cache.node_index(roster, nodes, N)
+    else:
+        node_idx = {n.metadata.name: i for i, n in enumerate(nodes)}
     assigned = [p for p in assigned_pods if p.spec.node_name in node_idx]
     if index is not None:
         # the fold below re-applies the from-scratch per-pod logic to just
@@ -587,15 +674,15 @@ def build_constraint_tables(
     combo_global = np.zeros(C, np.int32)
     combo_here = np.zeros((C, N), np.int32)
     combo_key = np.zeros(C, np.int32)
-    key_ids, topo_domain_, topo_onehot_, topo_unique, val_id_, key_vals = (
-        _topo_key_axis(reg.combos, nodes)
-    )
-    # pad the node axis of the key-domain planes to capacity N
-    K, D = topo_onehot_.shape[0], topo_onehot_.shape[1]
-    topo_domain = np.full((K, N), D, np.int32)
-    topo_domain[:, : topo_domain_.shape[1]] = topo_domain_
-    topo_onehot = np.zeros((K, D, N), bool)
-    topo_onehot[:, :, : topo_onehot_.shape[2]] = topo_onehot_
+    keys = tuple(sorted({topo for (_, _, topo) in reg.combos}))
+    key_ids = {k: i for i, k in enumerate(keys)}
+    if axis_cache is not None:
+        axes, (topo_domain, topo_onehot, topo_unique) = axis_cache.axes(
+            roster, nodes, keys, N
+        )
+    else:
+        axes = [_key_axis(k, nodes, N) for k in keys]
+        topo_domain, topo_onehot, topo_unique = _axis_planes(axes, N)
     pod_matches_combo = np.zeros((P, C), bool)
     combo_excl = np.zeros((C, N), bool)
     rev_weight = np.zeros((C, N), np.int32)
@@ -643,7 +730,9 @@ def build_constraint_tables(
     for cid, (nss, sel, topo) in enumerate(reg.combos):
         k = key_ids[topo]
         combo_key[cid] = k
-        domain_count: Dict[str, int] = {}
+        # (node index, matching pods) pairs of the pods this combo counts
+        here_i: List[int] = []
+        here_n: List[int] = []
         if index is not None:
             # O(nonzero): per-node counts from the index, assumed pods
             # folded through the same matcher; domain sums derive from the
@@ -653,46 +742,37 @@ def build_constraint_tables(
                 if _matches(sel, nss, rep):
                     for node, cnt in a_nodes[g].items():
                         here[node] = here.get(node, 0) + cnt
-            total = 0
             for node_name, cnt in here.items():
                 i = node_idx.get(node_name)
-                if i is None:
-                    continue  # node outside this wave's view
-                total += cnt
-                combo_here[cid, i] = cnt
-                val = nodes[i].metadata.labels.get(topo)
-                if val is not None:
-                    domain_count[val] = domain_count.get(val, 0) + cnt
-            combo_global[cid] = total
+                if i is not None:  # else the node is outside this view
+                    here_i.append(i)
+                    here_n.append(cnt)
         else:
-            total = 0
             for g, rep in enumerate(a_reps):
                 if not _matches(sel, nss, rep):
                     continue
                 for node, cnt in a_nodes[g].items():
-                    i = node_idx[node]
-                    total += cnt
-                    combo_here[cid, i] += cnt
-                    val = nodes[i].metadata.labels.get(topo)
-                    if val is not None:
-                        domain_count[val] = domain_count.get(val, 0) + cnt
-            combo_global[cid] = total
+                    here_i.append(node_idx[node])
+                    here_n.append(cnt)
+        hi = np.asarray(here_i, np.intp)
+        hn = np.asarray(here_n, np.int32)
+        np.add.at(combo_here[cid], hi, hn)
+        combo_global[cid] = hn.sum()
         # haskey/dsum/rev/excl rows as gathers through the node→value-id
         # axis (a per-combo × per-node Python loop here cost ~1s per scan
         # chunk at 32 combos × 10k nodes)
         rv = rev_vals.get(cid)
         ev = excl_vals.get(cid)
-        vid = val_id_[k, :n_real]  # (n_real,) value id, -1 absent
+        vid = axes[k].val_id[:n_real]  # (n_real,) value id, -1 absent
         has = vid >= 0
         combo_haskey[cid, :n_real] = has
-        vals_k = key_vals[k]
+        vals_k = axes[k].values
         safe_vid = np.where(has, vid, 0)
-        if domain_count:
+        h_vid = vid[hi]
+        h_keyed = h_vid >= 0
+        if h_keyed.any():
             cnt_by_vid = np.zeros(max(len(vals_k), 1), np.int32)
-            for val, c in domain_count.items():
-                vi = vals_k.get(val)
-                if vi is not None:
-                    cnt_by_vid[vi] = c
+            np.add.at(cnt_by_vid, h_vid[h_keyed], hn[h_keyed])
             combo_dsum[cid, :n_real] = np.where(has, cnt_by_vid[safe_vid], 0)
         if rv:
             rw_by_vid = np.zeros(max(len(vals_k), 1), np.int32)

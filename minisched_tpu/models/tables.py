@@ -972,6 +972,12 @@ class CachedNodeTableBuilder:
         #: DeviceScheduler._eval_packed_wave)
         self._static_dev_fallback: Optional[Dict[str, Any]] = None
         self._sig = None
+        #: moves whenever ``_sig`` does: the version of the node roster
+        #: (names, order, resource_versions) the statics encode
+        self._roster_epoch = 0
+        #: per thread, the roster epoch of that thread's last build
+        #: (roster_epoch)
+        self._tls = threading.local()
         self._static: Dict[str, Any] = {}
         self._static_dev: Dict[str, Any] = {}
         # incremental AGGREGATE base: persistent host copies of the
@@ -1037,6 +1043,15 @@ class CachedNodeTableBuilder:
             ),
         )
 
+    def roster_epoch(self) -> Optional[int]:
+        """The version of the node roster this thread's last build encoded
+        (None before one): equal versions mean the same nodes in the same
+        order with the same resource_versions, so what derives from the
+        nodes' objects alone may be kept across builds (the constraint
+        build's TopoAxisCache).  Per thread, because the wave build worker
+        and the loop thread build through one builder."""
+        return getattr(self._tls, "roster", None)
+
     def _drop_reuse(self) -> None:
         """Invalidate the idle-wave reuse cache (statics about to
         re-encode, aggregate base about to change, or a build failed)."""
@@ -1077,6 +1092,7 @@ class CachedNodeTableBuilder:
         self._names = names
         self._name_index = {name: i for i, name in enumerate(names)}
         self._sig = sig
+        self._roster_epoch += 1
 
     def _patch_rows(self, node_infos: Sequence[Any], sig: Tuple) -> bool:
         """Incremental static update: same node set/order/capacities, only
@@ -1119,6 +1135,7 @@ class CachedNodeTableBuilder:
         else:
             self._static = dict(t)
         self._sig = sig
+        self._roster_epoch += 1
         return True
 
     def _place_static_dev(self, t: Dict[str, Any]) -> None:
@@ -1373,9 +1390,11 @@ class CachedNodeTableBuilder:
                     dirty, agg_delta, epoch,
                 )
                 if reused is not None:
+                    self._tls.roster = self._roster_epoch
                     table, names = reused
                     return table, list(names)
                 self._ensure_static(node_infos, cap, prof_capacity)
+                self._tls.roster = self._roster_epoch
                 t = self._aggregates_for(node_infos, cap, dirty, agg_delta)
                 if self._device_static:
                     cols = dict(self._static_dev)
@@ -1431,9 +1450,11 @@ class CachedNodeTableBuilder:
                     dirty, agg_delta, epoch,
                 )
                 if reused is not None:
+                    self._tls.roster = self._roster_epoch
                     static_dev, packed, names = reused
                     return static_dev, packed, list(names)
                 self._ensure_static(node_infos, cap, prof_capacity)
+                self._tls.roster = self._roster_epoch
                 t = self._aggregates_for(node_infos, cap, dirty, agg_delta)
                 out = (
                     self._static_dev,

@@ -219,6 +219,15 @@ registered at 0 when a device engine is constructed (``GATE_COUNTERS``):
         — held batches whose timed wait at the gate ran its full 2.0 s
           with the gate still closed (a gate nobody reopened)
 
+The constraint build's node side (models/constraints ``TopoAxisCache``),
+registered at 0 when a device engine is constructed (``AXIS_COUNTERS``):
+
+    constraints.axis_built / constraints.axis_reused
+        — once a topology key a constraint build of the engine: the
+          key's node-side planes derived from the nodes' labels, or read
+          from the cache because the node roster stood since they were
+          (a window over an unchanged roster reads built 0)
+
 The device engine says what it runs on and when a device call fails
 (ISSUE 21: no fallback may hide the device) — asserted by chip_smoke.py:
 
@@ -653,6 +662,10 @@ LANE_COUNTERS = (
 GATE_COUNTERS = (
     "sched.gate_closed", "sched.gate_left_open", "informer.gate_wait_expired",
 )
+
+#: the topology-key axis cache's counters (see the module doc), registered
+#: beside ``LANE_COUNTERS``
+AXIS_COUNTERS = ("constraints.axis_built", "constraints.axis_reused")
 
 
 class Counters:
