@@ -43,7 +43,7 @@ import numpy as np
 
 from minisched_tpu.api.objects import LabelSelector, PodAffinityTerm
 from minisched_tpu.models.tables import _register_table, pad_to
-from minisched_tpu.observability import counters
+from minisched_tpu.observability import counters, profiling
 
 MAX_VOLUMES = 4  # PVC references per pod
 MAX_TSC = 4  # topology spread constraints per pod
@@ -59,6 +59,9 @@ MAX_DOMAINS = 64
 
 TS_DO_NOT_SCHEDULE = 0
 TS_SCHEDULE_ANYWAY = 1
+
+#: the combo-row fill of ``build_constraint_tables``, on ``/metrics`` from boot
+profiling.register_spans("constraints.combo_rows")
 
 #: packed-schema elision groups for the scan lane (pack_table
 #: ``elide_groups``): columns whose zero-ness is a property of the
@@ -727,67 +730,75 @@ def build_constraint_tables(
             d = a_nodes[g]
             node = p.spec.node_name
             d[node] = d.get(node, 0) + 1
-    for cid, (nss, sel, topo) in enumerate(reg.combos):
-        k = key_ids[topo]
-        combo_key[cid] = k
-        # (node index, matching pods) pairs of the pods this combo counts
-        here_i: List[int] = []
-        here_n: List[int] = []
-        if index is not None:
-            # O(nonzero): per-node counts from the index, assumed pods
-            # folded through the same matcher; domain sums derive from the
-            # CURRENT node labels so label churn self-heals
-            here = index.combo_aggregate(nss, sel, topo)
-            for g, rep in enumerate(a_reps):
-                if _matches(sel, nss, rep):
+    # the combo rows, forward and reverse: one row a distinct
+    # (namespaces, selector, topology key), its counts from the index and
+    # its bans from the placed owners' domains
+    with profiling.span("constraints.combo_rows", n=len(reg.combos)):
+        for cid, (nss, sel, topo) in enumerate(reg.combos):
+            k = key_ids[topo]
+            combo_key[cid] = k
+            # (node index, matching pods) pairs of the pods this combo counts
+            here_i: List[int] = []
+            here_n: List[int] = []
+            if index is not None:
+                # O(nonzero): per-node counts from the index, assumed pods
+                # folded through the same matcher; domain sums derive from the
+                # CURRENT node labels so label churn self-heals
+                here = index.combo_aggregate(nss, sel, topo)
+                for g, rep in enumerate(a_reps):
+                    if _matches(sel, nss, rep):
+                        for node, cnt in a_nodes[g].items():
+                            here[node] = here.get(node, 0) + cnt
+                for node_name, cnt in here.items():
+                    i = node_idx.get(node_name)
+                    if i is not None:  # else the node is outside this view
+                        here_i.append(i)
+                        here_n.append(cnt)
+            else:
+                for g, rep in enumerate(a_reps):
+                    if not _matches(sel, nss, rep):
+                        continue
                     for node, cnt in a_nodes[g].items():
-                        here[node] = here.get(node, 0) + cnt
-            for node_name, cnt in here.items():
-                i = node_idx.get(node_name)
-                if i is not None:  # else the node is outside this view
-                    here_i.append(i)
-                    here_n.append(cnt)
-        else:
-            for g, rep in enumerate(a_reps):
-                if not _matches(sel, nss, rep):
-                    continue
-                for node, cnt in a_nodes[g].items():
-                    here_i.append(node_idx[node])
-                    here_n.append(cnt)
-        hi = np.asarray(here_i, np.intp)
-        hn = np.asarray(here_n, np.int32)
-        np.add.at(combo_here[cid], hi, hn)
-        combo_global[cid] = hn.sum()
-        # haskey/dsum/rev/excl rows as gathers through the node→value-id
-        # axis (a per-combo × per-node Python loop here cost ~1s per scan
-        # chunk at 32 combos × 10k nodes)
-        rv = rev_vals.get(cid)
-        ev = excl_vals.get(cid)
-        vid = axes[k].val_id[:n_real]  # (n_real,) value id, -1 absent
-        has = vid >= 0
-        combo_haskey[cid, :n_real] = has
-        vals_k = axes[k].values
-        safe_vid = np.where(has, vid, 0)
-        h_vid = vid[hi]
-        h_keyed = h_vid >= 0
-        if h_keyed.any():
-            cnt_by_vid = np.zeros(max(len(vals_k), 1), np.int32)
-            np.add.at(cnt_by_vid, h_vid[h_keyed], hn[h_keyed])
-            combo_dsum[cid, :n_real] = np.where(has, cnt_by_vid[safe_vid], 0)
-        if rv:
-            rw_by_vid = np.zeros(max(len(vals_k), 1), np.int32)
-            for val, w in rv.items():
-                vi = vals_k.get(val)
-                if vi is not None:
-                    rw_by_vid[vi] = w
-            rev_weight[cid, :n_real] = np.where(has, rw_by_vid[safe_vid], 0)
-        if ev:
-            ban_by_vid = np.zeros(max(len(vals_k), 1), bool)
-            for val in ev:
-                vi = vals_k.get(val)
-                if vi is not None:
-                    ban_by_vid[vi] = True
-            combo_excl[cid, :n_real] = has & ban_by_vid[safe_vid]
+                        here_i.append(node_idx[node])
+                        here_n.append(cnt)
+            hi = np.asarray(here_i, np.intp)
+            hn = np.asarray(here_n, np.int32)
+            np.add.at(combo_here[cid], hi, hn)
+            combo_global[cid] = hn.sum()
+            # haskey/dsum/rev/excl rows as gathers through the node→value-id
+            # axis (a per-combo × per-node Python loop here cost ~1s per scan
+            # chunk at 32 combos × 10k nodes)
+            rv = rev_vals.get(cid)
+            ev = excl_vals.get(cid)
+            vid = axes[k].val_id[:n_real]  # (n_real,) value id, -1 absent
+            has = vid >= 0
+            combo_haskey[cid, :n_real] = has
+            vals_k = axes[k].values
+            safe_vid = np.where(has, vid, 0)
+            h_vid = vid[hi]
+            h_keyed = h_vid >= 0
+            if h_keyed.any():
+                cnt_by_vid = np.zeros(max(len(vals_k), 1), np.int32)
+                np.add.at(cnt_by_vid, h_vid[h_keyed], hn[h_keyed])
+                combo_dsum[cid, :n_real] = np.where(
+                    has, cnt_by_vid[safe_vid], 0
+                )
+            if rv:
+                rw_by_vid = np.zeros(max(len(vals_k), 1), np.int32)
+                for val, w in rv.items():
+                    vi = vals_k.get(val)
+                    if vi is not None:
+                        rw_by_vid[vi] = w
+                rev_weight[cid, :n_real] = np.where(
+                    has, rw_by_vid[safe_vid], 0
+                )
+            if ev:
+                ban_by_vid = np.zeros(max(len(vals_k), 1), bool)
+                for val in ev:
+                    vi = vals_k.get(val)
+                    if vi is not None:
+                        ban_by_vid[vi] = True
+                combo_excl[cid, :n_real] = has & ban_by_vid[safe_vid]
     if scan_planes:
         # how much of the cluster the reverse direction bans
         counters.inc("scan.excl_terms", len(excl_vals))

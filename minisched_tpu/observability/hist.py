@@ -208,6 +208,14 @@ a pod.  The lint in tests/test_telemetry.py holds every ``span(<name>)`` and
           ``sched.scan_build_pods``, ``sched.scan_build_constraints``),
           ``sched.scan_evaluate`` (ids ``call``, ``n``; children
           ``sched.scan_dispatch`` / ``sched.scan_fetch`` as in the wave)
+    constraints.combo_rows
+        — the thread that builds the constraint tables, inside
+          ``sched.scan_build_constraints`` and
+          ``sched.wave_build_constraints``: the combo rows of one
+          ``build_constraint_tables`` (a row a distinct namespaces,
+          selector and topology key: its per-node counts, domain sums,
+          symmetric weights and reverse anti-affinity bans); id ``n``
+          (the rows filled)
     sched.loop_gc
         — loop thread, the explicit collection at a wave boundary
     sched.snapshot / sched.schedule / sched.wait_on_permit

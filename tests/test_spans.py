@@ -36,6 +36,7 @@ TABLE_SPANS = [
     "sched_scan_flush", "sched_scan_grouping", "sched_scan_build",
     "sched_scan_evaluate", "sched_scan_dispatch", "sched_scan_fetch",
     "sched_loop_gc",
+    "constraints_combo_rows",
 ]
 #: spans of threads that wait for work: they may have closed once by the
 #: time a scrape right after start() is answered
