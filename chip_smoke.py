@@ -11,14 +11,16 @@ gives), and drives it ONLY over loopback HTTP with
 * 5,000 nodes (the Kubernetes scalability envelope and upstream
   ``scheduler_perf``'s 5000Nodes size: 8 cpu / 16Gi / 110 pods, 16 zones,
   20 % unschedulable) and 10,000 pods (``SchedulingBasic/
-  5000Nodes_10000Pods``) generated from ``--seed``: 9,500 plain
+  5000Nodes_10000Pods``) generated from ``--seed``: 8,880 plain
   ``500m/256Mi`` pods, a minority with a node selector or a toleration so
-  both packed pod schemas compile; then 480 ``DoNotSchedule`` topology-
-  spread pods in ONE burst (> SCAN_BLOCK_SIZE: the blocked scan lane), the
-  first half spread over 32 services, whose blocks fill (the wide layout
-  and its P=32 Pallas step), the second half all of one service, whose
-  blocks hold one pod each (the narrow layout, a pod a row: P=1 and the
-  XLA tail); then 20 more (≤ 32: the exact P=1 lane and the XLA tail).
+  both packed pod schemas compile; then 1,100 ``DoNotSchedule`` topology-
+  spread pods in ONE burst (> SCAN_BLOCK_SIZE: the blocked scan lane; >
+  SCAN_MAX_CHUNK, so more than one narrow call holds and the grouping
+  keeps its layout by fill), the first half spread over 32 services,
+  whose blocks fill (the wide layout and its P=32 Pallas step), the
+  second half all of one service, whose blocks hold one pod each (the
+  narrow layout, a pod a row: P=1 and the XLA tail); then 20 more (≤ 32:
+  the exact P=1 lane and the XLA tail).
   All four device programs the engine can dispatch compile and run inside
   the real loop.
 * The safety audit reads back through REST; parks, dispatch heals, the
@@ -61,7 +63,8 @@ NODE_PODS = 110
 class Sizes:
     nodes: int
     plain: int  # plain pods (a minority carry a selector or a toleration)
-    burst: int  # spread pods sent in one burst (> SCAN_BLOCK_SIZE)
+    burst: int  # spread pods sent in one burst (> SCAN_BLOCK_SIZE; wide
+    # and narrow only where > SCAN_MAX_CHUNK, else one narrow call)
     tail: int  # spread pods sent after it (<= SCAN_BLOCK_SIZE)
 
     @property
@@ -69,7 +72,7 @@ class Sizes:
         return self.plain + self.burst + self.tail
 
 
-FULL = Sizes(nodes=5000, plain=9500, burst=480, tail=20)
+FULL = Sizes(nodes=5000, plain=8880, burst=1100, tail=20)
 
 
 def say(msg: str) -> None:

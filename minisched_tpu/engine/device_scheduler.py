@@ -746,7 +746,8 @@ class DeviceScheduler(Scheduler):
     #: live pod (all of them, where every pod shares one selector) are
     #: laid out this many rows a pod and run through the same kernel at
     #: this block size, SCAN_MAX_CHUNK live pods a call at ONE capacity
-    #: (_plan_blocked_calls) — at 32 rows a pod a call built 8,192 rows
+    #: (_plan_blocked_calls; so is a whole grouping that fits one such
+    #: call, whatever its fill) — at 32 rows a pod a call built 8,192 rows
     #: and ran 256 steps of 32 for 256 live pods.  PERF.md §6 PR 32 has
     #: the chip reading that chose the width.
     SCAN_NARROW_WIDTH = 1
@@ -793,15 +794,51 @@ class DeviceScheduler(Scheduler):
         cls, blocks: List[List[Optional[Any]]]
     ) -> List[Tuple[bool, List[Optional[Any]], int]]:
         """(narrow, rows, capacity) of every kernel call one grouping
-        takes, in the order they must run.  The width of a call's rows
-        follows the fill the grouping found: the longest SUFFIX of blocks
-        that hold exactly one live pod is laid out SCAN_NARROW_WIDTH rows
-        a pod, SCAN_MAX_CHUNK live pods a call, at one capacity whatever
-        its length (a short last call pads up; its padded steps are
-        skipped); the head keeps SCAN_BLOCK_SIZE rows a block at
-        _blocked_cap's tiers and runs first.  A group's members sit in
-        strictly increasing blocks (scan_groups), so head-then-suffix in
-        block order is still FIFO within every group."""
+        takes, in the order they must run: the layout by fill
+        (_calls_by_fill), except that a grouping it would cut into
+        several calls whose live pods fit one narrow call (at most
+        SCAN_MAX_CHUNK) runs as that one narrow call, every live pod in
+        block order.  Each call pays a whole node/pod/constraint build,
+        snapshot, dispatch and commit, far more than the narrow layout's
+        extra device steps for so few pods; a pod a step is exactly
+        sequential, and block order keeps every group's FIFO.  Folding
+        the narrow tail into the WIDE call instead would lay it out
+        SCAN_BLOCK_SIZE rows a pod and push flushes of a few hundred pods
+        past the 1,024-row tier.  ``scan.calls_folded`` counts the calls
+        this saves."""
+        calls = cls._calls_by_fill(blocks)
+        if len(calls) <= 1:
+            return calls
+        live = [m for blk in blocks for m in blk if m is not None]
+        if len(live) > cls.SCAN_MAX_CHUNK:
+            return calls
+        from minisched_tpu.observability import counters
+
+        counters.inc("scan.calls_folded", len(calls) - 1)
+        return [cls._narrow_call(live)]
+
+    @classmethod
+    def _narrow_call(
+        cls, pods: List[Any]
+    ) -> Tuple[bool, List[Optional[Any]], int]:
+        """One narrow call over at most SCAN_MAX_CHUNK live pods:
+        SCAN_NARROW_WIDTH rows a pod, at the narrow layout's one
+        capacity (a short call pads up; its padded steps are skipped)."""
+        pad: List[Optional[Any]] = [None] * (cls.SCAN_NARROW_WIDTH - 1)
+        rows = [m for pod in pods for m in (pod, *pad)]
+        return True, rows, cls.SCAN_MAX_CHUNK * cls.SCAN_NARROW_WIDTH
+
+    @classmethod
+    def _calls_by_fill(
+        cls, blocks: List[List[Optional[Any]]]
+    ) -> List[Tuple[bool, List[Optional[Any]], int]]:
+        """The layout by the fill the grouping found: the longest SUFFIX
+        of blocks that hold exactly one live pod is laid out narrow,
+        SCAN_MAX_CHUNK live pods a call (_narrow_call); the head keeps
+        SCAN_BLOCK_SIZE rows a block at _blocked_cap's tiers and runs
+        first.  A group's members sit in strictly increasing blocks
+        (scan_groups), so head-then-suffix in block order is still FIFO
+        within every group."""
         head = len(blocks)
         while head and blocks[head - 1][1] is None:
             head -= 1
@@ -810,15 +847,12 @@ class DeviceScheduler(Scheduler):
         for i in range(0, len(wide), cls.BLOCKED_MAX_CHUNK):
             part = wide[i : i + cls.BLOCKED_MAX_CHUNK]
             calls.append((False, part, cls._blocked_cap(len(part))))
-        W = cls.SCAN_NARROW_WIDTH
-        pad: List[Optional[Any]] = [None] * (W - 1)
         for i in range(head, len(blocks), cls.SCAN_MAX_CHUNK):
-            rows = [
-                m
-                for blk in blocks[i : i + cls.SCAN_MAX_CHUNK]
-                for m in (blk[0], *pad)
-            ]
-            calls.append((True, rows, cls.SCAN_MAX_CHUNK * W))
+            calls.append(
+                cls._narrow_call(
+                    [blk[0] for blk in blocks[i : i + cls.SCAN_MAX_CHUNK]]
+                )
+            )
         return calls
 
     def prewarm(self, scan: bool = True) -> None:
@@ -1184,7 +1218,8 @@ class DeviceScheduler(Scheduler):
     ) -> None:
         """Blocked lane: group → order → the kernel calls the fill asks
         for (_plan_blocked_calls: full blocks wide, the trailing one-pod
-        blocks narrow); feasible pods that lose a same-node capacity race
+        blocks narrow, a grouping that fits one narrow call all narrow in
+        one call); feasible pods that lose a same-node capacity race
         retry in later rounds (re-grouped against fresh state); leftovers
         after SCAN_BLOCK_RETRIES ride the exact per-pod scan — a
         sequential order never fails them, so neither may this lane."""
@@ -1210,6 +1245,15 @@ class DeviceScheduler(Scheduler):
                     sets = interaction_sets([q.pod for q in pending])
                     blocks = order_into_blocks(pending, sets, B)
                     calls = self._plan_blocked_calls(blocks)
+                    if _attempt and len(calls) == 1 and not calls[0][0]:
+                        # a round of race losers that its fill lays out as
+                        # one wide call runs a pod a step instead: no pod
+                        # of it loses a race again, and it never meets a
+                        # small wide tier that the flushes before it did
+                        # not (their first rounds fold, _plan_blocked_calls)
+                        live = [m for m in calls[0][1] if m is not None]
+                        if len(live) <= self.SCAN_MAX_CHUNK:
+                            calls = [self._narrow_call(live)]
                 counters.inc("scan.rows_live", len(pending))
                 counters.inc(
                     "scan.rows_total", sum(len(part) for _, part, _ in calls)

@@ -22,7 +22,9 @@ fill their blocks and run 32 rows a kernel step; one large group (every
 pod under one selector) gets a block a pod, and such one-pod blocks at the
 end of the order run a pod a row through the same kernel at a narrow
 block size, after the blocks before them — block order is kept, so a
-group's members still run in FIFO order.
+group's members still run in FIFO order.  A grouping that would take
+several calls but whose live pods fit one narrow call runs all of them a
+pod a row, in block order, in that one call.
 
 The same fact makes first fit near-linear: a block REFUSES an identity
 when it is full or its union holds the identity, and a refusal is for

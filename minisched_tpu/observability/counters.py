@@ -162,6 +162,11 @@ each retry round of it):
         — kernel calls of the blocked lane by the layout of their rows
           (one grouping is cut into the calls its fill asks for: the
           head wide, the trailing one-pod blocks narrow)
+    scan.calls_folded
+        — the calls saved where a grouping that its fill would cut into
+          several calls fits one narrow call (at most SCAN_MAX_CHUNK live
+          pods) and runs as that one: the layout by fill's calls less one,
+          each time
     scan.combos_live / scan.combos_total
         — once a constraint build of the scan lanes (blocked and
           exact): the (namespaces, selector, topology key) combos among
@@ -647,7 +652,7 @@ from typing import Dict, Set
 #: lane that did not run from a program that has no such counter
 LANE_COUNTERS = (
     "scan.rows_live", "scan.rows_total", "scan.rows_narrow",
-    "scan.calls_wide", "scan.calls_narrow",
+    "scan.calls_wide", "scan.calls_narrow", "scan.calls_folded",
     "scan.combos_live", "scan.combos_total",
     "sched.lane_pods.wave", "sched.lane_pods.wide",
     "sched.lane_pods.narrow", "sched.lane_pods.exact",

@@ -516,7 +516,7 @@ def _burst(case):
         # three small services ahead of one large: the small ones share
         # the first blocks with it, the rest of the large one stands alone
         "mixed": [("a", 2), ("b", 3), ("c", 2), ("big", 60)],
-    }[case]
+    }["mixed" if case == "mixed_folded" else case]
     pods, left = [], dict(services)
     while left:  # round robin, as replicas of several services arrive
         for app in list(left):
@@ -528,13 +528,18 @@ def _burst(case):
     return pods
 
 
-@pytest.mark.parametrize("case", ["one_selector", "disjoint_services", "mixed"])
+@pytest.mark.parametrize(
+    "case", ["one_selector", "disjoint_services", "mixed", "mixed_folded"]
+)
 def test_live_engine_lays_rows_out_by_the_fill_it_found(case, monkeypatch):
     """The engine's half: blocks of one live pod at the end of a grouping
     go down the narrow layout and are counted by ``scan.rows_narrow``,
     every other block keeps 32 rows, wide calls run first; every pod
     binds, a group's pods are handed to the kernel in the order the
-    grouping got them, and ``maxSkew`` holds."""
+    grouping got them, and ``maxSkew`` holds.  ``mixed`` drives that
+    layout directly (``_calls_by_fill``): under the engine's own plan
+    (``mixed_folded``) the same burst, whose live pods fit one narrow
+    call, runs as that one call."""
     from minisched_tpu.controlplane.client import Client
     from minisched_tpu.engine.device_scheduler import DeviceScheduler
     from minisched_tpu.observability import counters, hist
@@ -542,7 +547,11 @@ def test_live_engine_lays_rows_out_by_the_fill_it_found(case, monkeypatch):
     from minisched_tpu.service.service import SchedulerService
 
     groupings = []  # [pods in, in order], then (narrow, cap, rows) a call
-    plan = DeviceScheduler._plan_blocked_calls.__func__
+    plan = (
+        DeviceScheduler._calls_by_fill
+        if case == "mixed"
+        else DeviceScheduler._plan_blocked_calls
+    ).__func__
 
     def recording_plan(cls, blocks):
         calls = plan(cls, blocks)
@@ -617,9 +626,13 @@ def test_live_engine_lays_rows_out_by_the_fill_it_found(case, monkeypatch):
     elif case == "disjoint_services":
         assert moved("scan.rows_narrow") == 0
         assert moved("scan.rows_total") == B * 2  # 32 + 8 pods: two blocks
-    else:
+    elif case == "mixed":
         assert narrow_pods and wide_rows
         assert moved("scan.rows_narrow") < len(burst)
+        assert moved("scan.calls_folded") == 0
+    else:
+        assert moved("scan.rows_narrow") == len(burst) and not wide_rows
+        assert moved("scan.calls_folded") >= 1 and moved("scan.calls_wide") == 0
     assert f"scan_rows_narrow {counters.get('scan.rows_narrow')}\n" in (
         hist.render_prometheus()
     )
@@ -643,3 +656,60 @@ def test_live_engine_lays_rows_out_by_the_fill_it_found(case, monkeypatch):
         )
         counts = [c.get(z, 0) for z in zones]
         assert max(counts) - min(counts) <= 1, (app, counts)
+
+
+def test_a_round_of_race_losers_runs_narrow_and_binds_them_all():
+    """34 services of one pod each: one wide call of two blocks, whose
+    first block all chooses the one empty node, which holds only eight of
+    them.  The losers' round (24 pods, one block by their fill: a lone
+    wide call) runs as one narrow call, a pod a step, so none of them
+    loses again and the wide program runs once; every pod binds and no
+    node goes over its cpu."""
+    from minisched_tpu.controlplane.client import Client
+    from minisched_tpu.observability import counters
+    from minisched_tpu.service.config import default_full_roster_config
+    from minisched_tpu.service.service import SchedulerService
+
+    client = Client()
+    for i in range(8):
+        client.nodes().create(
+            make_node(
+                f"node{i}", labels={"zone": f"z{i % 2}"},
+                capacity={"cpu": "4", "memory": "32Gi", "pods": 110},
+            )
+        )
+    for i in range(1, 8):  # every node but node0 already a quarter full
+        filler = make_pod(f"filler{i}", requests={"cpu": "1"})
+        filler.spec.node_name = f"node{i}"
+        client.pods().create(filler)
+    burst = []
+    for i in range(34):
+        pod = make_pod(f"r{i:02d}", labels={"app": f"svc{i:02d}"}, requests={"cpu": "500m"})
+        pod.spec.topology_spread_constraints = _spread_pod("x", f"svc{i:02d}").spec.topology_spread_constraints
+        burst.append(pod)
+    for pod in burst:
+        client.pods().create(pod)
+    before = counters.snapshot()
+    svc = SchedulerService(client)
+    svc.start_scheduler(default_full_roster_config(), device_mode=True, max_wave=256)
+    try:
+        deadline = time.monotonic() + 180
+        while time.monotonic() < deadline:
+            if all(p.spec.node_name for p in client.pods().list()):
+                break
+            time.sleep(0.2)
+    finally:
+        svc.shutdown_scheduler()
+
+    def moved(name):
+        return counters.get(name) - before.get(name, 0)
+
+    pods = client.pods().list()
+    assert all(p.spec.node_name for p in pods), [p.metadata.name for p in pods if not p.spec.node_name]
+    cpu = Counter()
+    for p in pods:
+        cpu[p.spec.node_name] += 1000 if p.metadata.name.startswith("filler") else 500
+    assert max(cpu.values()) <= 4000, cpu
+    assert moved("scan.rows_live") > len(burst)  # a round of losers ran
+    assert moved("scan.calls_wide") == 1 and moved("scan.calls_narrow") >= 1
+    assert moved("sched.lane_pods.wide") + moved("sched.lane_pods.narrow") == len(burst)

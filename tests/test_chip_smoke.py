@@ -32,12 +32,19 @@ def _run(code: str, env_extra: dict, cwd: str = REPO, drop=()):
     )
 
 
-def test_drive_and_audit_tiny_on_cpu():
+def test_drive_and_audit_tiny_on_cpu(monkeypatch):
     """The function chip_smoke.py runs at 5,000 nodes x 10,000 pods, at 64
     nodes x 348 pods: booted through __main__.start, driven over HTTP, all
-    four lanes dispatched, REST/metrics/trace audits clean."""
+    four lanes dispatched, REST/metrics/trace audits clean.  A burst of 40
+    fits one narrow call, which the engine's own plan would make of it:
+    the engine lays it out by its fill here (a wide head, a narrow tail),
+    as it does the full size's burst of 1,100."""
     import chip_smoke
+    from minisched_tpu.engine.device_scheduler import DeviceScheduler
 
+    monkeypatch.setattr(
+        DeviceScheduler, "_plan_blocked_calls", DeviceScheduler._calls_by_fill
+    )
     sizes = chip_smoke.Sizes(nodes=64, plain=300, burst=40, tail=8)
     with chip_smoke.booted_stack() as (base, service):
         programs = chip_smoke.drive_and_audit(

@@ -173,8 +173,8 @@ def _run_calls(nodes, calls, combo_capacity):
 def test_the_kernel_places_the_backlog_like_the_scalar_oracle_at_every_combo_capacity(deployment):
     """A backlog of the deployment's service pods with more than 32
     services, pool tenants among them, on nodes with taints and a cordoned
-    node: the grouping's head goes wide and its tail narrow
-    (``_plan_blocked_calls``), and pod for pod the kernel gives the scalar
+    node, laid out by its fill: the grouping's head goes wide and its tail
+    narrow (``_calls_by_fill``), and pod for pod the kernel gives the scalar
     oracle's sequential placements — at the combo capacity the build finds
     for itself and with spare tiers forced on top: a padded combo row
     never matches and never counts."""
@@ -188,7 +188,7 @@ def test_the_kernel_places_the_backlog_like_the_scalar_oracle_at_every_combo_cap
     )
     assert all(want), "the copy holds the backlog"
     blocks = order_into_blocks(pods, interaction_sets(pods), DeviceScheduler.SCAN_BLOCK_SIZE)
-    calls = DeviceScheduler._plan_blocked_calls(blocks)
+    calls = DeviceScheduler._calls_by_fill(blocks)
     assert [narrow for narrow, _, _ in calls] == [False, True]  # wide head, narrow tail
     assert sum(m is not None for _, rows, _ in calls[1:] for m in rows) > 1
     by_floor = {}
@@ -216,18 +216,43 @@ def test_the_kernel_places_the_backlog_like_the_scalar_oracle_at_every_combo_cap
         assert max(counts) - min(counts) <= 1, (app, counts)
 
 
+def test_the_one_narrow_call_a_small_backlog_takes_places_it_like_the_scalar_oracle(deployment):
+    """The same backlog under the engine's own plan: its live pods fit one
+    narrow call, so the wide head and the narrow tail become that one
+    call, every pod in block order a step of its own, and pod for pod the
+    kernel gives the scalar oracle's sequential placements at the combo
+    capacity the engine keeps."""
+    nodes = _nodes(deployment)
+    pods = [p for p in _measured(deployment, 5000, 220) if p.spec.topology_spread_constraints]
+    filters, pres, scores = _chains()
+    want = schedule_pods_sequentially(
+        list(filters), list(pres), list(scores), {}, pods, build_node_infos(nodes, [])
+    )
+    blocks = order_into_blocks(pods, interaction_sets(pods), DeviceScheduler.SCAN_BLOCK_SIZE)
+    folded = counters.get("scan.calls_folded")
+    calls = DeviceScheduler._plan_blocked_calls(blocks)
+    assert counters.get("scan.calls_folded") == folded + 1
+    ((narrow, rows, cap),) = calls
+    assert narrow and cap == DeviceScheduler.SCAN_MAX_CHUNK * DeviceScheduler.SCAN_NARROW_WIDTH
+    assert [m for m in rows if m is not None] == [m for blk in blocks for m in blk if m is not None]
+    got, sizes = _run_calls(nodes, calls, 256)
+    assert sizes == [256]
+    assert [got[p.metadata.name] for p in pods] == want
+
+
 # -- the live engine: lanes taking turns, the counters ------------------------
 
 
-def test_the_engine_binds_the_mix_lane_by_lane_and_counts_it(deployment):
-    """The served engine on the copy: the packed wave takes the plain,
-    pinned and tolerating pods, the blocked scan the services (head wide,
-    tail narrow), every guarantee of the deployment's references holds on
-    what it bound, the scan lanes end at one combo capacity, and the
-    counters say what ran."""
+def _serve_mix(deployment, by_fill):
+    """The served engine on the copy, over a backlog of 320 measured pods
+    pending before it starts: (the pods, what was bound, the engine, the
+    counters moved, the deployment's violations by its two references).
+    ``by_fill`` drives the wide layout directly: every grouping is laid
+    out by its fill (``_calls_by_fill``: a wide head, a narrow tail),
+    where the engine's own plan runs a backlog this small as one narrow
+    call."""
     from minisched_tpu.controlplane.checkpoint import _encode
     from minisched_tpu.controlplane.client import Client
-    from minisched_tpu.observability import hist
     from minisched_tpu.service.config import default_full_roster_config
     from minisched_tpu.service.service import SchedulerService
 
@@ -239,32 +264,64 @@ def test_the_engine_binds_the_mix_lane_by_lane_and_counts_it(deployment):
     pods = _measured(deployment, 5000, 320)
     for pod in pods:
         client.pods().create(pod)
-    svc = SchedulerService(client)
-    sched = svc.start_scheduler(default_full_roster_config(), device_mode=True, max_wave=256)
-    deadline = time.monotonic() + 240
-    while time.monotonic() < deadline:
-        if all(p.spec.node_name for p in client.pods().list()):
-            break
-        time.sleep(0.2)
-    svc.shutdown_scheduler()
+    with pytest.MonkeyPatch.context() as mp:
+        if by_fill:
+            mp.setattr(DeviceScheduler, "_plan_blocked_calls", DeviceScheduler._calls_by_fill)
+        svc = SchedulerService(client)
+        sched = svc.start_scheduler(default_full_roster_config(), device_mode=True, max_wave=256)
+        deadline = time.monotonic() + 240
+        while time.monotonic() < deadline:
+            if all(p.spec.node_name for p in client.pods().list()):
+                break
+            time.sleep(0.2)
+        svc.shutdown_scheduler()
     bound = client.pods().list()
+    moved = {k: v - before.get(k, 0) for k, v in counters.snapshot().items()}
+    nodes_json = [_encode(n) for n in client.nodes().list()]
+    pods_json = [_encode(p) for p in bound]
+    record = {"sent": [p.metadata.name for p in bound], "acks": {p.metadata.name: p.spec.node_name for p in bound}}
+    return pods, bound, sched, moved, (
+        reference.violations(nodes_json, pods_json),
+        own.violations(nodes_json, pods_json, config, record),
+    )
+
+
+def _holds_the_deployment(pods, bound, violations):
+    """Every pod bound and every guarantee of the deployment's references
+    held on what was bound."""
     assert all(p.spec.node_name for p in bound), [p.metadata.name for p in bound if not p.spec.node_name]
+    common, own = violations
+    assert {k: v for k, v in common.items() if k != "spread_groups"} == {
+        "unbound": 0, "on_unknown_node": 0, "on_unschedulable": 0, "selector_broken": 0,
+        "nodes_over_allocatable": 0, "skew_over_max": 0,
+    }, common
+    assert common["spread_groups"] == len({p.metadata.labels["app"] for p in pods if "app" in p.metadata.labels})
+    assert own == {"taint_not_tolerated": 0, "service_constraint_dropped": 0, "kinds_missing": 0}
 
-    def moved(name):
-        return counters.get(name) - before.get(name, 0)
 
+def test_the_engine_binds_the_mix_lane_by_lane_and_counts_it(deployment):
+    """The served engine on the copy, laying groupings out by their fill:
+    the packed wave takes the plain, pinned and tolerating pods, the
+    blocked scan the services (head wide, tail narrow), every guarantee
+    of the deployment's references holds on what it bound, the scan lanes
+    end at one combo capacity, and the counters say what ran."""
+    from minisched_tpu.observability import hist
+
+    pods, bound, sched, moved, violations = _serve_mix(deployment, by_fill=True)
+    _holds_the_deployment(pods, bound, violations)
     service = sum(bool(p.spec.topology_spread_constraints) for p in pods)
-    lanes = {lane: moved("sched.lane_pods." + lane) for lane in LANES}
+    lanes = {lane: moved.get("sched.lane_pods." + lane, 0) for lane in LANES}
     assert sum(lanes.values()) == len(pods), lanes
     assert lanes["wave"] == len(pods) - service and lanes["wide"] > 0 and lanes["narrow"] > 0, lanes
     assert lanes["wide"] + lanes["narrow"] + lanes["exact"] == service
-    assert moved("scan.calls_wide") >= 1 and moved("scan.calls_narrow") >= 1
-    assert moved("scan.rows_narrow") >= lanes["narrow"]  # a retry round counts its pods again
-    assert 0 < moved("scan.rows_live") < moved("scan.rows_total")
+    assert moved["scan.calls_wide"] >= 1 and moved["scan.calls_narrow"] >= 1
+    assert moved["scan.calls_folded"] == 0
+    assert moved["scan.rows_narrow"] >= lanes["narrow"]  # a retry round counts its pods again
+    assert 0 < moved["scan.rows_live"] < moved["scan.rows_total"]
     # more than 32 services were pending at once: the lanes went to 256
     # combo rows and stayed, whatever a later call held
     assert sched._scan_combo_cap == 256
-    assert moved("scan.combos_total") % 32 == 0 and 0 < moved("scan.combos_live") < moved("scan.combos_total")
+    assert moved["scan.combos_total"] % 32 == 0 and 0 < moved["scan.combos_live"] < moved["scan.combos_total"]
     # the scan programs keep the constraint buffer's narrow columns behind
     # a fence (unpack_columns); the wave's buffers are as they were
     programs = sched.dispatched_programs()
@@ -274,19 +331,27 @@ def test_the_engine_binds_the_mix_lane_by_lane_and_counts_it(deployment):
     metrics = hist.render_prometheus()
     for name in counters.LANE_COUNTERS:
         assert f"\n{name.replace('.', '_')} {counters.get(name)}\n" in "\n" + metrics, name
-    # the deployment's guarantees, by the benchmark's own plain references
-    nodes_json = [_encode(n) for n in client.nodes().list()]
-    pods_json = [_encode(p) for p in bound]
-    common = reference.violations(nodes_json, pods_json)
-    assert {k: v for k, v in common.items() if k != "spread_groups"} == {
-        "unbound": 0, "on_unknown_node": 0, "on_unschedulable": 0, "selector_broken": 0,
-        "nodes_over_allocatable": 0, "skew_over_max": 0,
-    }, common
-    assert common["spread_groups"] == len({p.metadata.labels["app"] for p in pods if "app" in p.metadata.labels})
-    record = {"sent": [p.metadata.name for p in bound], "acks": {p.metadata.name: p.spec.node_name for p in bound}}
-    assert own.violations(nodes_json, pods_json, config, record) == {
-        "taint_not_tolerated": 0, "service_constraint_dropped": 0, "kinds_missing": 0,
-    }
+
+
+def test_the_engine_runs_a_service_backlog_that_fits_one_narrow_call_as_one(deployment):
+    """The same backlog under the engine's own plan: its service pods
+    (fewer than one narrow call holds, a wide head and a narrow tail by
+    their fill) run a pod a step in one narrow call a grouping, the wide
+    program never runs, and every guarantee holds as in the wide layout."""
+    pods, bound, sched, moved, violations = _serve_mix(deployment, by_fill=False)
+    _holds_the_deployment(pods, bound, violations)
+    service = [p for p in pods if p.spec.topology_spread_constraints]
+    blocks = order_into_blocks(service, interaction_sets(service), DeviceScheduler.SCAN_BLOCK_SIZE)
+    assert [narrow for narrow, _, _ in DeviceScheduler._calls_by_fill(blocks)] == [False, True]
+    lanes = {lane: moved.get("sched.lane_pods." + lane, 0) for lane in LANES}
+    assert lanes["wave"] == len(pods) - len(service) and lanes["wide"] == 0 and lanes["narrow"] > 0, lanes
+    assert lanes["narrow"] + lanes["exact"] == len(service)
+    assert moved["scan.calls_folded"] >= 1 and moved["scan.calls_narrow"] >= 1
+    assert moved["scan.calls_wide"] == 0
+    assert moved["scan.rows_narrow"] == moved["scan.rows_live"] == moved["scan.rows_total"]
+    assert sched._scan_combo_cap == 256
+    programs = sched.dispatched_programs()
+    assert programs["narrow_scan"] and not programs["blocked_scan"]
 
 
 def test_the_lane_counters_stand_at_zero_from_the_engines_construction():
@@ -294,7 +359,7 @@ def test_the_lane_counters_stand_at_zero_from_the_engines_construction():
     from minisched_tpu.service.config import default_full_roster_config
     from minisched_tpu.service.service import SchedulerService
 
-    assert len(counters.LANE_COUNTERS) == 15 + len(LANES)
+    assert len(counters.LANE_COUNTERS) == 16 + len(LANES)
     assert {"sched.lane_pods." + lane for lane in LANES} <= set(counters.LANE_COUNTERS)
     fresh = counters.Counters()
     real, counters.GLOBAL = counters.GLOBAL, fresh

@@ -102,10 +102,11 @@ def _values(spy, name, **labels):
 @pytest.fixture(scope="module")
 def pod_path():
     """The served stack (``__main__.start``, device engine) with all four
-    lanes driven over HTTP: 24 plain pods (the packed wave), 40 spread
-    pods of two services (the blocked scan: two blocks of two pods wide,
-    36 of one narrow), 8 spread pods (the exact scan).  Returns the spy's
-    record and the ring's bind records of these pods."""
+    lanes driven over HTTP: 24 plain pods (the packed wave), 34 spread
+    pods of 17 services (the blocked scan: two full blocks, one wide
+    call), 40 spread pods of two services (the blocked scan: few enough
+    for one narrow call), 8 spread pods (the exact scan).  Returns the
+    spy's record and the ring's bind records of these pods."""
     import chip_smoke
     from minisched_tpu.api.objects import make_node, make_pod
     from minisched_tpu.controlplane.remote import RemoteClient
@@ -133,20 +134,26 @@ def pod_path():
             )
             _wait_bound(client, 24)
             client.pods().create_many(
+                [_spread_pod(f"w{k}.{i}", f"w{k}") for k in range(17) for i in range(2)],
+                return_objects=False,
+            )
+            _wait_bound(client, 58)
+            client.pods().create_many(
                 [_spread_pod(f"r{i}", "r") for i in range(2)]
                 + [_spread_pod(f"s{i}") for i in range(38)],
                 return_objects=False,
             )
-            _wait_bound(client, 64)
+            _wait_bound(client, 98)
             client.pods().create_many(
                 [_spread_pod(f"t{i}") for i in range(8)], return_objects=False
             )
-            _wait_bound(client, 72)
+            _wait_bound(client, 106)
             time.sleep(0.2)  # the last bind's watch writes
         with urllib.request.urlopen(base + "/debug/trace", timeout=30) as r:
             lines = [json.loads(ln) for ln in r.read().decode().splitlines()]
     ours = {f"default/{p}{i}" for p, n in (("a", 24), ("r", 2), ("s", 38), ("t", 8))
             for i in range(n)}
+    ours |= {f"default/w{k}.{i}" for k in range(17) for i in range(2)}
     records = [s for s in lines[1:] if s.get("pod") in ours]
     return {"spy": spy, "records": records, "head": lines[0], "ours": ours}
 
@@ -157,7 +164,7 @@ def test_every_pod_was_popped_once_and_every_lane_ran(pod_path):
     spy, records = pod_path["spy"], pod_path["records"]
     assert len(_values(spy, "sched.queue_wait_s")) == len(pod_path["ours"])
     assert collections.Counter(r["lane"] for r in records) == {
-        "wave": 24, "wide": 4, "narrow": 36, "exact": 8,
+        "wave": 24, "wide": 34, "narrow": 40, "exact": 8,
     }
 
 

@@ -5,8 +5,10 @@ ln(services+1) of the pods), labelled ``app=svc-<k>``, with one required
 anti-affinity term ``app In (svc-<k>)`` under the hostname key: one
 replica of a service a host.  Each service is an interaction group of its
 own, so a backlog of several services fills the blocked lane's WIDE layout
-(32 pods of 32 services a step), and the reverse direction of the placed
-pods' terms is one row of the combo axis a service.
+(32 pods of 32 services a step) where it is more than one narrow call
+holds, and runs a pod a step in that one call where it fits; the reverse
+direction of the placed pods' terms is one row of the combo axis a
+service.
 
 The guarantee is restated here in plain code (``_shared_hosts``: no two
 pods of one service on one host).  Everything is seeded and on the CPU.
@@ -209,12 +211,14 @@ def _wait(cond, seconds=300.0):
         time.sleep(0.1)
 
 
-@pytest.fixture(scope="module")
-def served():
+def _serve(by_fill):
     """Two backlogs of 128 pods dealt over 40 services (38 and 40 distinct),
     the first pending before the engine starts, the second created in one
     call once the first is bound: what the counters, the engine's combo
-    capacity and its scan programs read after each."""
+    capacity and its scan programs read after each.  ``by_fill`` drives
+    the wide layout directly: the engine lays every grouping out by its
+    fill (``_calls_by_fill``, a wide head and a narrow tail), where its own
+    plan runs a backlog this small as one narrow call."""
     from minisched_tpu.controlplane.client import Client
     from minisched_tpu.service.config import default_full_roster_config
     from minisched_tpu.service.service import SchedulerService
@@ -226,28 +230,51 @@ def served():
     for pod in first:
         client.pods().create(pod)
     before = counters.snapshot()
-    svc = SchedulerService(client)
-    sched = svc.start_scheduler(default_full_roster_config(), device_mode=True, max_wave=256)
     out = {"first": first, "second": second}
-    try:
-        _wait(lambda: all(p.spec.node_name for p in client.pods().list()))
-        out["cap_after_first"] = sched._scan_combo_cap
-        out["programs_after_first"] = {
-            lane: len(ev._packed_caller._fns)
+
+    def programs(sched):
+        return {
+            lane: len(ev._packed_caller._fns) if ev is not None else 0
             for lane, ev in (("wide", sched._blocked_scheduler), ("narrow", sched._narrow_scheduler))
         }
-        out["moved_first"] = {k: v - before.get(k, 0) for k, v in counters.snapshot().items()}
-        client.pods().create_many(second)
-        _wait(lambda: all(p.spec.node_name for p in client.pods().list()))
-        out["programs_after_second"] = {
-            lane: len(ev._packed_caller._fns)
-            for lane, ev in (("wide", sched._blocked_scheduler), ("narrow", sched._narrow_scheduler))
-        }
-        out["moved"] = {k: v - before.get(k, 0) for k, v in counters.snapshot().items()}
-    finally:
-        svc.shutdown_scheduler()
+
+    with pytest.MonkeyPatch.context() as mp:
+        if by_fill:
+            mp.setattr(DeviceScheduler, "_plan_blocked_calls", DeviceScheduler._calls_by_fill)
+        svc = SchedulerService(client)
+        sched = svc.start_scheduler(default_full_roster_config(), device_mode=True, max_wave=256)
+        try:
+            _wait(lambda: all(p.spec.node_name for p in client.pods().list()))
+            out["cap_after_first"] = sched._scan_combo_cap
+            out["programs_after_first"] = programs(sched)
+            out["moved_first"] = {k: v - before.get(k, 0) for k, v in counters.snapshot().items()}
+            client.pods().create_many(second)
+            _wait(lambda: all(p.spec.node_name for p in client.pods().list()))
+            out["programs_after_second"] = programs(sched)
+            out["moved"] = {k: v - before.get(k, 0) for k, v in counters.snapshot().items()}
+        finally:
+            svc.shutdown_scheduler()
     out["bound"] = client.pods().list()
     return out
+
+
+@pytest.fixture(scope="module")
+def served():
+    return _serve(by_fill=True)
+
+
+@pytest.fixture(scope="module")
+def served_folded():
+    return _serve(by_fill=False)
+
+
+def _one_replica_a_host(bound):
+    assert len(bound) == 256 and all(p.spec.node_name for p in bound)
+    assert _shared_hosts([(p.metadata.labels["app"], p.spec.node_name) for p in bound]) == []
+    # every pod was read back with its own service's term
+    for p in bound:
+        (term,) = p.spec.affinity.pod_anti_affinity.required
+        assert term.label_selector.match_expressions[0].values == [p.metadata.labels["app"]]
 
 
 def test_a_backlog_of_many_services_goes_wide_and_keeps_each_one_a_host(served):
@@ -257,13 +284,25 @@ def test_a_backlog_of_many_services_goes_wide_and_keeps_each_one_a_host(served):
     moved = served["moved_first"]
     assert moved["scan.calls_wide"] >= 1 and moved["sched.lane_pods.wide"] > 0
     assert 0 < moved["scan.rows_live"] < moved["scan.rows_total"]
-    bound = served["bound"]
-    assert len(bound) == 256 and all(p.spec.node_name for p in bound)
-    assert _shared_hosts([(p.metadata.labels["app"], p.spec.node_name) for p in bound]) == []
-    # every pod was read back with its own service's term
-    for p in bound:
-        (term,) = p.spec.affinity.pod_anti_affinity.required
-        assert term.label_selector.match_expressions[0].values == [p.metadata.labels["app"]]
+    assert served["moved"]["scan.calls_folded"] == 0
+    _one_replica_a_host(served["bound"])
+
+
+def test_a_backlog_that_fits_one_narrow_call_takes_one_and_keeps_each_one_a_host(served_folded):
+    """The same two backlogs under the engine's own plan: each grouping
+    (128 live pods, a wide head and a narrow tail by its fill) runs as
+    one narrow call, a pod a step, and the guarantee holds as in the wide
+    layout."""
+    first = served_folded["first"]
+    blocks = order_into_blocks(first, interaction_sets(first), DeviceScheduler.SCAN_BLOCK_SIZE)
+    assert len(DeviceScheduler._calls_by_fill(blocks)) == 2
+    moved = served_folded["moved_first"]
+    assert moved["scan.calls_folded"] >= 1 and moved["scan.calls_narrow"] >= 1
+    assert moved["scan.calls_wide"] == 0 and moved["sched.lane_pods.wide"] == 0
+    assert moved["sched.lane_pods.narrow"] > 0
+    assert moved["scan.rows_narrow"] == moved["scan.rows_live"] == moved["scan.rows_total"]
+    assert served_folded["programs_after_first"]["wide"] == 0
+    _one_replica_a_host(served_folded["bound"])
 
 
 def test_past_32_services_the_combo_axis_holds_256_rows_and_later_builds_add_no_program(served):

@@ -9,7 +9,8 @@ inside a wave).  These tests pin the quantization invariants so a
   ``constraints.cap_tier``; tests/test_mixed_deployment.py steps over
   them) and the topology-key axis quantizes to 4,
 * scan chunks use exactly two capacities, the blocked lane's wide layout
-  one more, its narrow layout exactly one whatever it carries,
+  one more, its narrow layout exactly one whatever it carries (a whole
+  grouping that fits one narrow call takes it),
 * pod tables have exactly TWO packed schemas per capacity (fast/slow),
   and the slow one can be force-packed below the size threshold (the
   prewarm relies on it).
@@ -35,6 +36,7 @@ from minisched_tpu.models.tables import (
     build_pod_table,
     node_profile_capacity,
 )
+from minisched_tpu.observability import counters
 
 
 def _spread_pod(name: str, app: str) -> object:
@@ -100,36 +102,87 @@ def test_scan_chunks_use_exactly_two_capacities():
     ) == DeviceScheduler.BLOCKED_MAX_CHUNK
 
 
-@pytest.mark.parametrize("head_blocks", [0, 3])
-@pytest.mark.parametrize("suffix", [1, 33, 1024, 1025, 8192])
-def test_narrow_layout_runs_at_exactly_one_capacity(suffix, head_blocks):
-    """Whatever the length of the run of one-pod blocks a grouping ends
-    in, its calls share ONE pod capacity (a short last call pads up to
-    it), carry at most SCAN_MAX_CHUNK live pods each and keep block order;
-    the blocks before it keep the wide layout's tiers and run first."""
-    S = DeviceScheduler
-    B, W = S.SCAN_BLOCK_SIZE, S.SCAN_NARROW_WIDTH
+def _grouping(head_blocks, suffix):
+    """``head_blocks`` blocks of 32, 31, ... live pods, then ``suffix``
+    blocks of one."""
+    B = DeviceScheduler.SCAN_BLOCK_SIZE
     head = [
         [f"h{b}.{i}" for i in range(B - b)] + [None] * b
         for b in range(head_blocks)
     ]
     tail = [[f"s{i}"] + [None] * (B - 1) for i in range(suffix)]
+    return head, tail
+
+
+#: the live pods of three head blocks (32 + 31 + 30)
+HEAD3 = 93
+
+
+@pytest.mark.parametrize("head_blocks", [0, 3])
+@pytest.mark.parametrize(
+    "suffix",
+    [1, 33, DeviceScheduler.SCAN_MAX_CHUNK - HEAD3,
+     DeviceScheduler.SCAN_MAX_CHUNK - HEAD3 + 1, 1024, 1025, 8192],
+)
+def test_narrow_layout_runs_at_exactly_one_capacity(suffix, head_blocks):
+    """Whatever the length of the run of one-pod blocks a grouping ends
+    in, its calls share ONE pod capacity (a short last call pads up to
+    it), carry at most SCAN_MAX_CHUNK live pods each and keep block order;
+    the blocks before it keep the wide layout's tiers and run first —
+    unless the whole grouping's live pods fit one narrow call (at most
+    SCAN_MAX_CHUNK, the edge is a case of its own), which is then the
+    grouping's one call, every live pod in block order."""
+    S = DeviceScheduler
+    B, W = S.SCAN_BLOCK_SIZE, S.SCAN_NARROW_WIDTH
+    head, tail = _grouping(head_blocks, suffix)
+    folded_before = counters.get("scan.calls_folded")
     calls = S._plan_blocked_calls(head + tail)
+    folded = counters.get("scan.calls_folded") - folded_before
     narrow = [(part, cap) for is_narrow, part, cap in calls if is_narrow]
     wide = [(part, cap) for is_narrow, part, cap in calls if not is_narrow]
     assert [is_narrow for is_narrow, _, _ in calls] == (
         [False] * len(wide) + [True] * len(narrow)
     )
     assert {cap for _, cap in narrow} == {S.SCAN_MAX_CHUNK * W}
-    assert len(narrow) == -(-suffix // S.SCAN_MAX_CHUNK)
     for part, cap in narrow:
         assert len(part) <= cap and len(part) % W == 0
         assert all((m is not None) == (i % W == 0) for i, m in enumerate(part))
+    live_head = [m for b in head for m in b if m]
+    if head and len(live_head) + suffix <= S.SCAN_MAX_CHUNK:
+        # head and tail fit one narrow call: it is the only one, and it
+        # saved the call the tail would have taken
+        assert not wide and len(narrow) == 1 and folded == 1
+        assert [m for m in narrow[0][0] if m] == live_head + [
+            f"s{i}" for i in range(suffix)
+        ]
+        return
+    assert folded == 0
+    assert len(narrow) == -(-suffix // S.SCAN_MAX_CHUNK)
     assert [m for part, _ in narrow for m in part if m] == [
         f"s{i}" for i in range(suffix)
     ]
     assert [m for part, _ in wide for m in part] == [m for b in head for m in b]
     assert {cap for _, cap in wide} <= {S._blocked_cap(B * head_blocks)}
+
+
+@pytest.mark.parametrize("layout", ["wide", "narrow"])
+def test_a_plan_of_one_call_is_left_as_it_was(layout):
+    """A grouping its fill already lays out as one call keeps that call:
+    full blocks alone stay one wide call at their tier, one-pod blocks
+    alone one narrow call, and no call counts as folded."""
+    S = DeviceScheduler
+    head, tail = _grouping(3, 0) if layout == "wide" else _grouping(0, 40)
+    folded_before = counters.get("scan.calls_folded")
+    calls = S._plan_blocked_calls(head + tail)
+    assert calls == S._calls_by_fill(head + tail)
+    assert counters.get("scan.calls_folded") == folded_before
+    ((is_narrow, rows, cap),) = calls
+    if layout == "wide":
+        assert not is_narrow and cap == S._blocked_cap(len(rows))
+        assert rows == [m for b in head for m in b]
+    else:
+        assert is_narrow and cap == S.SCAN_MAX_CHUNK * S.SCAN_NARROW_WIDTH
+        assert [m for m in rows if m] == [f"s{i}" for i in range(40)]
 
 
 def test_pod_table_has_two_schemas_per_capacity():

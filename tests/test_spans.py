@@ -283,19 +283,26 @@ def traced_wave(tmp_path_factory):
         plain = [make_pod(f"a{i}", requests={"cpu": "100m"}) for i in range(24)]
         client.pods().create_many(plain, return_objects=False)
         _wait_bound(client, 24)
-        # 40 > SCAN_BLOCK_SIZE: the blocked lane, whose first two blocks
-        # hold two pods (wide) and whose other 36 hold one (narrow); 8: the
-        # exact lane
+        # 34 > SCAN_BLOCK_SIZE: the blocked lane; 17 services of two pods
+        # fill two blocks of 17, one wide call
+        client.pods().create_many(
+            [_spread_pod(f"w{k}.{i}", f"w{k}") for k in range(17) for i in range(2)],
+            return_objects=False,
+        )
+        _wait_bound(client, 58)
+        # 40: the blocked lane again, whose first two blocks hold two pods
+        # and whose other 36 hold one; 40 live pods fit one narrow call,
+        # so the grouping runs as that one; 8: the exact lane
         client.pods().create_many(
             [_spread_pod(f"r{i}", "r") for i in range(2)]
             + [_spread_pod(f"s{i}") for i in range(38)],
             return_objects=False,
         )
-        _wait_bound(client, 64)
+        _wait_bound(client, 98)
         client.pods().create_many(
             [_spread_pod(f"t{i}") for i in range(8)], return_objects=False
         )
-        _wait_bound(client, 72)
+        _wait_bound(client, 106)
 
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
@@ -307,7 +314,7 @@ def traced_wave(tmp_path_factory):
                 [make_pod(f"b{i}", requests={"cpu": "100m"}) for i in range(24)],
                 return_objects=False,
             )
-            _wait_bound(client, 96)
+            _wait_bound(client, 130)
         finally:
             jax.profiler.stop_trace()
         sched = service.scheduler
